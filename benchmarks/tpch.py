@@ -254,6 +254,24 @@ def q6(lineitem) -> "object":
 # pyarrow/numpy oracle implementations
 # ---------------------------------------------------------------------------
 
+def parity(got: dict, want: dict, rtol: float) -> bool:
+    """Column-dict equality as bench.py and chip_smoke.py gate it: same
+    columns and lengths, floats within ``rtol`` (absolute floor 1e-6),
+    everything else exact."""
+    if set(got) != set(want):
+        return False
+    for k in want:
+        if len(got[k]) != len(want[k]):
+            return False
+        for a, b in zip(got[k], want[k]):
+            if isinstance(b, float):
+                if abs(a - b) > max(rtol * abs(b), 1e-6):
+                    return False
+            elif a != b:
+                return False
+    return True
+
+
 def oracle_q1(lineitem: pa.Table) -> dict:
     import pyarrow.compute as pc
 

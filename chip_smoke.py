@@ -1,0 +1,467 @@
+"""chip_smoke.py — does the engine's main path run on the chip, and is it right?
+
+One process that holds the accelerator and drives the engine through the
+entry points a user calls (``set_execution_config``, ``from_arrow``,
+``read_parquet``, the DataFrame API, ``dt.sql``, ``ServingRuntime.submit``,
+the mesh runner) over TPC-H at ``--scale`` (SF1 by default: 6.0M lineitem,
+1.5M orders, 150k customer rows, generated from ``--seed``), in 32-bit mode
+with the device path switched on. Every answer is compared with the
+``pyarrow.compute`` oracles of ``benchmarks/tpch.py`` at rtol 1e-6, and every
+leg fails if any fallback, breaker, degraded or device-error counter moved:
+on the chip a kernel the compiler refuses still gives the right answer from
+the host path, and only the counters show it.
+
+    python chip_smoke.py                      # on a machine with a TPU
+    python chip_smoke.py --cpu --scale 0.01   # tier-1: 8 virtual CPU devices
+
+Without ``--cpu`` a platform other than ``tpu`` is refused before any work.
+Wall times are printed as smoke timings; they are not metrics and go in no
+record as speed. The last line of stdout is one JSON object,
+``{"ok": ..., "device": {"platform", "kind", "count"}}``; the exit code is 0
+only when every leg that applies passed.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import shutil
+import sys
+import time
+import traceback
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+# counters that say "this ran on the device"; a warm run must repeat them
+DEVICE_COUNTERS = (
+    "device_aggregations", "device_resident_segments", "segment_dispatches",
+    "device_agg_dispatches", "device_join_probes", "device_sorts",
+    "device_projections", "device_filters", "device_fused_maps",
+    "device_shuffles",
+)
+
+
+def failure_counters(counters: dict) -> dict:
+    """Every counter that means a device path was refused, broke or was
+    bypassed — all must stay zero."""
+    bad = {}
+    for k, v in counters.items():
+        if not v:
+            continue
+        if (k in ("degraded_completions", "segment_fallbacks",
+                  "degraded_shuffles", "degraded_sketch_merges",
+                  "device_attempt_errors")
+                or k.endswith("_breaker_trips")
+                or (k.startswith("device_") and k.endswith("_fallbacks"))):
+            bad[k] = v
+    return bad
+
+
+class Leg:
+    """One leg's checks and its printed verdict. Notes print as they are
+    made, so a run that is killed still shows how far it got."""
+
+    def __init__(self, name: str):
+        self.name = name
+        self.problems: list = []
+        self.t0 = time.perf_counter()
+
+    def check(self, ok: bool, what: str) -> bool:
+        if not ok:
+            self.problems.append(what)
+        return ok
+
+    def note(self, text: str) -> None:
+        print(f"    {self.name}: {text}", flush=True)
+
+    def counters_clean(self, label: str, counters: dict, **at_least) -> None:
+        bad = failure_counters(counters)
+        self.check(not bad, f"{label}: failure counters {bad}")
+        for key, floor in at_least.items():
+            self.check(counters.get(key, 0) >= floor,
+                       f"{label}: {key}={counters.get(key, 0)} < {floor}")
+        shown = {k: counters[k] for k in DEVICE_COUNTERS if counters.get(k)}
+        self.note(f"{label}: {shown}")
+
+    def finish(self) -> bool:
+        ok = not self.problems
+        wall = time.perf_counter() - self.t0
+        for p in self.problems:
+            print(f"    {self.name}: FAIL: {p}")
+        print(f"[{self.name}] {'ok' if ok else 'FAIL'}  ({wall:.1f}s)",
+              flush=True)
+        return ok
+
+
+def timed(fn):
+    t0 = time.perf_counter()
+    out = fn()
+    return out, time.perf_counter() - t0
+
+
+def run_query(build):
+    """Build, execute and fetch one query: (answer dict, counters)."""
+    q = build()
+    got = q.collect().to_pydict()
+    return got, q.stats.snapshot()["counters"]
+
+
+Q1_SQL = """
+    SELECT l_returnflag, l_linestatus,
+           SUM(l_quantity) AS sum_qty,
+           SUM(l_extendedprice) AS sum_base_price,
+           SUM(l_extendedprice * (1 - l_discount)) AS sum_disc_price,
+           SUM(l_extendedprice * (1 - l_discount) * (1 + l_tax)) AS sum_charge,
+           AVG(l_quantity) AS avg_qty,
+           AVG(l_extendedprice) AS avg_price,
+           AVG(l_discount) AS avg_disc,
+           COUNT(l_quantity) AS count_order
+    FROM lineitem
+    WHERE l_shipdate <= DATE '1998-09-02'
+    GROUP BY l_returnflag, l_linestatus
+    ORDER BY l_returnflag, l_linestatus
+"""
+
+Q6_SQL = """
+    SELECT SUM(l_extendedprice * l_discount) AS revenue
+    FROM lineitem
+    WHERE l_shipdate >= DATE '1994-01-01' AND l_shipdate < DATE '1995-01-01'
+      AND l_discount BETWEEN 0.05 AND 0.07 AND l_quantity < 24
+"""
+
+
+class Smoke:
+    def __init__(self, args, jax):
+        from benchmarks import tpch
+
+        import daft_tpu as dt
+
+        self.args, self.jax, self.dt, self.tpch = args, jax, dt, tpch
+        self.rtol = 1e-6
+        t0 = time.perf_counter()
+        self.tables = tpch.generate_tables(scale=args.scale, seed=args.seed)
+        t_gen = time.perf_counter() - t0
+        li, orders = self.tables["lineitem"], self.tables["orders"]
+        cust, nation = self.tables["customer"], self.tables["nation"]
+        # collected once: the partitions carry the per-partition stage
+        # cache, so the columns stay HBM-resident across every query below
+        self.li = dt.from_arrow(li).collect()
+        self.orders = dt.from_arrow(orders).collect()
+        self.cust = dt.from_arrow(cust).collect()
+        self.nation = dt.from_arrow(nation).collect()
+        t0 = time.perf_counter()
+        self.want = {
+            "q1": tpch.oracle_q1(li),
+            "q6": {"revenue": [tpch.oracle_q6(li)]},
+            "q12": tpch.oracle_q12(li),
+            "q3": tpch.oracle_q3(cust, orders, li),
+            "q5": tpch.oracle_q5(cust, orders, li, nation),
+        }
+        print(f"data: lineitem={li.num_rows} orders={orders.num_rows} "
+              f"customer={cust.num_rows} rows, seed={args.seed} "
+              f"(generate {t_gen:.1f}s, oracles "
+              f"{time.perf_counter() - t0:.1f}s)")
+        self.build = {
+            "q1": lambda: tpch.q1(self.li),
+            "q6": lambda: tpch.q6(self.li),
+            "q12": lambda: tpch.q12(self.li),
+            "q3": lambda: tpch.q3(self.cust, self.orders, self.li),
+            "q5": lambda: tpch.q5(self.cust, self.orders, self.li,
+                                  self.nation),
+        }
+        # first-run device counters per query, for the sql leg to compare
+        self.api_counters: dict = {}
+
+    def matches(self, name: str, got: dict) -> bool:
+        return self.tpch.parity(got, self.want[name], self.rtol)
+
+    # ------------------------------------------------------------ resident
+    def leg_resident(self) -> bool:
+        leg = Leg("resident")
+        # q1/q6/q12 plan as ONE fused filter+aggregate program over the
+        # resident columns; q3/q5 project `revenue` below their aggregate,
+        # which is the shape the planner compiles into a resident segment.
+        # q5's final sort orders five nation rows (host, under any
+        # device_min_rows), so only q3's sort over its groups is asked for.
+        floors = {
+            "q1": dict(device_aggregations=1),
+            "q6": dict(device_aggregations=1),
+            "q12": dict(device_aggregations=1),
+            "q3": dict(device_aggregations=1, device_resident_segments=1,
+                       device_join_probes=1, device_sorts=1),
+            "q5": dict(device_aggregations=1, device_resident_segments=1,
+                       device_join_probes=1),
+        }
+        for name in ("q1", "q6", "q12", "q3", "q5"):
+            (got1, c1), cold = timed(lambda: run_query(self.build[name]))
+            (got2, c2), warm = timed(lambda: run_query(self.build[name]))
+            leg.check(self.matches(name, got1), f"{name} run 1 != oracle")
+            # the second run reads what the first left in the stage cache:
+            # anything that consumed or freed a resident buffer fails HERE
+            leg.check(self.matches(name, got2), f"{name} run 2 != oracle")
+            leg.counters_clean(f"{name} run 1", c1, **floors[name])
+            bad2 = failure_counters(c2)
+            leg.check(not bad2, f"{name} run 2: failure counters {bad2}")
+            d1 = {k: c1.get(k, 0) for k in DEVICE_COUNTERS}
+            d2 = {k: c2.get(k, 0) for k in DEVICE_COUNTERS}
+            leg.check(d1 == d2, f"{name}: device counters moved between "
+                                f"runs: {d1} -> {d2}")
+            leg.note(f"{name}: smoke timing cold {cold:.2f}s warm {warm:.2f}s")
+            self.api_counters[name] = d1
+        return leg.finish()
+
+    # ----------------------------------------------------------------- sql
+    def leg_sql(self) -> bool:
+        leg = Leg("sql")
+        for name, text in (("q1", Q1_SQL), ("q6", Q6_SQL)):
+            (got, c), wall = timed(lambda: run_query(
+                lambda: self.dt.sql(text, lineitem=self.li)))
+            leg.check(self.matches(name, got), f"{name} sql != oracle")
+            leg.counters_clean(f"{name} sql", c, device_aggregations=1)
+            d = {k: c.get(k, 0) for k in DEVICE_COUNTERS}
+            leg.check(d == self.api_counters.get(name),
+                      f"{name}: sql device counters {d} != DataFrame API's "
+                      f"{self.api_counters.get(name)}")
+            leg.note(f"{name}: smoke timing {wall:.2f}s")
+        return leg.finish()
+
+    # ---------------------------------------------------------------- scan
+    def leg_scan(self) -> bool:
+        import pyarrow.parquet as papq
+
+        leg = Leg("scan")
+        li = self.tables["lineitem"]
+        nfiles = 8
+        per = -(-li.num_rows // nfiles)
+        pq_dir = os.path.join(self.args.out, "lineitem_parquet")
+        shutil.rmtree(pq_dir, ignore_errors=True)
+        os.makedirs(pq_dir)
+        cfg = self.dt.get_context().execution_config
+        saved_min = cfg.scan_tasks_min_size_bytes
+        try:
+            for i in range(nfiles):
+                papq.write_table(li.slice(i * per, per), os.path.join(
+                    pq_dir, f"part-{i:02d}.parquet"))
+            # one scan task per file at every scale (small files would
+            # otherwise merge into one task and one partition)
+            self.dt.set_execution_config(scan_tasks_min_size_bytes=1)
+            (got, c), wall = timed(lambda: run_query(
+                lambda: self.tpch.q1(self.dt.read_parquet(
+                    os.path.join(pq_dir, "*.parquet")))))
+        finally:
+            self.dt.set_execution_config(scan_tasks_min_size_bytes=saved_min)
+            shutil.rmtree(pq_dir, ignore_errors=True)
+        leg.check(self.matches("q1", got), "q1 over parquet != oracle")
+        dispatches = (c.get("segment_dispatches", 0)
+                      + c.get("device_agg_dispatches", 0))
+        leg.check(dispatches >= nfiles,
+                  f"{dispatches} double-buffered dispatch(es) for {nfiles} "
+                  "partitions")
+        leg.counters_clean("q1 parquet", c, device_aggregations=nfiles)
+        leg.note(f"q1: smoke timing {wall:.2f}s over {nfiles} files")
+        return leg.finish()
+
+    # ------------------------------------------------------------- serving
+    def leg_serving(self) -> bool:
+        leg = Leg("serving")
+        rt = self.dt.ServingRuntime()
+        mix = ["q1", "q6", "q3", "q1", "q6", "q3", "q1", "q6"]
+        try:
+            t0 = time.perf_counter()
+            handles = [(name, rt.submit(self.build[name]())) for name in mix]
+            for name, h in handles:
+                out = h.result(timeout=900)
+                leg.check(self.matches(name, out.to_pydict()),
+                          f"{h.query_id} ({name}) != oracle")
+                bad = failure_counters(h.stats.snapshot()["counters"])
+                leg.check(not bad, f"{h.query_id} ({name}): failure "
+                                   f"counters {bad}")
+            wall = time.perf_counter() - t0
+            leg.check(rt.admission.shed_total == 0,
+                      f"{rt.admission.shed_total} submission(s) shed")
+            leg.note(f"{len(handles)} submissions in flight together, "
+                     f"{rt.admission.admitted_total} admitted, "
+                     f"{rt.admission.shed_total} shed; smoke timing "
+                     f"{wall:.2f}s")
+        finally:
+            rt.shutdown(timeout_s=30)
+        return leg.finish()
+
+    # -------------------------------------------------------------- resize
+    def leg_resize(self) -> bool:
+        import numpy as np
+        from benchmarks import laion
+
+        from daft_tpu import DataType, col, multimodal
+
+        leg = Leg("resize")
+        jax = self.jax
+        # 2560 = one full 2048-image device chunk plus a padded tail
+        n = 2560 if self.args.scale >= 1 else 96
+        rng = np.random.RandomState(self.args.seed)
+        blocks = rng.randint(0, 256, (n, 6, 6, 3), dtype=np.uint8)
+        imgs = np.repeat(np.repeat(blocks, 16, axis=1), 16, axis=2)
+        series = multimodal.image_series_from_arrays(list(imgs), "img").cast(
+            DataType.image("RGB", 96, 96))
+        df = self.dt.from_pydict({"img": series})
+        q = (df.select(col("img").image.resize(224, 224).alias("r"))
+             .select(col("r").cast(DataType.tensor(
+                 DataType.uint8(), (224, 224, 3))).alias("t")))
+        out, wall = timed(q.collect)
+        got = laion.frame_tensors(out, 224)
+        want = np.clip(np.rint(np.asarray(jax.device_get(jax.image.resize(
+            jax.numpy.asarray(imgs.astype(np.float32)),
+            (n, 224, 224, 3), method="bilinear")))), 0, 255).astype(np.uint8)
+        diff = np.abs(got.astype(np.int16) - want.astype(np.int16))
+        # the +-1-count gate of benchmarks/laion.run_rung
+        leg.check(float(diff.mean()) <= 0.5 and int(diff.max()) <= 2,
+                  f"resize off the jax.image.resize reference: mean "
+                  f"{diff.mean():.4f} max {diff.max()}")
+        jitted = multimodal._RS_JIT is not None
+        if jax.default_backend() != "cpu":
+            leg.check(jitted, "the jitted resize branch did not run")
+        leg.note(f"{n} images 96x96 -> 224x224, jitted branch ran: {jitted}, "
+                 f"mean |diff| {diff.mean():.4f}, max {diff.max()}; smoke "
+                 f"timing {wall:.2f}s")
+        return leg.finish()
+
+    # ---------------------------------------------------------------- mesh
+    def leg_mesh(self) -> bool:
+        from __graft_entry__ import run_multichip_steps
+
+        from daft_tpu import col
+        from daft_tpu.parallel import default_mesh
+
+        leg = Leg("mesh")
+        jax, dt, tpch = self.jax, self.dt, self.tpch
+        n = len(jax.devices())
+        _, wall = timed(lambda: run_multichip_steps(default_mesh(n)))
+        leg.note(f"run_multichip_steps on {n} device(s): smoke timing "
+                 f"{wall:.2f}s")
+        # an n-way hash repartition of the fact table over the mesh, then
+        # the query (q3's sort over its groups is a global range shuffle)
+        builds = {
+            "q1": lambda: tpch.q1(self.li.repartition(
+                n, col("l_returnflag"), col("l_linestatus"))),
+            "q3": lambda: tpch.q3(self.cust, self.orders,
+                                  self.li.repartition(n, col("l_orderkey"))),
+        }
+        for name, build in builds.items():
+            dt.set_runner_native()
+            native, _ = run_query(build)
+            dt.set_runner_mesh()
+            try:
+                (got, c), wall = timed(lambda: run_query(build))
+            finally:
+                dt.set_runner_native()
+            leg.check(self.matches(name, got), f"{name} on mesh != oracle")
+            leg.check(tpch.parity(got, native, self.rtol),
+                      f"{name} on mesh != NativeRunner")
+            # (a tripped collective breaker is one of the failure counters)
+            leg.counters_clean(f"{name} mesh", c, device_shuffles=1)
+            leg.note(f"{name}: smoke timing {wall:.2f}s")
+        for d in jax.devices():
+            ms = d.memory_stats() or {}
+            leg.note(f"device {d.id}: bytes_in_use={ms.get('bytes_in_use')} "
+                     f"peak_bytes_in_use={ms.get('peak_bytes_in_use')}")
+        return leg.finish()
+
+
+def peak_hbm(jax) -> list:
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in jax.devices()]
+
+
+def cache_files(path) -> int:
+    if not path or not os.path.isdir(path):
+        return 0
+    return sum(len(files) for _, _, files in os.walk(path))
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--scale", type=float, default=1.0,
+                    help="TPC-H scale factor (1.0 = 6.0M lineitem rows)")
+    ap.add_argument("--seed", type=int, default=42)
+    ap.add_argument("--out", default=os.path.join(HERE, "chiprun_out",
+                                                  "chip_smoke"),
+                    help="directory for the scan leg's Parquet files")
+    ap.add_argument("--cpu", action="store_true",
+                    help="run on 8 virtual CPU devices (tier-1 tests); "
+                         "without it anything but a TPU is refused")
+    args = ap.parse_args(argv)
+
+    if args.cpu:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        flags = os.environ.get("XLA_FLAGS", "")
+        if "xla_force_host_platform_device_count" not in flags:
+            os.environ["XLA_FLAGS"] = (
+                flags + " --xla_force_host_platform_device_count=8").strip()
+    import jax
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    if dev.platform != "tpu" and not args.cpu:
+        print(f"chip_smoke: refused — jax found platform {dev.platform!r}, "
+              "not a TPU (tier-1 runs pass --cpu)", file=sys.stderr)
+        return 2
+
+    sys.path.insert(0, HERE)
+    try:
+        import daft_tpu as dt
+        from daft_tpu import native
+        from daft_tpu.kernels.compile_cache import configure_compile_cache
+    except ImportError as e:
+        print(f"chip_smoke: refused — the engine is not beside this script "
+              f"({e})", file=sys.stderr)
+        return 2
+
+    cache_dir = configure_compile_cache()
+    files_start = cache_files(cache_dir)
+    print(f"chip_smoke: jax={jax.__version__} platform={dev.platform} "
+          f"device_kind={dev.device_kind!r} devices={device['count']} "
+          f"x64={bool(jax.config.jax_enable_x64)} compile_cache={cache_dir} "
+          f"native={native.available()}")
+    os.makedirs(args.out, exist_ok=True)
+    dt.set_execution_config(
+        use_device_kernels=True,
+        # the device threshold shrinks with the data, so the operators that
+        # engage at SF1 engage at a test scale too (4096 = the default)
+        device_min_rows=max(8, int(4096 * min(1.0, args.scale))),
+        # every run must execute: the second run of a query checks the
+        # resident buffers, not a replayed result
+        enable_result_cache=False)
+
+    smoke = Smoke(args, jax)
+    legs = [smoke.leg_resident, smoke.leg_sql, smoke.leg_scan,
+            smoke.leg_serving, smoke.leg_resize]
+    if device["count"] >= 2:
+        legs.append(smoke.leg_mesh)
+    failed = []
+    for leg in legs:
+        name = leg.__name__[len("leg_"):]
+        try:
+            ok = leg()
+        except Exception:  # report it, go on to the next leg, exit non-zero
+            traceback.print_exc(file=sys.stdout)
+            print(f"[{name}] FAIL  (raised)")
+            ok = False
+        if not ok:
+            failed.append(name)
+        print(f"    {name}: peak_bytes_in_use so far {peak_hbm(jax)}",
+              flush=True)
+    if device["count"] < 2:
+        print("mesh: skipped (1 device)")
+
+    print(f"footer: peak_bytes_in_use per device {peak_hbm(jax)}; compile cache "
+          f"files {files_start} at start, {cache_files(cache_dir)} at end; "
+          f"failed legs: {failed or 'none'}")
+    dt.shutdown()
+    print(json.dumps({"ok": not failed, "device": device}))
+    return 0 if not failed else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

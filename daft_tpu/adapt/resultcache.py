@@ -143,7 +143,7 @@ RESULT_CACHE = SubplanResultCache()
 # float-affecting knobs: the only config under which "byte-identical at
 # every knob setting" does not hold (reduced-precision device sums)
 _CFG_KEY_FIELDS = ("use_device_kernels", "device_reduced_precision",
-                   "use_pallas_segment_sums", "use_pallas_deep_fusion")
+                   "use_pallas_segment_sums")
 
 
 def _chain_over_scan(op) -> Optional[Tuple[list, object]]:

@@ -170,11 +170,6 @@ class ExecutionConfig:
     # instead of K scatter-based segment_sum lowerings. Same float32
     # accumulation contract as device_reduced_precision.
     use_pallas_segment_sums: bool = True
-    # deep fusion: predicate + derived float-sum columns evaluated INSIDE
-    # the pallas kernel (no pre-masked (n,K) HBM intermediate). Off by
-    # default until the device measurement (bench q1_deep_pallas_vs_composed)
-    # proves it wins — the r4 verdict's "keep it only if it wins" rule.
-    use_pallas_deep_fusion: bool = False
     # query deadline: the runner converts this to an absolute deadline at
     # run start (ONE deadline across all AQE stages), checked cooperatively
     # in the morsel loop and at pipeline breakers; expiry raises
@@ -371,8 +366,7 @@ class ExecutionConfig:
     # device circuit breaker (execution.DeviceHealth): after this many
     # CONSECUTIVE device-kernel failures the breaker opens and every
     # device-eligible partition routes straight to the host path (one trip,
-    # not one failure tax per partition — the BENCH_r05 tpu_unreachable
-    # lesson) ...
+    # not one failure tax per partition) ...
     device_breaker_threshold: int = 3
     # ... until the cooldown elapses, after which ONE probe partition tries
     # the device again: success re-closes the breaker, failure re-opens it.

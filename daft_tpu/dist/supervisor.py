@@ -185,6 +185,17 @@ class _WorkerHandle:
         self.drained = False
 
 
+def _worker_env(root: str) -> dict:
+    """The environment a worker process starts in. Workers are host-path
+    processes and a chip belongs to one process at a time: whatever the
+    driver's JAX_PLATFORMS says, a worker gets the CPU platform, or N
+    workers would race the driver for the accelerator."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 def _repo_root() -> str:
     import daft_tpu
 
@@ -315,10 +326,8 @@ class WorkerPool:
             if self._closed:
                 raise DaftTransientError("worker pool is shut down")
         faults.check("worker.spawn")
-        env = dict(os.environ)
         root = _repo_root()
-        env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env = _worker_env(root)
         proc = subprocess.Popen(
             [sys.executable, "-m", "daft_tpu.dist.worker",
              "127.0.0.1", str(self._port), str(w.wid), self._token],

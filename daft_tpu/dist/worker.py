@@ -376,7 +376,7 @@ def main(argv) -> int:
 
 
 if __name__ == "__main__":
-    # workers compute on the host path by default: a spawned worker must
-    # never race the driver for the accelerator (override to opt in)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # workers compute on the host path: a chip belongs to one process at a
+    # time, so a worker must never race the driver for the accelerator
+    os.environ["JAX_PLATFORMS"] = "cpu"
     sys.exit(main(sys.argv[1:]))

@@ -78,8 +78,9 @@ class ResourceAccountant:
 
     def __init__(self, cpus: float, gpus, memory_bytes: Optional[int]):
         """gpus may be a float or a zero-arg callable resolved on FIRST use —
-        counting accelerators touches the jax backend, which host-only
-        queries must never do (a wedged device link would hang them)."""
+        counting accelerators initializes the jax backend, which host-only
+        queries must never pay for (and which, in a process that is not
+        meant to hold the chip, would take it)."""
         self.total_cpus = cpus
         self._gpu_src = gpus
         self._gpus_resolved: Optional[float] = (
@@ -140,12 +141,18 @@ class ResourceAccountant:
 
 
 def _accelerator_count() -> int:
-    """Non-CPU jax devices on this host (0 on a CPU-only test mesh)."""
+    """Non-CPU jax devices on this host (0 on a CPU-only test mesh). A
+    backend that fails to initialize also counts 0 — the admission check
+    then refuses accelerator requests — but says why."""
     try:
         import jax
 
         return sum(1 for d in jax.devices() if d.platform != "cpu")
-    except Exception:
+    except Exception as e:
+        from .obs.log import get_logger
+
+        get_logger("device").warning(
+            "accelerator_count_failed", error=f"{type(e).__name__}: {e}")
         return 0
 
 
@@ -174,6 +181,11 @@ class RuntimeStats:
         # fingerprint -> [rows, bytes] accumulated by tagged exchanges/
         # joins, folded into the process history at query end
         self.fdo_obs: Dict[str, list] = {}
+        # the first "site: Type: message" a device-path attempt raised in
+        # this query (note_device_error) — the host path answered, so this
+        # is the only place the cause survives
+        self.device_error: Optional[str] = None
+        self._device_errors_logged: set = set()
 
     def cancel(self) -> None:
         """Stop the query this handle is attached to at the next partition
@@ -201,6 +213,26 @@ class RuntimeStats:
         with self._lock:
             if n > self.counters.get(key, 0):
                 self.counters[key] = n
+
+    def note_device_error(self, site: str, exc: BaseException) -> None:
+        """A device-path attempt raised and the host path is about to
+        answer in its place: count it (``device_attempt_errors``), keep the
+        first ``site: Type: message`` for explain_analyze / the QueryRecord,
+        and log one structured line per distinct error of this query."""
+        msg = f"{type(exc).__name__}: {exc}"
+        with self._lock:
+            self.counters["device_attempt_errors"] = (
+                self.counters.get("device_attempt_errors", 0) + 1)
+            if self.device_error is None:
+                self.device_error = f"{site}: {msg}"[:400]
+            first = (site, msg) not in self._device_errors_logged
+            if first:
+                self._device_errors_logged.add((site, msg))
+        if first:
+            from .obs.log import get_logger
+
+            get_logger("device").warning("device_attempt_error", site=site,
+                                         error=msg[:2000])
 
     def fdo_observe(self, site_fp: str, rows: int, nbytes: int) -> None:
         """Accumulate one FDO site observation (what actually flowed
@@ -262,7 +294,7 @@ class RuntimeStats:
 
     def io_breakdown(self) -> Dict[str, float]:
         """The io_wait-vs-compute split plus prefetch hit/miss and spill
-        write/read throughput — the explain_analyze / bench-snapshot view
+        write/read throughput — the explain_analyze / bench view
         of the pipelined IO layer."""
         with self._lock:
             c = dict(self.counters)
@@ -287,8 +319,7 @@ class RuntimeStats:
 
     def op_throughput(self) -> Dict[str, Dict[str, float]]:
         """Per-operator rows/sec and bytes/sec over accumulated wall time —
-        the explain_analyze / bench-snapshot throughput view (VERDICT item 1:
-        ready to fire on first real-TPU contact)."""
+        the explain_analyze / bench throughput view."""
         with self._lock:
             out: Dict[str, Dict[str, float]] = {}
             for name, ns in self.op_wall_ns.items():
@@ -315,8 +346,8 @@ class DeviceHealth:
     """Circuit breaker for one accelerator resource (device kernels, mesh
     collectives). Closed = normal; after `threshold` CONSECUTIVE failures it
     opens and allow() answers False — callers route straight to the host
-    path instead of re-paying the failure per partition (the BENCH_r05
-    tpu_unreachable tax). After `cooldown_s` the breaker goes half-open and
+    path instead of re-paying the failure per partition. After
+    `cooldown_s` the breaker goes half-open and
     lets exactly ONE probe attempt through: success re-closes it, failure
     re-opens it for another cooldown.
 
@@ -668,27 +699,36 @@ class ExecutionContext:
         return False
 
     def _device_eligible(self, part: MicroPartition) -> bool:
-        return (self.cfg.use_device_kernels
-                and (part.num_rows_or_none() or 0) >= self.cfg.device_min_rows
-                and self._device_allowed())
+        if not self.cfg.use_device_kernels:
+            return False
+        n = part.num_rows_or_none()
+        if n is None and not self.foreign_owned(part):
+            # a scan partition with a pushed-down filter has no count until
+            # it is read — which the device and the host path both do next.
+            # Treating "unknown" as 0 sent every such scan to the host.
+            n = len(part.table())
+        return (n or 0) >= self.cfg.device_min_rows and self._device_allowed()
 
     def _device_attempt(self, fn, launch: bool = False):
         """Run one device-path attempt under the fault registry + breaker.
-        An exception records a breaker failure and returns None (the device
-        layer's decline convention); a None result is a decline (probe slot
-        released, breaker untouched). A non-None result records success —
+        An exception is reported (RuntimeStats.note_device_error), records
+        a breaker failure and returns None (the device layer's decline
+        convention); a None result is a decline (probe slot released,
+        breaker untouched). A non-None result records success —
         unless `launch` is set, in which case the caller owns the outcome
         (async dispatch: the launch succeeding says nothing about the
         deferred computation, whose resolver records for real)."""
         from . import faults
+        from .kernels.compile_cache import configure_compile_cache
 
+        configure_compile_cache()
         prof = self.stats.profiler
         t0 = time.perf_counter_ns() if prof.armed else 0
         try:
             faults.check("device.kernel", self.stats)
             out = fn()
-        except Exception:
-            self.device_health.record_failure(self.stats)
+        except Exception as e:
+            self._device_failed("device.attempt", e)
             return None
         finally:
             if prof.armed:
@@ -700,6 +740,13 @@ class ExecutionContext:
         elif not launch:
             self.device_health.record_success(self.stats)
         return out
+
+    def _device_failed(self, site: str, exc: BaseException) -> None:
+        """A device attempt (launch or deferred resolve) raised: report the
+        exception and inform the breaker. The caller falls back to the host
+        path — the answer stays right, the cause stays visible."""
+        self.stats.note_device_error(site, exc)
+        self.device_health.record_failure(self.stats)
 
     def foreign_owned(self, part: MicroPartition) -> bool:
         """True when this process must not materialize `part` (another host
@@ -765,11 +812,11 @@ class ExecutionContext:
         def finish() -> MicroPartition:
             try:
                 out = part._wrap(resolve())
-            except Exception:
+            except Exception as e:
                 # the partition was NOT computed on device after all: keep
                 # the counters truthful (same attribution the synchronous
                 # path's fallback produces)
-                self.device_health.record_failure(self.stats)
+                self._device_failed("device.projection", e)
                 self.stats.bump("device_projections", -1)
                 self.stats.bump("device_projection_fallbacks")
                 self.stats.bump("host_projections")
@@ -854,10 +901,10 @@ class ExecutionContext:
         def finish() -> MicroPartition:
             try:
                 out = program.assemble_device(resolve())
-            except Exception:
+            except Exception as e:
                 # the chain was NOT computed on device after all: keep the
                 # counters truthful, inform the breaker, host pass takes over
-                self.device_health.record_failure(self.stats)
+                self._device_failed("device.fused_map", e)
                 self._bump_fused_device(program, -1)
                 self.stats.bump("device_fused_map_fallbacks")
                 return self._eval_fused_host(part, program)
@@ -950,10 +997,10 @@ class ExecutionContext:
         def finish() -> MicroPartition:
             try:
                 out = resolve()
-            except Exception:
+            except Exception as e:
                 # the scatter was NOT computed on device: truthful counters,
                 # breaker informed, host build takes over
-                self.device_health.record_failure(self.stats)
+                self._device_failed("device.sketch", e)
                 self.stats.bump("device_sketch_builds", -1)
                 self.stats.bump("device_sketch_fallbacks")
                 return self._eval_agg_host(part, aggregations, groupby,
@@ -1043,9 +1090,9 @@ class ExecutionContext:
         def finish() -> MicroPartition:
             try:
                 out = resolve()
-            except Exception:
+            except Exception as e:
                 out = None
-                self.device_health.record_failure(self.stats)
+                self._device_failed("device.agg", e)
             if out is not None:
                 self.device_health.record_success(self.stats)
                 return MicroPartition.from_table(out)
@@ -1101,9 +1148,9 @@ class ExecutionContext:
             with self.stats.profiler.span("fuse.segment", kind="phase"):
                 try:
                     out = resolve()
-                except Exception:
+                except Exception as e:
                     out = None
-                    self.device_health.record_failure(self.stats)
+                    self._device_failed("fuse.segment", e)
                 if out is not None:
                     self.device_health.record_success(self.stats)
                     # ONE boundary crossed resident: the map→agg Arrow
@@ -1235,8 +1282,8 @@ class ExecutionContext:
         def finish() -> MicroPartition:
             try:
                 res = launch()
-            except Exception:
-                self.device_health.record_failure(self.stats)
+            except Exception as e:
+                self._device_failed("device.join", e)
                 self.stats.bump("device_join_fallbacks")
                 self.stats.bump("host_joins")
                 return lpart.hash_join(rpart, left_on, right_on, how, suffix)
@@ -1313,8 +1360,8 @@ class ExecutionContext:
                 out = resolve()
                 mask = out._columns[0]
                 result = part._wrap(part.table().filter_with_mask(mask))
-            except Exception:
-                self.device_health.record_failure(self.stats)
+            except Exception as e:
+                self._device_failed("device.filter", e)
                 self.stats.bump("device_filters", -1)
                 self.stats.bump("device_filter_fallbacks")
                 self.stats.bump("host_filters")

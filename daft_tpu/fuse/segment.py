@@ -19,12 +19,12 @@ Zero Arrow materialization happens between the map and the aggregation:
 the ``FusedMapOp → Aggregate`` handoff that previously round-tripped
 Arrow↔DeviceArray is elided (counted as ``device_handoffs_elided``).
 
-Sharding/donation contract: consecutive programs run on the same default
-device with identical size buckets, so the map outputs are consumed by the
-aggregation with no resharding; when every intermediate is provably fresh
-(no bare column passthrough that could alias the partition's residency
-cache) and the backend is not CPU, the intermediate env is donated
-(``donate_argnums``) so XLA reuses its HBM for the reduction outputs.
+Sharding contract: consecutive programs run on the same default device
+with identical size buckets, so the map outputs are consumed by the
+aggregation with no resharding. The intermediates are not donated: the
+aggregation's outputs are group-length and its inputs row-length, so XLA
+has no output to alias a donated input to (on the v5e the donation was
+reported "not usable" for every buffer and did nothing).
 
 Invariants (tests/test_segment.py): results are byte-identical with
 ``cfg.device_residency`` off; ANY segment-compile or resident-run failure
@@ -120,22 +120,18 @@ class SegmentProgram:
     - ``gb_inputs``: group keys remapped to the INPUT table's columns —
       group codes compute over the unfiltered input (rows stay aligned with
       the mask lanes; the pruning output restores filtered-first-occurrence
-      group order, exactly the staged FusedFilterAggregate semantics);
-    - ``donation_safe``: True when every resident intermediate is provably
-      fresh (no bare column passthrough whose jitted identity could hand
-      back the partition's residency-cache buffer) — the gate for
-      ``donate_argnums`` on the aggregation program.
+      group order, exactly the staged FusedFilterAggregate semantics).
 
     The per-binding sharding key of a compiled segment is
     (nodes, inter_schema, input_names, kinds, modes, segment bucket,
-    x64 mode, donate) — ``_compile_agg``'s cache key — so repeat traffic
+    x64 mode) — ``_compile_agg``'s cache key — so repeat traffic
     with the same shape and size bucket reuses ONE XLA executable, and the
     plan cache (adapt/plancache.py) serves the whole SegmentProgram warm
     with zero translate/segment-compile calls."""
 
     __slots__ = ("seg_exprs", "input_schema", "inter_schema", "specs",
                  "child_nodes", "pred_node", "input_names", "kinds", "modes",
-                 "gb_inputs", "has_groupby", "n_masks", "donation_safe")
+                 "gb_inputs", "has_groupby", "n_masks")
 
     def __init__(self, seg_exprs, input_schema, inter_schema, specs,
                  child_nodes, pred_node, input_names, kinds, modes,
@@ -152,8 +148,6 @@ class SegmentProgram:
         self.gb_inputs = list(gb_inputs)
         self.has_groupby = bool(gb_inputs)
         self.n_masks = n_masks
-        self.donation_safe = all(
-            not isinstance(_peel(e._node), Column) for e in seg_exprs)
 
 
 def _map_program_for(child: PhysicalOp) -> Optional[FusedProgram]:
@@ -447,18 +441,9 @@ def run_segment_async(table, prog: SegmentProgram,
     gbk = max(16, 1 << (num_groups - 1).bit_length())
 
     use_pallas = bool(getattr(cfg, "use_pallas_segment_sums", False))
-    use_deep = bool(getattr(cfg, "use_pallas_deep_fusion", False))
-    # donation: only fresh intermediates (donation_safe), never on the CPU
-    # backend (jax warns + no-ops), and never when XLA could see one buffer
-    # twice (duplicate outputs would be a double donation)
-    donate = prog.donation_safe and jax.default_backend() != "cpu"
-    if donate:
-        bufs = [id(a) for vm in env2.values() for a in vm]
-        donate = len(set(bufs)) == len(bufs)
-
     run = _compile_agg(prog.child_nodes, prog.pred_node, prog.inter_schema,
                        prog.input_names, prog.kinds, prog.modes, gbk,
-                       use_pallas, use_deep, donate=donate)
+                       use_pallas)
 
     nkey = ("nrows", n)
     n_dev = stage_cache.get(nkey) if stage_cache is not None else None

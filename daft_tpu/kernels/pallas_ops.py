@@ -2,10 +2,22 @@
 
 The TPC-H-Q1-shaped pipeline (filter mask -> K weighted segment sums over
 small group cardinality) is one fused MXU program here: each grid step loads a
-row block into VMEM, forms the masked one-hot group matrix, and accumulates
-`one_hot.T @ values` into a (groups, K) VMEM accumulator — so ALL K aggregate
-columns ride a single data pass through the 128x128 systolic array, instead of
-K separate scatter-based `segment_sum` lowerings touching HBM K times.
+block of rows into VMEM, forms the one-hot group matrix, and accumulates its
+contraction with the values into a (K, groups) VMEM accumulator — so ALL K
+aggregate columns ride a single data pass through the systolic array, instead
+of K separate `segment_sum` lowerings touching HBM K times.
+
+Layout: rows run along the LANE axis everywhere — codes are (1, n), values
+are (K, n), the one-hot is (groups, block). A (n, 1) or (n, K) operand is
+laid out by XLA in (8, 128) tiles, i.e. padded 128x (or 128/K x) in HBM:
+at SF1's 8M-row bucket that is 4 GB per column, which the v5e refused to
+allocate. With rows on lanes nothing pads by more than the 8-sublane tile,
+and the contraction is the MXU-native `A @ B^T` (no in-kernel transpose).
+
+The multiply pins Precision.HIGHEST: f32 operands at the TPU's default
+precision take one bf16 pass, which put Q1's money sums 1e-6..1e-4 off the
+oracle on the chip (interpret mode on the CPU never shows it). The Kahan step
+guards the accumulation ACROSS grid steps, not the multiply.
 
 Counts come from an exact host bincount (float32 one-hot accumulation would
 silently stall at 2^24 rows per group); the kernel carries the K weighted
@@ -32,14 +44,18 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-_BLOCK_ROWS = 1024
-
-# trace-time engagement counter: bumped when a deep-fused kernel is BUILT
-# into a compiled agg program (bench asserts the path actually engaged)
-DEEP_FUSED_TRACES = [0]
+# smallest row block (one lane tile); every size bucket is a multiple of it
+MIN_BLOCK_ROWS = 128
 
 
-def _kernel(codes_ref, mask_ref, vals_ref, out_ref, comp_ref, *, num_groups: int):
+def block_rows(num_groups: int, n: int) -> int:
+    """Rows per grid step: as many as keep the (groups, block) f32 one-hot
+    at 2 MiB of VMEM, between one lane tile and 8192, never past n. Powers
+    of two throughout, so the block divides every size bucket >= it."""
+    return min(n, max(MIN_BLOCK_ROWS, min(8192, (1 << 19) // num_groups)))
+
+
+def _kernel(codes_ref, vals_ref, out_ref, comp_ref, *, num_groups: int):
     step = pl.program_id(0)
 
     @pl.when(step == 0)
@@ -47,12 +63,14 @@ def _kernel(codes_ref, mask_ref, vals_ref, out_ref, comp_ref, *, num_groups: int
         out_ref[:] = jnp.zeros_like(out_ref)
         comp_ref[:] = jnp.zeros_like(comp_ref)
 
-    codes = codes_ref[:]  # (B, 1) int32
-    mask = mask_ref[:]    # (B, 1) float32 (0/1)
-    group_ids = jax.lax.broadcasted_iota(jnp.int32, (1, num_groups), 1)
-    one_hot = (codes == group_ids).astype(jnp.float32) * mask  # (B, G)
-    # (G, B) @ (B, K) -> (G, K) on the MXU
-    block = jnp.dot(one_hot.T, vals_ref[:], preferred_element_type=jnp.float32)
+    codes = codes_ref[:]  # (1, B) int32
+    group_ids = jax.lax.broadcasted_iota(jnp.int32, (num_groups, 1), 0)
+    one_hot_t = (group_ids == codes).astype(jnp.float32)  # (G, B)
+    # (K, B) x (G, B)^T -> (K, G): both operands contract their lane axis
+    block = jax.lax.dot_general(
+        vals_ref[:], one_hot_t, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     # Kahan-compensated accumulation ACROSS grid steps: naive float32 adds
     # drift past 1e-6 relative on TPC-H-scale money sums (the segment_sum
     # route this kernel replaces compensates too, device.py _sum_kahan)
@@ -63,97 +81,34 @@ def _kernel(codes_ref, mask_ref, vals_ref, out_ref, comp_ref, *, num_groups: int
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "interpret"))
-def _masked_segment_sums_padded(codes, mask, vals, num_groups: int, interpret: bool):
-    n, k = vals.shape
-    grid = n // _BLOCK_ROWS
+def segment_sums_lanes(codes, vals, num_groups: int, interpret: bool):
+    """codes (1, n) int32, vals (K, n) float32 with every row that must not
+    count already zeroed -> sums (K, num_groups) float32. n is a power of
+    two >= MIN_BLOCK_ROWS (a size bucket).
+
+    The matmul runs on whole tiles: K rounds up to the 8-sublane tile with
+    zero rows and the groups to the 128-lane tile with codes nobody has, so
+    Mosaic sees an aligned (K8, B) x (G128, B)^T and unmasked stores."""
+    k, n = vals.shape
+    k8 = -(-k // 8) * 8
+    g = -(-num_groups // 128) * 128
+    if k8 != k:
+        vals = jnp.pad(vals, ((0, k8 - k), (0, 0)))
+    b = block_rows(g, n)
     sums, _comp = pl.pallas_call(
-        functools.partial(_kernel, num_groups=num_groups),
-        out_shape=(jax.ShapeDtypeStruct((num_groups, k), jnp.float32),
-                   jax.ShapeDtypeStruct((num_groups, k), jnp.float32)),
-        grid=(grid,),
+        functools.partial(_kernel, num_groups=g),
+        out_shape=(jax.ShapeDtypeStruct((k8, g), jnp.float32),
+                   jax.ShapeDtypeStruct((k8, g), jnp.float32)),
+        grid=(n // b,),
         in_specs=[
-            pl.BlockSpec((_BLOCK_ROWS, 1), lambda i: (i, 0)),
-            pl.BlockSpec((_BLOCK_ROWS, 1), lambda i: (i, 0)),
-            pl.BlockSpec((_BLOCK_ROWS, k), lambda i: (i, 0)),
+            pl.BlockSpec((1, b), lambda i: (0, i)),
+            pl.BlockSpec((k8, b), lambda i: (0, i)),
         ],
-        out_specs=(pl.BlockSpec((num_groups, k), lambda i: (0, 0)),
-                   pl.BlockSpec((num_groups, k), lambda i: (0, 0))),
+        out_specs=(pl.BlockSpec((k8, g), lambda i: (0, 0)),
+                   pl.BlockSpec((k8, g), lambda i: (0, 0))),
         interpret=interpret,
-    )(codes, mask, vals)
-    return sums
-
-
-def build_fused_expr_sums(pred_fn, child_fns, names, num_groups: int,
-                          k: int, interpret: bool):
-    """Deep-fused Q1-shaped kernel (r4 verdict weak #5): the filter
-    PREDICATE and the K derived float-sum columns are evaluated INSIDE the
-    pallas body from the raw staged columns, per VMEM block — the XLA
-    composition materializes a pre-masked (n, K) float32 matrix in HBM as
-    the pallas operand (one write + one read of n*K*4 bytes that this
-    kernel never pays). `pred_fn`/`child_fns` are the expression compiler's
-    closures (pure jnp over {name: (values, valid)}), so the kernel body is
-    generated from the SAME compiled expressions as the host/XLA paths —
-    parity by construction.
-
-    Returns fn(codes [n,1] i32, inb [n,1] bool, *cols interleaved
-    (values [n,1], valid [n,1]) per name) -> sums (num_groups, K) f32.
-    n must be a multiple of _BLOCK_ROWS."""
-
-    def kernel(codes_ref, inb_ref, *refs):
-        col_refs = refs[:-2]
-        out_ref, comp_ref = refs[-2], refs[-1]
-        step = pl.program_id(0)
-
-        @pl.when(step == 0)
-        def _zero():
-            out_ref[:] = jnp.zeros_like(out_ref)
-            comp_ref[:] = jnp.zeros_like(comp_ref)
-
-        env = {}
-        for j, name in enumerate(names):
-            env[name] = (col_refs[2 * j][:][:, 0],
-                         col_refs[2 * j + 1][:][:, 0])
-        inb = inb_ref[:][:, 0]
-        if pred_fn is not None:
-            pv, pm = pred_fn(env)
-            sel = pv & pm & inb  # invalid predicate rows filter out (WHERE)
-        else:
-            sel = inb
-        cols = []
-        for fn in child_fns:
-            v, m = fn(env)
-            cols.append(jnp.where(m & sel, v.astype(jnp.float32),
-                                  jnp.float32(0)))
-        vk = jnp.stack(cols, axis=1)  # (B, K) in VMEM
-        codes = codes_ref[:]          # (B, 1)
-        group_ids = jax.lax.broadcasted_iota(jnp.int32, (1, num_groups), 1)
-        one_hot = ((codes == group_ids).astype(jnp.float32)
-                   * sel.astype(jnp.float32)[:, None])
-        block = jnp.dot(one_hot.T, vk, preferred_element_type=jnp.float32)
-        y = block - comp_ref[:]
-        t = out_ref[:] + y
-        comp_ref[:] = (t - out_ref[:]) - y
-        out_ref[:] = t
-
-    def call(codes, inb, *cols):
-        grid = codes.shape[0] // _BLOCK_ROWS
-        blk2 = pl.BlockSpec((_BLOCK_ROWS, 1), lambda i: (i, 0))
-        sums, _comp = pl.pallas_call(
-            kernel,
-            out_shape=(jax.ShapeDtypeStruct((num_groups, k), jnp.float32),
-                       jax.ShapeDtypeStruct((num_groups, k), jnp.float32)),
-            grid=(grid,),
-            in_specs=[blk2, blk2] + [blk2] * len(cols),
-            out_specs=(pl.BlockSpec((num_groups, k), lambda i: (0, 0)),
-                       pl.BlockSpec((num_groups, k), lambda i: (0, 0))),
-            interpret=interpret,
-        )(codes, inb, *cols)
-        # bump AFTER the pallas trace succeeded: a body/BlockSpec failure
-        # falls back to the batched kernel and must not read as engagement
-        DEEP_FUSED_TRACES[0] += 1
-        return sums
-
-    return call
+    )(codes, vals)
+    return sums[:k, :num_groups]
 
 
 def masked_segment_sums(codes: np.ndarray, mask: Optional[np.ndarray],
@@ -176,24 +131,24 @@ def masked_segment_sums(codes: np.ndarray, mask: Optional[np.ndarray],
         return np.zeros((num_groups, k)), np.zeros(num_groups, np.int64)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    m = np.ones(n, np.float32) if mask is None else mask.astype(np.float32)
     # counts must be exact (float32 accumulation stalls at 2^24), so they come
     # from a host bincount; the kernel carries only the K weighted sums
     if mask is None:
         counts = np.bincount(codes, minlength=num_groups).astype(np.int64)
+        vk = values.astype(np.float32)
     else:
         counts = np.bincount(codes[mask], minlength=num_groups).astype(np.int64)
-    # masked-out rows contribute nothing; also zero their values so NaN*0
-    # poisoning cannot leak through the matmul
-    vk = np.where(m[:, None] > 0, values, 0.0).astype(np.float32)
-    pad = (-n) % _BLOCK_ROWS
-    if pad:
-        codes = np.concatenate([codes, np.zeros(pad, codes.dtype)])
-        m = np.concatenate([m, np.zeros(pad, np.float32)])
-        vk = np.concatenate([vk, np.zeros((pad, k), np.float32)])
-    out = _masked_segment_sums_padded(
-        jnp.asarray(codes.astype(np.int32)[:, None]),
-        jnp.asarray(m[:, None]),
-        jnp.asarray(vk),
-        num_groups, interpret)
-    return np.asarray(jax.device_get(out)).astype(np.float64), counts
+        # masked-out rows contribute nothing: zero their values (which also
+        # keeps a masked NaN out of the matmul)
+        vk = np.where(mask[:, None], values, 0.0).astype(np.float32)
+    # pad to a power-of-two row count (zero values: padding adds nothing)
+    padded = MIN_BLOCK_ROWS
+    while padded < n:
+        padded <<= 1
+    codes_p = np.zeros((1, padded), np.int32)
+    codes_p[0, :n] = codes
+    vals_p = np.zeros((k, padded), np.float32)
+    vals_p[:, :n] = vk.T
+    out = segment_sums_lanes(jnp.asarray(codes_p), jnp.asarray(vals_p),
+                             num_groups, interpret)
+    return np.asarray(jax.device_get(out)).astype(np.float64).T, counts

@@ -330,6 +330,10 @@ def _rs_jitted():
         import jax
         import jax.numpy as jnp
 
+        from .kernels.compile_cache import configure_compile_cache
+
+        configure_compile_cache()
+
         @jax.jit
         def _rs(x, a, b):
             # HIGHEST matches jax.image.resize (its internal einsums pin

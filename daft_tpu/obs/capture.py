@@ -126,6 +126,12 @@ def render_runtime_stats(stats) -> str:
     if res:
         lines.append("")
         lines.append(res)
+    if counters.get("device_attempt_errors"):
+        lines.append("")
+        lines.append(
+            f"device errors: {counters['device_attempt_errors']} attempt(s) "
+            f"raised and were answered by the host path; first: "
+            f"{getattr(stats, 'device_error', None)}")
     if counters:
         lines.append("")
         lines.append("counters: " + ", ".join(

@@ -46,7 +46,8 @@ _EVENT_COUNTERS = (
     "device_breaker_trips", "device_breaker_reopens",
     "device_breaker_recoveries", "collective_breaker_trips",
     "collective_breaker_reopens", "collective_breaker_recoveries",
-    "faults_injected", "degraded_completions", "deadline_expired",
+    "faults_injected", "degraded_completions", "device_attempt_errors",
+    "deadline_expired",
     "prefetch_throttled", "preload_throttled", "spill_write_failures",
     "task_retries", "dispatch_backpressure_stalls",
     "task_redispatches", "worker_losses", "dist_local_fallbacks",
@@ -246,6 +247,11 @@ def build_record(query_id: str, fingerprint: str, plan_ops: Dict[str, int],
             "segment_compiles": counters.get("segment_compiles", 0),
             "segment_fallbacks": counters.get("segment_fallbacks", 0),
         }
+    if getattr(stats, "device_error", None):
+        # a device-path attempt raised and the host path answered: the
+        # query's outcome is still "ok", so this key is the only trace of
+        # the cause (count in events["device_attempt_errors"])
+        rec["device_error"] = stats.device_error
     if error is not None:
         rec["error_type"] = type(error).__name__
         rec["error_message"] = str(error)[:400]

@@ -35,17 +35,10 @@ MIN_CAPACITY = 128
 
 
 def _shard_map(body, mesh: Mesh, in_specs, out_specs):
-    """Version-compat shard_map: `jax.shard_map(..., check_vma=)` on new
-    jax, `jax.experimental.shard_map.shard_map(..., check_rep=)` on 0.4.x —
-    one accessor so every exchange kernel builds on either."""
-    try:
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    # check_vma off: the bodies index their per-shard [1, R, ...] views and
+    # return per-shard results; nothing here is replicated across the axis
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _scatter_to_slabs(bucket, valid, cols, n: int, capacity: int):

@@ -1,6 +1,6 @@
 """Plan-segment compiler (ISSUE 19): byte-identity with residency off
 across the dtype/null/breaker/streaming matrix, warm plan-cache reuse with
-zero segment compiles, donation safety, fuse.segment fault semantics
+zero segment compiles, stage-cache reuse, fuse.segment fault semantics
 (compile-time and runtime firing both degrade to the staged path, never a
 query failure), and the residency observability surfaces."""
 
@@ -195,33 +195,13 @@ class TestPlanCacheReuse:
 
 
 # ---------------------------------------------------------------------------
-# donation safety
+# residency cache across runs
 # ---------------------------------------------------------------------------
 
-class TestDonationSafety:
-    def test_derived_outputs_are_donation_safe(self, cfg):
-        phys = translate(optimize(_query("some")._plan), cfg)
-        (seg,) = _find_segments(phys)
-        # every resident column is computed by the segment (x, g, w are
-        # all derived) -> donating them can never invalidate a staged
-        # source buffer another query still holds
-        assert seg.program.donation_safe is True
-
-    def test_passthrough_outputs_are_not_donation_safe(self, cfg):
-        # an aggregation over a bare source column makes the staged input
-        # buffer itself a kernel argument: donating it would free a
-        # stage-cache entry out from under the partition
-        df = dt.from_arrow(_data("some")).into_partitions(2)
-        q = (df.select(col("v"), (col("u") * 3).alias("w"), col("k"))
-             .where(col("w") > 30)
-             .groupby("k").agg(col("v").sum().alias("sv")).sort("k"))
-        for seg in _find_segments(translate(optimize(q._plan), cfg)):
-            assert seg.program.donation_safe is False
-
+class TestStageCacheReuse:
     def test_stage_cache_survives_repeated_resident_runs(self, cfg):
-        # donation is CPU-disabled and gated on donation_safe, so running
-        # the same resident partitions twice must reuse the staged buffers
-        # (a donated-then-read buffer would fail or corrupt the rerun)
+        # running the same resident partitions twice must reuse the staged
+        # buffers: nothing a resident run does may consume them
         df = dt.from_arrow(_data("some")).into_partitions(2).collect()
 
         def run():
