@@ -42,6 +42,31 @@ DEVICE_COUNTERS = (
 )
 
 
+# where a query's host time went, by layer (daft_tpu/profile/timeline.py):
+# always on, so a smoke run shows stage, wait and gather time without a trace
+LAYER_COUNTERS = (
+    ("plan", "planning_wall_ns"), ("stage", "stage_ns"),
+    ("dispatch", "device_dispatch_ns"), ("wait", "device_wait_ns"),
+    ("gather", "gather_ns"), ("host", "op_self_host_ns"),
+)
+
+
+def layer_times(counters: dict) -> str:
+    """``plan 1ms stage 12ms ... staged 3.1MB in 2 columns ... compiles 0
+    (0ms)``: the layer counters of one query, for its printed line. A warm
+    query that stages columns lost its stage cache; one that compiles (and
+    for how long) met a shape the warm-up did not."""
+    parts = [f"{label} {counters.get(key, 0) / 1e6:.0f}ms"
+             for label, key in LAYER_COUNTERS]
+    parts.append(f"staged {counters.get('stage_bytes', 0) / 1e6:.1f}MB "
+                 f"in {counters.get('stage_columns', 0)} columns "
+                 f"gathered {counters.get('gather_bytes', 0) / 1e6:.1f}MB "
+                 f"compiles {counters.get('xla_compiles', 0)} "
+                 f"({counters.get('xla_compile_ns', 0) / 1e6:.0f}ms) "
+                 f"cache loads {counters.get('xla_cache_loads', 0)}")
+    return " ".join(parts)
+
+
 def failure_counters(counters: dict) -> dict:
     """Every counter that means a device path was refused, broke or was
     bypassed — all must stay zero."""
@@ -208,6 +233,7 @@ class Smoke:
             leg.check(d1 == d2, f"{name}: device counters moved between "
                                 f"runs: {d1} -> {d2}")
             leg.note(f"{name}: smoke timing cold {cold:.2f}s warm {warm:.2f}s")
+            leg.note(f"{name} warm: {layer_times(c2)}")
             self.api_counters[name] = d1
         return leg.finish()
 

@@ -463,6 +463,16 @@ def _has_write(plan) -> bool:
 
 def plan_query(plan, cfg, stats=None, optimized: bool = False,
                runner: str = "native"):
+    """``_plan_query`` inside a ``plan`` span (what ``planning_wall_ns``
+    times), when the query's profiler is armed."""
+    from ..profile import DISARMED
+
+    prof = DISARMED if stats is None else stats.profiler
+    with prof.span("plan", kind="phase"):
+        return _plan_query(plan, cfg, stats, optimized, runner)
+
+
+def _plan_query(plan, cfg, stats, optimized: bool, runner: str):
     """The runners' one planning entry point: FDO-informed optimize +
     translate + fuse, served from the plan cache when possible.
 

@@ -454,12 +454,11 @@ class DataFrame:
         self.stats.reset_cancel()  # a cancelled DataFrame stays retryable
         from .runners import partition_set_cache, plan_cache_key
 
-        cfg = get_context().execution_config
-        want = profile if profile is not None else cfg.enable_profiling
-        if want:
-            from .profile import Profiler
+        from .profile import arm_for_query
 
-            self.stats.profiler = Profiler(query_id=f"q-{id(self._plan):x}")
+        cfg = get_context().execution_config
+        # armed here, where the query begins, so planning is inside a span
+        want = arm_for_query(self.stats, f"q-{id(self._plan):x}", profile)
         cache = partition_set_cache()
         key = (plan_cache_key(self._plan)
                if cfg.enable_result_cache else None)

@@ -207,6 +207,33 @@ class RuntimeStats:
         with self._lock:
             self.counters[key] = self.counters.get(key, 0) + n
 
+    def bump_many(self, adds: Dict[str, int]) -> None:
+        """Several counters under one lock (a device frame's flush)."""
+        with self._lock:
+            counters = self.counters
+            for key, n in adds.items():
+                counters[key] = counters.get(key, 0) + n
+
+    # what a device attempt records inside an operator, by layer
+    # (profile/timeline.py); xla_compiles rides along so every finished
+    # query reads it, 0 included
+    _DEVICE_LAYER_NS = ("stage_ns", "device_dispatch_ns", "device_wait_ns",
+                        "gather_ns")
+
+    def fold_op_self_host(self) -> None:
+        """Query end: ``op_self_host_ns`` is the operators' self time less what
+        their device attempts recorded inside them — the host-operator
+        work proper. Set, not added: an AQE query folds once per stage over
+        shared stats, and the last fold covers them all. The layer
+        counters it reads default to 0, so a finished query always reads
+        them (a stage-cache hit records nothing, which is 0, not absent)."""
+        with self._lock:
+            c = self.counters
+            c.setdefault("xla_compiles", 0)
+            inside = sum(c.setdefault(k, 0) for k in self._DEVICE_LAYER_NS)
+            c["op_self_host_ns"] = max(
+                0, sum(self.op_wall_ns.values()) - inside)
+
     def bump_max(self, key: str, n: int) -> None:
         """Monotonic-max counter (channel high-water marks and the like):
         the stored value only ever ratchets up to ``n``."""
@@ -720,26 +747,40 @@ class ExecutionContext:
         deferred computation, whose resolver records for real)."""
         from . import faults
         from .kernels.compile_cache import configure_compile_cache
+        from .profile.timeline import DeviceFrame
 
         configure_compile_cache()
-        prof = self.stats.profiler
-        t0 = time.perf_counter_ns() if prof.armed else 0
-        try:
-            faults.check("device.kernel", self.stats)
-            out = fn()
-        except Exception as e:
-            self._device_failed("device.attempt", e)
-            return None
-        finally:
-            if prof.armed:
-                # the host-side cost of staging + launching (sync attempts
-                # include the kernel wall; async launches just the dispatch)
-                prof.phase("device_dispatch", time.perf_counter_ns() - t0)
+        # device_dispatch_ns: this attempt's wall less the staging (and, for
+        # a synchronous attempt, the waits and copies) recorded inside it
+        with DeviceFrame(self.stats, "dispatch", "device_dispatch_ns"):
+            try:
+                faults.check("device.kernel", self.stats)
+                out = fn()
+            except Exception as e:
+                self._device_failed("device.attempt", e)
+                return None
         if out is None:
             self.device_health.release_probe()
         elif not launch:
             self.device_health.record_success(self.stats)
         return out
+
+    def _device_resolve(self, resolve):
+        """Run a launched attempt's resolver: waiting for the device
+        (``device_wait_ns``, recorded by ``kernels.device.fetch``) apart
+        from copying back and assembling (``gather_ns``, the rest of this
+        wall). Exceptions pass through to the caller's fallback."""
+        from .profile.timeline import DeviceFrame
+
+        with DeviceFrame(self.stats, "gather", "gather_ns"):
+            return resolve()
+
+    def _resolve_now(self, resolve):
+        """A synchronous attempt's second half: resolve what it has just
+        launched, inside the attempt (a failure is the attempt's), with
+        the wait and gather accounting of a deferred resolve. None (the
+        launch declined) stays None."""
+        return None if resolve is None else self._device_resolve(resolve)
 
     def _device_failed(self, site: str, exc: BaseException) -> None:
         """A device attempt (launch or deferred resolve) raised: report the
@@ -772,11 +813,11 @@ class ExecutionContext:
             return self._defer_projection(part, exprs)
         if self._device_eligible(part):
             def _run():
-                from .kernels.device import eval_projection_device
+                from .kernels.device import eval_projection_device_async
 
-                return eval_projection_device(
+                return self._resolve_now(eval_projection_device_async(
                     part.table(), list(exprs),
-                    stage_cache=part.device_stage_cache())
+                    stage_cache=part.device_stage_cache()))
 
             out = self._device_attempt(_run)
             if out is not None:
@@ -811,7 +852,7 @@ class ExecutionContext:
 
         def finish() -> MicroPartition:
             try:
-                out = part._wrap(resolve())
+                out = part._wrap(self._device_resolve(resolve))
             except Exception as e:
                 # the partition was NOT computed on device after all: keep
                 # the counters truthful (same attribution the synchronous
@@ -862,12 +903,19 @@ class ExecutionContext:
             return self._defer_fused(part, program)
         if program.device_exprs is not None and self._device_eligible(part):
             def _run():
-                from .kernels.device import eval_projection_device
+                from .kernels.device import eval_projection_device_async
+                from .profile import timeline
 
-                out = eval_projection_device(
+                out = self._resolve_now(eval_projection_device_async(
                     part.table(), program.device_exprs,
-                    stage_cache=part.device_stage_cache())
-                return None if out is None else program.assemble_device(out)
+                    stage_cache=part.device_stage_cache()))
+                if out is None:
+                    return None
+                # the chain's host half (mask compaction): the operator's
+                # own time, inside the attempt only so that a failure of it
+                # still falls back to the host pass
+                with timeline.timed("fuse.assemble"):
+                    return program.assemble_device(out)
 
             out = self._device_attempt(_run)
             if out is not None:
@@ -900,7 +948,7 @@ class ExecutionContext:
 
         def finish() -> MicroPartition:
             try:
-                out = program.assemble_device(resolve())
+                out = program.assemble_device(self._device_resolve(resolve))
             except Exception as e:
                 # the chain was NOT computed on device after all: keep the
                 # counters truthful, inform the breaker, host pass takes over
@@ -996,7 +1044,7 @@ class ExecutionContext:
 
         def finish() -> MicroPartition:
             try:
-                out = resolve()
+                out = self._device_resolve(resolve)
             except Exception as e:
                 # the scatter was NOT computed on device: truthful counters,
                 # breaker informed, host build takes over
@@ -1021,13 +1069,12 @@ class ExecutionContext:
             return fin()
         if self._device_eligible(part):
             def _run():
-                from .kernels.device_agg import device_grouped_agg
+                from .kernels.device_agg import device_grouped_agg_async
 
-                return device_grouped_agg(part.table(), list(aggregations),
-                                          list(groupby or []),
-                                          stage_cache=part.device_stage_cache(),
-                                          predicate=predicate,
-                                          stats=self.stats)
+                return self._resolve_now(device_grouped_agg_async(
+                    part.table(), list(aggregations), list(groupby or []),
+                    stage_cache=part.device_stage_cache(),
+                    predicate=predicate, stats=self.stats))
 
             out = self._device_attempt(_run)
             if out is not None:
@@ -1089,7 +1136,7 @@ class ExecutionContext:
 
         def finish() -> MicroPartition:
             try:
-                out = resolve()
+                out = self._device_resolve(resolve)
             except Exception as e:
                 out = None
                 self._device_failed("device.agg", e)
@@ -1147,7 +1194,7 @@ class ExecutionContext:
         def finish() -> MicroPartition:
             with self.stats.profiler.span("fuse.segment", kind="phase"):
                 try:
-                    out = resolve()
+                    out = self._device_resolve(resolve)
                 except Exception as e:
                     out = None
                     self._device_failed("fuse.segment", e)
@@ -1281,7 +1328,7 @@ class ExecutionContext:
 
         def finish() -> MicroPartition:
             try:
-                res = launch()
+                res = self._device_resolve(launch)
             except Exception as e:
                 self._device_failed("device.join", e)
                 self.stats.bump("device_join_fallbacks")
@@ -1291,8 +1338,9 @@ class ExecutionContext:
             # assembly runs OUTSIDE the catch-all: a defect there must crash
             # loudly, not silently recompute on host (same error contract
             # as the blocking path)
-            out = self._assemble_join(res, lpart, rpart, left_on,
-                                      right_on, how, suffix)
+            with self.stats.profiler.span("join.assemble", kind="phase"):
+                out = self._assemble_join(res, lpart, rpart, left_on,
+                                          right_on, how, suffix)
             self.stats.bump("device_join_probes")
             return out
 
@@ -1318,11 +1366,11 @@ class ExecutionContext:
             return self._defer_filter(part, predicate)
         if self._device_eligible(part):
             def _run():
-                from .kernels.device import eval_projection_device
+                from .kernels.device import eval_projection_device_async
 
-                return eval_projection_device(
+                return self._resolve_now(eval_projection_device_async(
                     part.table(), [predicate],
-                    stage_cache=part.device_stage_cache())
+                    stage_cache=part.device_stage_cache()))
 
             out = self._device_attempt(_run)
             if out is not None:
@@ -1357,7 +1405,7 @@ class ExecutionContext:
 
         def finish() -> MicroPartition:
             try:
-                out = resolve()
+                out = self._device_resolve(resolve)
                 mask = out._columns[0]
                 result = part._wrap(part.table().filter_with_mask(mask))
             except Exception as e:
@@ -1458,12 +1506,14 @@ def execute_plan(root: PhysicalOp, ctx: ExecutionContext,
 
     Every op is wrapped with per-partition accounting (rows + wall time into
     RuntimeStats, feeding explain_analyze) and — when the query's profiler
-    is armed — with profiler spans. A chrome trace armed without an armed
-    profiler (tracing.chrome_trace / DAFT_TPU_CHROME_TRACE) arms one here:
-    the chrome output is rendered FROM the span tree at query end (one
-    consolidated writer, re-armed per query) — and so does the slow-query
-    auto-capture when a previous run of this plan fingerprint crossed
-    ``cfg.slow_query_threshold_s``.
+    is armed — with profiler spans. Queries are armed where they begin,
+    before planning (``profile.arm_for_query`` in ``DataFrame.collect`` and
+    the serving runtime); a plan that reaches here unarmed (iter_partitions,
+    a bare runner) gets the same decision now, and the slow-query
+    auto-capture arms here because it needs the plan's fingerprint: a
+    previous run of it crossed ``cfg.slow_query_threshold_s``. The chrome
+    output is rendered FROM the span tree at query end (one consolidated
+    writer, re-armed per query).
 
     The flight recorder (daft_tpu/obs/) hooks both ends: the query id is
     bound as structured-log context for the query's lifetime, and EVERY
@@ -1485,16 +1535,14 @@ def execute_plan(root: PhysicalOp, ctx: ExecutionContext,
         # serving-runtime queries carry their admission-visible id through
         # the whole observability stack (records, logs, health)
         query_id = ctx.qctx.query_id or f"q-{next(_QUERY_SEQ)}"
-        arm = tracing.active()
-        if not arm:
-            # slow-query auto-arm is part of the capture contract, which
-            # survives a disabled query log
-            from .obs import capture as obs_capture
+        from .obs import capture as obs_capture
+        from .profile import Profiler, arm_for_query
 
-            arm = obs_capture.take_arm(fingerprint)
-        if arm:
-            from .profile.spans import Profiler
-
+        arm_for_query(ctx.stats, query_id, profile=False)
+        # slow-query auto-arm is part of the capture contract, which
+        # survives a disabled query log
+        if (not ctx.stats.profiler.armed
+                and obs_capture.take_arm(fingerprint)):
             ctx.stats.profiler = Profiler(query_id=query_id)
     parallel = ctx.num_workers > 1
 
@@ -1636,6 +1684,7 @@ def execute_plan(root: PhysicalOp, ctx: ExecutionContext,
                         short_circuit=outcome in ("ok", "abandoned"))
                     ctx.shutdown_pool()
                     ctx.finish_query()
+                    ctx.stats.fold_op_self_host()
                     prof = ctx.stats.profiler
                     prof.finish()
                     if tracing.active() and prof.armed:

@@ -404,9 +404,8 @@ def run_segment_async(table, prog: SegmentProgram,
     resolver for the ONE result fetch. Returns None when this partition is
     resident-ineligible (the caller degrades to the staged per-op path);
     raises only for real device failures (the breaker's concern)."""
-    import jax
-
-    from ..kernels.device import _stage_and_run, int64_wrap_safe, size_bucket
+    from ..kernels.device import (_stage_and_run, fetch, int64_wrap_safe,
+                                  size_bucket)
     from ..kernels.device_agg import (_compile_agg, _finish_agg,
                                       group_codes_cached)
 
@@ -469,7 +468,7 @@ def run_segment_async(table, prog: SegmentProgram,
         from ..series import Series
         from ..table import Table
 
-        got = jax.device_get(outs_dev)
+        got = fetch(outs_dev)
         out_cols = list(uniq._columns) if uniq is not None else []
         out_fields = list(uniq.schema) if uniq is not None else []
         agg_outs = got[:len(prog.specs)]

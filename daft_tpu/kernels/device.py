@@ -31,6 +31,9 @@ import jax
 import jax.numpy as jnp
 
 from ..datatypes import DataType, TypeKind
+from ..profile import timeline
+
+timeline.listen_for_compiles()  # once a process, before a kernel can compile
 
 # Pad row counts up to one of these buckets (TPU lane width friendly: multiples of
 # 8*128). Each bucket compiles once; growth factor 2 bounds waste at 2x.
@@ -234,19 +237,51 @@ def _stage_string_series(s, bucket: Optional[int]) -> DeviceColumn:
 
 
 def stage_series(s, bucket: Optional[int] = None) -> DeviceColumn:
-    """Stage a host Series onto the device (values + validity, padded)."""
-    if s.dtype.is_string():
-        return _stage_string_series(s, bucket)
-    vals, valid, n = stage_np(s, bucket)
-    return DeviceColumn(jnp.asarray(vals), jnp.asarray(valid), n, s.dtype)
+    """Stage a host Series onto the device (values + validity, padded).
+    The one place Arrow columns go to HBM (callers keep the stage cache, so
+    a hit never comes here): ``stage`` span, ``stage_ns``, ``stage_bytes``
+    (the padded bytes handed over) and ``stage_columns`` of the device
+    attempt running on this thread."""
+    with timeline.timed("stage", "stage_ns"):
+        if s.dtype.is_string():
+            dc = _stage_string_series(s, bucket)
+        else:
+            vals, valid, n = stage_np(s, bucket)
+            dc = DeviceColumn(jnp.asarray(vals), jnp.asarray(valid), n,
+                              s.dtype)
+    timeline.add("stage_bytes", int(dc.values.nbytes) + int(dc.valid.nbytes))
+    timeline.add("stage_columns", 1)
+    return dc
+
+
+def fetch(x):
+    """Device arrays (any pytree of them) to host numpy. The one place HBM
+    goes back to the host, so the wait for the device (``device.wait``
+    span, ``device_wait_ns``: the chip is busy) reads apart from the copy
+    (``gather`` span, ``gather_ns``, ``gather_bytes``: the chip is idle) in
+    the device frame running on this thread; outside one nothing is
+    recorded. The copies are queued before the wait, as ``jax.device_get``
+    queues them, so they start the moment the outputs exist and not a host
+    round trip later."""
+    for leaf in jax.tree_util.tree_leaves(x):
+        if isinstance(leaf, jax.Array):
+            leaf.copy_to_host_async()
+    with timeline.timed("device.wait", "device_wait_ns"):
+        jax.block_until_ready(x)
+    with timeline.timed("gather", "gather_ns"):
+        out = jax.device_get(x)
+    timeline.add("gather_bytes", sum(
+        int(getattr(a, "nbytes", 0)) for a in jax.tree_util.tree_leaves(out)))
+    return out
 
 
 def unstage(col: DeviceColumn):
     """Bring a DeviceColumn back to a host Series."""
     from ..series import Series
 
-    vals = np.asarray(jax.device_get(col.values))[:col.length]
-    valid = np.asarray(jax.device_get(col.valid))[:col.length]
+    vals, valid = fetch((col.values, col.valid))
+    vals = np.asarray(vals)[:col.length]
+    valid = np.asarray(valid)[:col.length]
     dt = col.dtype
     if col.dictionary is not None:
         uniq = col.dictionary
@@ -2387,7 +2422,7 @@ def int64_wrap_safe(nodes, schema, env, stage_cache: Optional[dict],
                 return None
             lo_d = jnp.min(jnp.where(m, v, jnp.iinfo(v.dtype).max))
             hi_d = jnp.max(jnp.where(m, v, jnp.iinfo(v.dtype).min))
-            lo, hi = (int(x) for x in jax.device_get((lo_d, hi_d)))  # 1 sync
+            lo, hi = (int(x) for x in fetch((lo_d, hi_d)))  # 1 sync
             if hi < lo:  # all-null column
                 lo = hi = 0
             r = (lo, hi)
@@ -2503,7 +2538,7 @@ def _stage_and_run(table, exprs, stage_cache: Optional[dict]):
 def eval_projection_device_async(table, exprs, stage_cache: Optional[dict] = None):
     """Dispatch a device projection WITHOUT blocking: staging and the jitted
     compute launch happen now (jax dispatch is asynchronous); the returned
-    zero-arg resolver materializes the host Table (device_get) when called.
+    zero-arg resolver materializes the host Table (``fetch``) when called.
     This is what lets the executor double-buffer — stage morsel i+1 while the
     device still computes morsel i (reference role: the pipelined channel
     hand-off of daft-local-execution intermediate_op.rs:71+).
@@ -2538,12 +2573,6 @@ def eval_projection_device_async(table, exprs, stage_cache: Optional[dict] = Non
         return Table(Schema(fields), cols)
 
     return resolve
-
-
-def eval_projection_device(table, exprs, stage_cache: Optional[dict] = None) -> Optional[object]:
-    """Evaluate a projection on device; returns a host Table or None if ineligible."""
-    resolve = eval_projection_device_async(table, exprs, stage_cache)
-    return None if resolve is None else resolve()
 
 
 # ---------------------------------------------------------------------------
@@ -2854,7 +2883,7 @@ def device_table_argsort(table, sort_keys, descending=None, nulls_first=None,
             entries[i] = vm
     nf_resolved = [(f if f is not None else d) for f, d in zip(nf, desc)]
     idx = device_argsort(entries, desc, nf_resolved, n)
-    return np.asarray(jax.device_get(idx))[:n]
+    return np.asarray(fetch(idx))[:n]
 
 
 def device_argsort(key_cols: Sequence[Tuple],

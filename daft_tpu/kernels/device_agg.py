@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from ..datatypes import DataType
 from .device import (
     compile_projection,
+    fetch,
     segment_reduce,
     size_bucket,
     stage_table_columns,
@@ -160,8 +161,9 @@ def _try_device_group_codes(table, group_by, stage_cache, n: int):
     vals, valid = lanes
     codes, num_groups, first_rows, _uv, _um = _group_codes_kernel(
         vals, valid, jnp.int32(n))
-    num_groups = int(num_groups)  # one tiny sync; bounds the segment bucket
-    first = np.asarray(jax.device_get(first_rows))[:num_groups]
+    num_groups, first_rows = fetch((num_groups, first_rows))
+    num_groups = int(num_groups)  # bounds the segment bucket
+    first = np.asarray(first_rows)[:num_groups]
     import pyarrow as pa
 
     # gather the num_groups first-occurrence ROWS first, then evaluate the
@@ -187,7 +189,7 @@ def _staged_group_lanes(table, keys, stage_cache, n: int):
     if len(staged) == 1:
         return staged[0]
     # ONE fused reduction + sync for the nullability check, not one/key
-    all_valid = bool(jax.device_get(
+    all_valid = bool(fetch(
         jnp.all(jnp.stack([jnp.all(m[:n]) for _, m in staged]))))
     if not all_valid:
         return None
@@ -212,8 +214,8 @@ def device_distinct_indices(table, keys, stage_cache, n: int):
     vals, valid = lanes
     _, num_groups, first_rows, _, _ = _group_codes_kernel(
         vals, valid, jnp.int32(n))
-    num_groups = int(num_groups)
-    return np.asarray(jax.device_get(first_rows))[:num_groups]
+    num_groups, first_rows = fetch((num_groups, first_rows))
+    return np.asarray(first_rows)[:int(num_groups)]
 
 
 def group_codes_cached(table, group_by, stage_cache: Optional[dict], n: int,
@@ -259,15 +261,6 @@ def group_codes_cached(table, group_by, stage_cache: Optional[dict], n: int,
         if stage_cache is not None:
             stage_cache[codes_key] = cached
     return cached
-
-
-def device_grouped_agg(table, to_agg, group_by, stage_cache: Optional[dict] = None,
-                       predicate=None, stats=None):
-    """Synchronous fused grouped aggregation on device: dispatch + resolve.
-    Returns a host Table or None when ineligible (see the async variant)."""
-    resolve = device_grouped_agg_async(table, to_agg, group_by, stage_cache,
-                                       predicate, stats=stats)
-    return None if resolve is None else resolve()
 
 
 def _plan_agg_specs(to_agg, schema, predicate=None):
@@ -413,7 +406,7 @@ def device_grouped_agg_async(table, to_agg, group_by,
     outs_dev = run(env, codes_dev, n_dev)  # async: device computes from here
 
     def resolve():
-        outs = jax.device_get(outs_dev)
+        outs = fetch(outs_dev)
 
         # --- assemble host result ----------------------------------------
         from ..series import Series

@@ -37,7 +37,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .device import is_device_dtype, size_bucket, stage_table_columns
+from ..profile import timeline
+from .device import fetch, is_device_dtype, size_bucket, stage_table_columns
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -91,9 +92,17 @@ def _range_join(lo_d, counts_d, perm_d, ln: int, how: str):
     engine-wide (see Table.hash_join), so a query flipping between device
     and host paths may legitimately reorder rows; only the multiset is
     guaranteed."""
-    lo = np.asarray(jax.device_get(lo_d))[:ln].astype(np.int64)
-    counts = np.asarray(jax.device_get(counts_d))[:ln].astype(np.int64)
-    perm = np.asarray(jax.device_get(perm_d)).astype(np.int64)
+    lo, counts, perm = fetch((lo_d, counts_d, perm_d))
+    lo = np.asarray(lo)[:ln].astype(np.int64)
+    counts = np.asarray(counts)[:ln].astype(np.int64)
+    perm = np.asarray(perm).astype(np.int64)
+    # the expansion is the join's own host work, not copying back: its
+    # time stays the operator's self time (``join.expand`` when armed)
+    with timeline.timed("join.expand"):
+        return _expand_ranges(lo, counts, perm, ln, how)
+
+
+def _expand_ranges(lo, counts, perm, ln: int, how: str):
     hit = counts > 0
     if how in ("semi", "anti"):
         return "right_build", hit, np.zeros(ln, dtype=np.int64)
@@ -581,18 +590,16 @@ def _launch_probe(lv, lm, rv, rm, ln: int, rn: int, how: str):
     def resolve():
         # build=right first (probe order == host output order); ONE sort
         # serves whichever path the dup flag selects
-        if not bool(dup):
-            hit, bidx = _pk_outputs(lo, counts, perm)
-            hit = np.asarray(jax.device_get(hit))[:ln]
-            bidx = np.asarray(jax.device_get(bidx))[:ln].astype(np.int64)
-            return "right_build", hit, bidx
+        if not bool(fetch(dup)):
+            hit, bidx = fetch(_pk_outputs(lo, counts, perm))
+            return ("right_build", np.asarray(hit)[:ln],
+                    np.asarray(bidx)[:ln].astype(np.int64))
         if how == "inner":
             lo2, counts2, perm2, dup2 = _range_probe_kernel(lv, lm, rv, rm)
-            if not bool(dup2):
-                hit, bidx = _pk_outputs(lo2, counts2, perm2)
-                hit = np.asarray(jax.device_get(hit))[:rn]
-                bidx = np.asarray(jax.device_get(bidx))[:rn].astype(np.int64)
-                return "left_build", hit, bidx
+            if not bool(fetch(dup2)):
+                hit, bidx = fetch(_pk_outputs(lo2, counts2, perm2))
+                return ("left_build", np.asarray(hit)[:rn],
+                        np.asarray(bidx)[:rn].astype(np.int64))
         # duplicate build keys on every usable orientation: N:M range join,
         # reusing the right-build probe already on device
         return _range_join(lo, counts, perm, ln, how)

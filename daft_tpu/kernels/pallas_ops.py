@@ -44,6 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .device import fetch
+
 # smallest row block (one lane tile); every size bucket is a multiple of it
 MIN_BLOCK_ROWS = 128
 
@@ -151,4 +153,4 @@ def masked_segment_sums(codes: np.ndarray, mask: Optional[np.ndarray],
     vals_p[:, :n] = vk.T
     out = segment_sums_lanes(jnp.asarray(codes_p), jnp.asarray(vals_p),
                              num_groups, interpret)
-    return np.asarray(jax.device_get(out)).astype(np.float64).T, counts
+    return np.asarray(fetch(out)).astype(np.float64).T, counts

@@ -152,6 +152,9 @@ class QueryProfile:
         return "\n".join(lines)
 
 
+_DEVICE_FRAMES = ("dispatch", "gather")
+
+
 def build_profile(profiler: Profiler, stats=None) -> QueryProfile:
     """Roll a finished Profiler (plus the query's RuntimeStats) up into a
     QueryProfile."""
@@ -192,7 +195,6 @@ def build_profile(profiler: Profiler, stats=None) -> QueryProfile:
             o["self_ns"] += max(s.dur_ns - child_op_ns.get(s.sid, 0), 0)
             o["io_wait_ns"] += ph.get("io_wait", 0)
             o["queue_wait_ns"] += ph.get("queue_wait", 0)
-            o["device_ns"] += ph.get("device_dispatch", 0)
             o["partitions"] += 1
             if s.attrs:
                 o["rows"] += s.attrs.get("rows", 0) or 0
@@ -219,7 +221,13 @@ def build_profile(profiler: Profiler, stats=None) -> QueryProfile:
             # the per-op buckets undercount the RuntimeStats totals
             o["io_wait_ns"] += ph.get("io_wait", 0)
             o["queue_wait_ns"] += ph.get("queue_wait", 0)
-            o["device_ns"] += ph.get("device_dispatch", 0)
+            # the op's time on the device path: its attempts' `dispatch`
+            # frames and its resolvers' `gather` frames (timeline.py), the
+            # outermost of each (a synchronous attempt nests a gather)
+            parent = by_id.get(s.parent)
+            if s.name in _DEVICE_FRAMES and (
+                    parent is None or parent.name not in _DEVICE_FRAMES):
+                o["device_ns"] += s.dur_ns
 
     # critical path: from the hottest root op, greedily follow the child op
     # with the largest caused wall time

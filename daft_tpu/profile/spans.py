@@ -19,10 +19,16 @@ Span kinds:
              readahead pool), parented via ``capture()``/``activate()``
 
 Besides spans, the profiler records *typed events* (breaker transitions,
-fault injections, throttles, fusion outcomes) on the same clock
-(``time.perf_counter_ns``), and *phases* — named nanosecond buckets
-(io_wait, queue_wait, device_dispatch, jit_compile) attached to the
-innermost open span of the current thread.
+fault injections, throttles, fusion outcomes, ``compile.xla`` with its
+duration) on the same clock (``time.perf_counter_ns``), and *phases* —
+named nanosecond buckets (io_wait, queue_wait) attached to the innermost
+open span of the current thread.
+
+Device timeline: a Profiler armed while a ``jax.profiler`` session is live
+(``timeline.arm_for_query``) also opens a ``jax.profiler.TraceAnnotation``
+named ``daft_tpu:<kind>:<name>`` for every span it begins, so the same
+spans are events of the profiler's xplane, on the clock the device's
+operations are on. Off (the default), no annotation is ever constructed.
 
 Cost discipline: the DISARMED singleton is what every RuntimeStats carries
 by default. Its ``armed`` flag is False and every method is a constant-time
@@ -56,7 +62,7 @@ class Span:
     accounting); ``attrs`` carries small scalars (rows, ...)."""
 
     __slots__ = ("sid", "parent", "name", "op", "part", "kind", "thread",
-                 "t0_ns", "dur_ns", "phases", "attrs")
+                 "t0_ns", "dur_ns", "phases", "attrs", "annotation")
 
     def __init__(self, sid: int, parent: Optional[int], name: str,
                  op: Optional[str], part: Optional[int], kind: str,
@@ -72,6 +78,7 @@ class Span:
         self.dur_ns = 0
         self.phases: Optional[Dict[str, int]] = None
         self.attrs: Optional[Dict[str, Any]] = None
+        self.annotation = None  # the open TraceAnnotation (device timeline)
 
     def add_phase(self, key: str, ns: int) -> None:
         ph = self.phases
@@ -175,8 +182,14 @@ class Profiler:
 
     def __init__(self, query_id: Optional[str] = None, armed: bool = True,
                  max_spans: int = DEFAULT_MAX_SPANS,
-                 max_events: int = DEFAULT_MAX_EVENTS):
+                 max_events: int = DEFAULT_MAX_EVENTS,
+                 device_timeline: bool = False):
         self.armed = armed
+        self.device_timeline = device_timeline and armed
+        if self.device_timeline:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
         self.query_id = query_id or f"q-{id(self):x}"
         self.max_spans = max_spans
         self.max_events = max_events
@@ -221,11 +234,22 @@ class Profiler:
         sp = Span(next(self._seq), parent, name, op, part, kind,
                   threading.current_thread().name, time.perf_counter_ns())
         st.append(sp)
+        if self.device_timeline:
+            sp.annotation = self._annotation(f"daft_tpu:{kind}:{name}")
+            sp.annotation.__enter__()
         return sp
+
+    @staticmethod
+    def _close_annotation(sp: Span) -> None:
+        ann = sp.annotation
+        if ann is not None:
+            sp.annotation = None
+            ann.__exit__(None, None, None)
 
     def end(self, sp: Optional[Span]) -> None:
         if sp is None:
             return
+        self._close_annotation(sp)
         sp.dur_ns = time.perf_counter_ns() - sp.t0_ns
         st = self._stack()
         # tolerate a corrupted stack (a span leaked across a generator
@@ -246,6 +270,7 @@ class Profiler:
         empty pull — a StopIteration — is not a partition)."""
         if sp is None:
             return
+        self._close_annotation(sp)
         st = self._stack()
         if st and st[-1] is sp:
             st.pop()
@@ -285,7 +310,7 @@ class Profiler:
     # ------------------------------------------------------------ phases
     def phase(self, key: str, ns: int) -> None:
         """Add ``ns`` to the named phase bucket of this thread's innermost
-        open span (io_wait, queue_wait, device_dispatch, ...)."""
+        open span (io_wait, queue_wait, ...)."""
         if not self.armed:
             return
         st = getattr(self._tl, "stack", None)

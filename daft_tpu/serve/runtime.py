@@ -224,6 +224,9 @@ class ServingRuntime:
         handle._qctx = qctx
         try:
             from ..dataframe import from_partitions
+            from ..profile import arm_for_query
+
+            arm_for_query(handle.stats, handle.query_id)
 
             pset = ctx.runner().run(plan, stats=handle.stats, qctx=qctx)
             out = from_partitions(pset.partitions, pset.schema)

@@ -9,9 +9,11 @@ chrome://tracing-compatible JSON array; since PR 6 the per-op duration
 events are rendered FROM the structured profiler's span tree
 (daft_tpu/profile/) at each query's end — one consolidated writer,
 re-armed per query — so the trace carries the same cross-thread
-attribution the QueryProfile does. On TPU the same file can be opened
-alongside an xprof/xplane capture to line up host pipeline stages with
-device kernels.
+attribution the QueryProfile does. The file's clock is the host's
+``perf_counter``; to see the same spans beside the device's operations,
+start a ``jax.profiler`` trace and run the query: the Profiler then writes
+every span into that trace as a ``daft_tpu:<kind>:<name>`` annotation
+(daft_tpu/profile/timeline.py).
 
 Enable with the env var DAFT_TPU_CHROME_TRACE=<path> (armed at import/query
 time) or programmatically:

@@ -27,6 +27,12 @@ def table():
     })
 
 
+def eval_projection_device(table, exprs):
+    """Launch and resolve at once: a host Table, or None when ineligible."""
+    resolve = dev.eval_projection_device_async(table, exprs)
+    return None if resolve is None else resolve()
+
+
 PROJ_EXPRS = [
     (col("a") * 2 + 1).alias("x"),
     (col("b") / col("a")).alias("div"),
@@ -49,7 +55,7 @@ PROJ_EXPRS = [
 class TestDeviceProjection:
     def test_parity_with_host(self, table):
         host = table.eval_expression_list(PROJ_EXPRS)
-        devout = dev.eval_projection_device(table, PROJ_EXPRS)
+        devout = eval_projection_device(table, PROJ_EXPRS)
         assert devout is not None
         hd, dd = host.to_pydict(), devout.to_pydict()
         for k in hd:
@@ -59,7 +65,7 @@ class TestDeviceProjection:
         # upper(s) rides the transformed-dictionary lane (sorted-order ids
         # gathered by code, decoded at unstage) — exact host parity
         t = Table.from_pydict({"s": ["a", "B", None, "c"]})
-        out = dev.eval_projection_device(t, [col("s").str.upper()])
+        out = eval_projection_device(t, [col("s").str.upper()])
         assert out is not None
         assert out.to_pydict() == {"s": ["A", "B", None, "C"]}
 
@@ -67,13 +73,13 @@ class TestDeviceProjection:
         # a string producer over TWO columns has no single source
         # dictionary to transform: stays host
         t = Table.from_pydict({"s": ["a", "b"], "t": ["x", "y"]})
-        assert dev.eval_projection_device(t, [col("s") + col("t")]) is None
+        assert eval_projection_device(t, [col("s") + col("t")]) is None
 
     def test_float_division_by_zero_matches_host(self):
         t = Table.from_pydict({"a": [1.0, 2.0], "z": [0, 2]})
         exprs = [(col("a") / col("z")).alias("q")]
         host = t.eval_expression_list(exprs).to_pydict()
-        devout = dev.eval_projection_device(t, exprs).to_pydict()
+        devout = eval_projection_device(t, exprs).to_pydict()
         assert devout["q"] == host["q"] == [float("inf"), 1.0]
 
     def test_kleene_and_or(self):
@@ -81,14 +87,14 @@ class TestDeviceProjection:
                                "q": [True, True, True, False, False, False, None, None, None]})
         exprs = [(col("p") & col("q")).alias("and_"), (col("p") | col("q")).alias("or_")]
         host = t.eval_expression_list(exprs).to_pydict()
-        devout = dev.eval_projection_device(t, exprs).to_pydict()
+        devout = eval_projection_device(t, exprs).to_pydict()
         assert devout == host
 
     def test_compile_cache_reused(self, table):
         dev._PROJ_CACHE.clear()
-        dev.eval_projection_device(table, [(col("a") + 1).alias("y")])
+        eval_projection_device(table, [(col("a") + 1).alias("y")])
         assert len(dev._PROJ_CACHE) == 1
-        dev.eval_projection_device(table.head(50), [(col("a") + 1).alias("y")])
+        eval_projection_device(table.head(50), [(col("a") + 1).alias("y")])
         assert len(dev._PROJ_CACHE) == 1  # same expr+schema: one entry, bucket via jit
 
 

@@ -22,8 +22,14 @@ class TestChromeTrace:
         assert evs, "no events captured"
         names = {e["name"] for e in evs}
         assert any("Aggregate" in n for n in names), names
+        # the query is armed before it is planned, so the planner's one
+        # typed event is in the trace: an instant, and the only one
+        instants = [e for e in evs if e["ph"] != "X"]
+        assert [(e["name"], e["ph"]) for e in instants] == [("plancache", "i")]
         for e in evs:
-            assert e["ph"] == "X" and "ts" in e and "dur" in e
+            if e not in instants:
+                assert e["ph"] == "X" and "ts" in e and "dur" in e
+        assert any(e["ph"] == "X" and e["name"] == "plan" for e in evs)
 
     def test_disabled_by_default(self, tmp_path):
         assert not tracing.active()
