@@ -53,9 +53,11 @@ LAYER_COUNTERS = (
 
 def layer_times(counters: dict) -> str:
     """``plan 1ms stage 12ms ... staged 3.1MB in 2 columns ... compiles 0
-    (0ms)``: the layer counters of one query, for its printed line. A warm
-    query that stages columns lost its stage cache; one that compiles (and
-    for how long) met a shape the warm-up did not."""
+    (0ms) cache loads 0 dict lookups 1 packed 0 gathered``: the layer
+    counters of one query, for its printed line. A warm query that stages
+    columns lost its stage cache; one that compiles (and for how long) met a
+    shape the warm-up did not; one that gathers a dictionary predicate met a
+    dictionary over ``DICT_PACKED_MAX_ENTRIES``."""
     parts = [f"{label} {counters.get(key, 0) / 1e6:.0f}ms"
              for label, key in LAYER_COUNTERS]
     parts.append(f"staged {counters.get('stage_bytes', 0) / 1e6:.1f}MB "
@@ -63,7 +65,9 @@ def layer_times(counters: dict) -> str:
                  f"gathered {counters.get('gather_bytes', 0) / 1e6:.1f}MB "
                  f"compiles {counters.get('xla_compiles', 0)} "
                  f"({counters.get('xla_compile_ns', 0) / 1e6:.0f}ms) "
-                 f"cache loads {counters.get('xla_cache_loads', 0)}")
+                 f"cache loads {counters.get('xla_cache_loads', 0)} "
+                 f"dict lookups {counters.get('dict_lookup_packed', 0)} packed "
+                 f"{counters.get('dict_lookup_gather', 0)} gathered")
     return " ".join(parts)
 
 

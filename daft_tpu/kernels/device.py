@@ -716,9 +716,10 @@ def _string_lut_shape(node, schema):
     endswith with a literal pattern, and is_in over string literals. The
     host computes the predicate over the O(unique) dictionary values with
     the SAME pyarrow kernels the host path uses (exact parity), producing a
-    bool lookup table the device gathers by code — O(rows) work stays on
-    the accelerator, O(unique) bookkeeping on the host (the division of
-    labor SURVEY §7 prescribes)."""
+    bool table the device looks up by code (``_dict_bool_lookup``: bit
+    tests over packed words up to ``DICT_PACKED_MAX_ENTRIES``, a gather
+    above) — O(rows) work stays on the accelerator, O(unique) bookkeeping
+    on the host (the division of labor SURVEY §7 prescribes)."""
     from ..expressions import Function, IsIn, Literal
 
     if isinstance(node, Function) and node.fname in _STR_PRED_FNS:
@@ -768,7 +769,8 @@ def _string_dict_pred_shape(node, schema):
     `(s + "-suffix").is_in([...])`. Each row's result depends only on that
     row's string value, so the host evaluates the WHOLE predicate over the
     O(unique) dictionary (+ one null slot for exact null semantics) with
-    the registered host kernels, and the device gathers by code —
+    the registered host kernels, and the device looks (value, validity) up
+    by code, the null slot being one more entry (``_dict_bool_lookup``) —
     generalizing the fixed contains/startswith/endswith LUT shapes to
     arbitrary predicate trees over string transforms. Reference semantics:
     fully general utf8 kernels, src/daft-core/src/array/ops/utf8.rs."""
@@ -1004,38 +1006,39 @@ def transform_cmp_env(nodes, schema, table, bucket: int,
                 else dc.dictionary
         return aux.get(_stroutdict_aux_key(n._key()))
 
-    def walk(n):
-        nonlocal merged
-        if isinstance(n, BinaryOp):
-            shape = _transform_cmp_shape(n, schema)
-            if shape is not None:
-                ls, rs, _op = shape
-                lk, rk = _transcmp_env_keys(n._key())
-                if lk in merged:
-                    return True
-                cache_key = ("__transcmp__", n._key(), bucket)
-                cached = (stage_cache.get(cache_key)
-                          if stage_cache is not None else None)
-                if cached is None:
-                    ld, rd = side_dict(ls), side_dict(rs)
-                    if ld is None or rd is None:
-                        return False
-                    joint = pc.unique(pa.concat_arrays(
-                        [ld.cast(pa.large_string()),
-                         rd.cast(pa.large_string())]))
-                    joint = joint.take(pc.sort_indices(joint))
-                    cached = (joint_remap(ld, joint), joint_remap(rd, joint))
-                    if stage_cache is not None:
-                        stage_cache[cache_key] = cached
-                if merged is env:
-                    merged = dict(env)
-                merged[lk], merged[rk] = cached
-                return True
-        return all(walk(c) for c in n.children())
-
-    for nd in nodes:
-        if not walk(nd):
-            return None
+    # an explicit stack, not a recursive closure: one that named itself
+    # would form a reference cycle holding `env` (this attempt's device
+    # arrays) until the cyclic collector next ran, and the peak of device
+    # memory would follow the collector's timing
+    stack = list(reversed(nodes))
+    while stack:
+        n = stack.pop()
+        shape = (_transform_cmp_shape(n, schema)
+                 if isinstance(n, BinaryOp) else None)
+        if shape is None:
+            stack.extend(reversed(n.children()))
+            continue
+        ls, rs, _op = shape
+        lk, rk = _transcmp_env_keys(n._key())
+        if lk in merged:
+            continue
+        cache_key = ("__transcmp__", n._key(), bucket)
+        cached = (stage_cache.get(cache_key)
+                  if stage_cache is not None else None)
+        if cached is None:
+            ld, rd = side_dict(ls), side_dict(rs)
+            if ld is None or rd is None:
+                return None
+            joint = pc.unique(pa.concat_arrays(
+                [ld.cast(pa.large_string()),
+                 rd.cast(pa.large_string())]))
+            joint = joint.take(pc.sort_indices(joint))
+            cached = (joint_remap(ld, joint), joint_remap(rd, joint))
+            if stage_cache is not None:
+                stage_cache[cache_key] = cached
+        if merged is env:
+            merged = dict(env)
+        merged[lk], merged[rk] = cached
     return merged
 
 
@@ -1050,17 +1053,17 @@ def string_transform_env(nodes, schema, table, bucket: int,
     Returns env (possibly unchanged), or None when a lane cannot stage —
     the caller declines to the host path."""
     merged = env
-
-    def walk(n):
-        nonlocal merged
+    stack = list(reversed(nodes))  # no recursive closure: transform_cmp_env
+    while stack:
+        n = stack.pop()
         if (_string_lut_shape(n, schema) is not None
                 or _string_dict_pred_applies(n, schema) is not None):
-            return True  # the LUT env owns this subtree
+            continue  # the LUT env owns this subtree
         vs = _string_value_applies(n, schema)
         if vs is not None:
             lane = dict_transform_lane(table, vs, bucket, stage_cache)
             if lane is None:
-                return False
+                return None
             vals, valid, tuniq = lane
             if merged is env:
                 merged = dict(env)
@@ -1068,22 +1071,18 @@ def string_transform_env(nodes, schema, table, bucket: int,
             merged[vk] = vals
             merged[mk] = valid
             aux[_stroutdict_aux_key(vs[2])] = tuniq
-            return True
+            continue
         ivs = _int_transform_applies(n, schema)
         if ivs is not None:
             lane = dict_int_transform_lane(table, ivs, bucket, stage_cache)
             if lane is None:
-                return False
+                return None
             if merged is env:
                 merged = dict(env)
             vk, mk = _inttrans_env_keys(ivs[2])
             merged[vk], merged[mk] = lane
-            return True
-        return all(walk(c) for c in n.children())
-
-    for nd in nodes:
-        if not walk(nd):
-            return None
+            continue
+        stack.extend(reversed(n.children()))
     return merged
 
 
@@ -1581,12 +1580,81 @@ def _eval_over_dictionary(colname: str, node, uniq):
         return None
 
 
+# A boolean table over at most this many dictionary entries goes to the
+# device as packed uint32 words, looked up with selects and one shift that
+# fuse into the consumer; a larger one as bool[bucket], gathered by code.
+# Fixed from a sweep on one TPU v5e over 64M codes (PERF.md section 6, PR 28;
+# `tools/dict_lookup_sweep.py` repeats it): the gather takes 542 ms at every
+# size. Up to 1024 entries (32 words) the packed lookup costs what one word
+# costs, 1.5 ms, the consumer's own memory time, and its program compiles as
+# fast as the gather's (0.5 s). Above, both double with the words (4096
+# entries 2.9 ms and 4 s, 131072 entries 99 ms and 62 s): still faster over
+# 64M rows, but a program over few rows would pay the compile for nothing.
+DICT_PACKED_MAX_ENTRIES = 1024
+
+
+def _dict_bool_tables(*tables: np.ndarray) -> list:
+    """Device form of boolean tables over ONE dictionary's entries (equal
+    lengths): at or under ``DICT_PACKED_MAX_ENTRIES`` each packs into
+    ``uint32[W]`` (bit ``c & 31`` of word ``c >> 5`` is entry ``c``; W the
+    power of two at or above ceil(entries/32), so the program's shape
+    follows a bucket and not the data), above it each pads to
+    ``bool[size_bucket]``. Either is a traced input, never a constant of
+    the program: a new dictionary of the same bucket compiles nothing.
+    Bumps ``dict_lookup_packed`` or ``dict_lookup_gather`` once: one lookup
+    built for the query."""
+    entries = len(tables[0])
+    packed = entries <= DICT_PACKED_MAX_ENTRIES
+    timeline.add("dict_lookup_packed" if packed else "dict_lookup_gather", 1)
+    if packed:
+        size = 32 << (max(entries - 1, 0) >> 5).bit_length()
+    else:
+        size = size_bucket(entries)
+    out = []
+    for t in tables:
+        padded = np.zeros(size, dtype=bool)
+        padded[:entries] = t
+        if packed:
+            padded = np.packbits(padded, bitorder="little").view("<u4")
+        out.append(jnp.asarray(padded))
+    return out
+
+
+def _dict_bool_lookup(table, codes):
+    """``table[codes]`` for a table ``_dict_bool_tables`` built, in the form
+    it finds (a trace-time branch, so jit keeps one program a form and
+    bucket). Packed words: bit ``j`` of ``codes >> 5`` picks a half of the
+    words at each of log2(W) levels, W-1 selects in all, then one shift
+    reads bit ``codes & 31`` — element-wise work the compiler fuses into
+    the consumer; a code outside the words reads False. A bool table
+    gathers."""
+    if table.dtype != jnp.uint32:
+        return table[codes]
+    words = table.shape[0]
+    hi = codes >> 5
+    word = jnp.where(hi.astype(jnp.uint32) < words,
+                     _packed_word(table, hi, 0, words), jnp.uint32(0))
+    return ((word >> (codes & 31).astype(jnp.uint32)) & 1).astype(jnp.bool_)
+
+
+def _packed_word(table, hi, lo: int, n: int):
+    """The word of ``table[lo:lo + n]`` (n a power of two) that the low
+    bits of ``hi`` pick: one select a level on bit ``n // 2``."""
+    if n == 1:
+        return jax.lax.index_in_dim(table, lo, keepdims=False)
+    half = n // 2
+    return jnp.where((hi & half) != 0, _packed_word(table, hi, lo + half, half),
+                     _packed_word(table, hi, lo, half))
+
+
 def _merge_dict_pred(merged: dict, colname: str, node, node_key, dcs) -> bool:
     """Evaluate a general dictionary predicate over the column's dictionary
     values PLUS one null slot (exact null semantics: whatever the host path
-    produces for a null input — is_null, fill_null chains — the gather
-    produces identically), through the host evaluator itself so parity is
-    by construction. False = decline to the host path."""
+    produces for a null input — is_null, fill_null chains — the device's
+    lookup by code produces identically), through the host evaluator itself
+    so parity is by construction. The (value, validity) tables go to the
+    device in the form ``_dict_bool_tables`` picks for dictionary + null
+    slot. False = decline to the host path."""
     vals_k, valid_k, null_k = _strdictpred_env_keys(node_key)
     if vals_k in merged:
         return True
@@ -1597,16 +1665,9 @@ def _merge_dict_pred(merged: dict, colname: str, node, node_key, dcs) -> bool:
     arr = _eval_over_dictionary(colname, node, uniq)
     if arr is None:
         return False
-    vals_np = np.asarray(pc.fill_null(arr, False), dtype=bool)
-    valid_np = np.asarray(pc.is_valid(arr), dtype=bool)
-    u1 = len(uniq) + 1
-    b = size_bucket(u1)
-    if b > u1:
-        pad = np.zeros(b - u1, dtype=bool)
-        vals_np = np.concatenate([vals_np, pad])
-        valid_np = np.concatenate([valid_np, pad])
-    merged[vals_k] = jnp.asarray(vals_np)
-    merged[valid_k] = jnp.asarray(valid_np)
+    merged[vals_k], merged[valid_k] = _dict_bool_tables(
+        np.asarray(pc.fill_null(arr, False), dtype=bool),
+        np.asarray(pc.is_valid(arr), dtype=bool))
     merged[null_k] = jnp.int32(len(uniq))
     return True
 
@@ -1645,11 +1706,8 @@ def string_lut_env(nodes, schema, dcs, env) -> Optional[dict]:
                 Series.from_arrow(uniq, "u"),
                 Series.from_pylist([payload], "p", DataType.string()))
             lut = got.to_arrow()
-        lut_np = np.asarray(pc.fill_null(lut, False), dtype=bool)
-        b = size_bucket(max(len(uniq), 1))
-        if b > len(lut_np):
-            lut_np = np.concatenate([lut_np, np.zeros(b - len(lut_np), bool)])
-        merged[key] = jnp.asarray(lut_np)
+        merged[key], = _dict_bool_tables(
+            np.asarray(pc.fill_null(lut, False), dtype=bool))
     return merged
 
 
@@ -1868,14 +1926,15 @@ def _compile_node(node, schema) -> "Tuple[callable, DataType]":
     if gshape is not None:
         # general dictionary predicate: the WHOLE boolean subtree was
         # host-evaluated over the column's dictionary (+ null slot); the
-        # device gathers (value, validity) by code
+        # device looks (value, validity) up by code
         colname, _pred, node_key = gshape
         vals_k, valid_k, null_k = _strdictpred_env_keys(node_key)
 
         def run(env, _c=colname, _vk=vals_k, _mk=valid_k, _nk=null_k):
             codes, m = env[_c]
             idx = jnp.where(m, codes, env[_nk])
-            return env[_vk][idx], env[_mk][idx]
+            return (_dict_bool_lookup(env[_vk], idx),
+                    _dict_bool_lookup(env[_mk], idx))
 
         return run, out_dt
 
@@ -2230,7 +2289,7 @@ def _compile_node(node, schema) -> "Tuple[callable, DataType]":
 
             def run(env, _c=colname, _lk=lut_k):
                 codes, m = env[_c]
-                return env[_lk][codes], m
+                return _dict_bool_lookup(env[_lk], codes), m
 
             return run, out_dt
 
@@ -2482,7 +2541,13 @@ def int64_wrap_safe(nodes, schema, env, stage_cache: Optional[dict],
                     return False
         return all(safe(c) for c in n.children())
 
-    return all(safe(n) for n in nodes)
+    try:
+        return all(safe(n) for n in nodes)
+    finally:
+        # bounds and safe name themselves: cycles that reach env through
+        # col_range and would hold this attempt's device arrays until the
+        # cyclic collector next ran
+        bounds = safe = None
 
 
 def _stage_and_run(table, exprs, stage_cache: Optional[dict]):

@@ -422,3 +422,324 @@ class TestPipelinedDeviceFilter:
             assert c.get("device_filter_dispatches", 0) >= 5, c
         finally:
             cfg.use_device_kernels, cfg.device_min_rows = old
+
+
+# --------------------------------------------------------------------------
+# Boolean dictionary tables looked up by code (PR 28): packed uint32 words and
+# bit tests up to DICT_PACKED_MAX_ENTRIES, bool[bucket] and a gather above.
+OVER_BOUND = dev.DICT_PACKED_MAX_ENTRIES + 1
+DICT_SIZES = [1, 7, 31, 32, 33, 1000, 1024, OVER_BOUND]
+HIT_PATTERNS = {
+    "none": lambda u: np.zeros(u, dtype=bool),
+    "all": lambda u: np.ones(u, dtype=bool),
+    "one": lambda u: np.arange(u) == u // 2,
+    "every_other": lambda u: np.arange(u) % 2 == 0,
+}
+
+
+def _is_in_case(hits):
+    vals = [f"v{i:05d}" for i in range(len(hits))]
+    wanted = [v for v, h in zip(vals, hits) if h] or ["absent"]
+    return vals, col("s").is_in(wanted)
+
+
+# kind -> hits -> (dictionary values, predicate matching exactly the hits)
+DICT_PRED_CASES = {
+    "is_in": _is_in_case,
+    "contains": lambda hits: (
+        [f"{i:05d}{'_X_' if h else '___'}" for i, h in enumerate(hits)],
+        col("s").str.contains("X")),
+    "startswith": lambda hits: (
+        [f"{'X' if h else '_'}{i:05d}" for i, h in enumerate(hits)],
+        col("s").str.startswith("X")),
+    "like": lambda hits: (
+        [f"{i:05d}{'ab' if h else 'ba'}" for i, h in enumerate(hits)],
+        col("s").str.like("%ab")),
+    # the general dictionary predicate: values and validity, null slot
+    "upper_endswith": lambda hits: (
+        [f"{i:05d}{'x' if h else 'y'}" for i, h in enumerate(hits)],
+        col("s").str.upper().str.endswith("X")),
+}
+
+
+def _dict_table(values, nulls: bool, seed: int = 0) -> Table:
+    """Every dictionary value at least once, in a shuffled column; with
+    ``nulls`` every fifth row is null on top."""
+    rng = np.random.RandomState(seed)
+    n = max(64, 2 * len(values))
+    rows = [values[i] for i in rng.permutation(np.arange(n) % len(values))]
+    if nulls:
+        rows = [v for i, v in enumerate(rows) for v in
+                ((v, None) if i % 4 == 0 else (v,))]
+    return Table.from_pydict({"s": rows})
+
+
+def _device_vs_host(t: Table, exprs):
+    host = t.eval_expression_list(exprs).to_pydict()
+    devout = eval_projection_device(t, exprs)
+    assert devout is not None, "left the device path"
+    return devout.to_pydict(), host
+
+
+def _lookup_counters(df):
+    c = df.stats.snapshot()["counters"]
+    return (c.get("dict_lookup_packed", 0), c.get("dict_lookup_gather", 0),
+            c.get("xla_compiles", 0))
+
+
+@pytest.fixture
+def device_kernels_on():
+    import daft_tpu
+
+    cfg = daft_tpu.context.get_context().execution_config
+    old = cfg.use_device_kernels, cfg.device_min_rows
+    cfg.use_device_kernels, cfg.device_min_rows = True, 1
+    yield
+    cfg.use_device_kernels, cfg.device_min_rows = old
+
+
+@pytest.fixture
+def jaxprs(monkeypatch):
+    """The jaxpr of every projection program launched, as text."""
+    import jax
+
+    seen = []
+    real = dev.compile_projection
+
+    def spy(nodes, schema, names):
+        run, dts = real(nodes, schema, names)
+
+        def traced(env):
+            seen.append(str(jax.make_jaxpr(run)(env)))
+            return run(env)
+
+        return traced, dts
+
+    monkeypatch.setattr(dev, "compile_projection", spy)
+    return seen
+
+
+class TestDictLookup:
+    @pytest.mark.parametrize("nulls", [False, True], ids=["dense", "nulls"])
+    @pytest.mark.parametrize("pattern", list(HIT_PATTERNS))
+    @pytest.mark.parametrize("size", DICT_SIZES)
+    @pytest.mark.parametrize("kind", list(DICT_PRED_CASES))
+    def test_parity_with_host(self, kind, size, pattern, nulls):
+        hits = HIT_PATTERNS[pattern](size)
+        values, pred = DICT_PRED_CASES[kind](hits)
+        t = _dict_table(values, nulls)
+        got, want = _device_vs_host(t, [pred.alias("p")])
+        assert got == want
+        # the case is what it says: exactly the hit entries answer True
+        by_value = dict(zip(values, hits))
+        assert all(p == by_value[s] for s, p in
+                   zip(t.to_pydict()["s"], got["p"]) if s is not None)
+
+    @pytest.mark.parametrize("nulls", [False, True], ids=["dense", "nulls"])
+    @pytest.mark.parametrize("size", DICT_SIZES)
+    @pytest.mark.parametrize("pred", [
+        col("s").str.upper() == "V00000",
+        # the null slot answers True: one more dictionary entry, so the
+        # packed form ends at DICT_PACKED_MAX_ENTRIES - 1 strings
+        col("s").fill_null("v00000").is_in(["v00000", "absent"]),
+    ], ids=["upper_eq", "fill_null_is_in"])
+    def test_general_predicate_null_slot(self, pred, size, nulls):
+        t = _dict_table([f"v{i:05d}" for i in range(size)], nulls)
+        got, want = _device_vs_host(t, [pred.alias("p")])
+        assert got == want
+        assert sum(bool(p) for p in got["p"]) >= 1
+
+    @pytest.mark.parametrize("size", [31, 32, 1023, 1024])
+    def test_null_slot_counts_as_an_entry(self, size, monkeypatch):
+        seen = []
+        real = dev._dict_bool_tables
+        monkeypatch.setattr(
+            dev, "_dict_bool_tables",
+            lambda *tables: seen.extend(tables) or real(*tables))
+        t = _dict_table([f"v{i:05d}" for i in range(size)], True)
+        got, want = _device_vs_host(
+            t, [col("s").fill_null("v00000").is_in(["v00000"]).alias("p")])
+        assert got == want
+        assert [len(tb) for tb in seen] == [size + 1, size + 1]
+
+    @pytest.mark.parametrize("entries,words", [
+        (0, 1), (1, 1), (32, 1), (33, 2), (64, 2), (65, 4), (129, 8),
+        (1000, 32), (1024, 32)])
+    def test_packed_shape_follows_a_bucket(self, entries, words):
+        table, = dev._dict_bool_tables(np.ones(entries, dtype=bool))
+        assert (table.dtype, table.shape) == (jnp.uint32, (words,))
+        assert sum(bin(int(w)).count("1") for w in table) == entries
+
+    @pytest.mark.parametrize("entries", [7, 33, 1024, OVER_BOUND])
+    def test_code_outside_the_dictionary_reads_false(self, entries):
+        # staging never makes such a code; the packed form owes False for
+        # one all the same (no shift past 31, no word past the last)
+        table, = dev._dict_bool_tables(np.ones(entries, dtype=bool))
+        inside = np.arange(entries, dtype=np.int32)
+        assert np.asarray(dev._dict_bool_lookup(table, inside)).all()
+        if entries <= dev.DICT_PACKED_MAX_ENTRIES:
+            outside = np.array([entries, entries + 31, entries + 32, 1 << 20,
+                                2**31 - 1, -1, -32, -2**31], dtype=np.int32)
+            assert not np.asarray(dev._dict_bool_lookup(table, outside)).any()
+
+    @pytest.mark.parametrize("size,gathers", [
+        (7, 0), (dev.DICT_PACKED_MAX_ENTRIES, 0), (OVER_BOUND, 1)])
+    def test_q12_predicate_gathers_only_over_the_bound(self, size, gathers,
+                                                       jaxprs):
+        modes = (["MAIL", "SHIP"] + [f"m{i:05d}" for i in range(size)])[:size]
+        t = _dict_table(modes, False)
+        t = Table.from_pydict({
+            "l_shipmode": t.to_pydict()["s"],
+            "l_shipdate": [datetime.date(1994, 1 + i % 12, 1)
+                           for i in range(len(t))]})
+        q12 = (col("l_shipmode").is_in(["MAIL", "SHIP"])
+               & (col("l_shipdate") >= datetime.date(1994, 1, 1))
+               & (col("l_shipdate") < datetime.date(1995, 1, 1)))
+        got, want = _device_vs_host(t, [q12.alias("keep")])
+        assert got == want
+        assert len(jaxprs) == 1
+        assert jaxprs[0].count("gather") == gathers, jaxprs[0]
+
+    def test_counters_on_the_two_sides_of_the_bound(self, device_kernels_on):
+        import daft_tpu
+
+        for size, want in [(dev.DICT_PACKED_MAX_ENTRIES, (1, 0)),
+                           (OVER_BOUND, (0, 1))]:
+            s = _dict_table([f"v{i:05d}" for i in range(size)], True)
+            df = daft_tpu.from_pydict(
+                {"s": s.to_pydict()["s"], "x": list(range(len(s)))}
+            ).where(col("s").is_in(["v00000", "v00001"])).groupby("s").agg(
+                col("x").sum().alias("t"))
+            df.collect()
+            assert _lookup_counters(df)[:2] == want
+            c = df.stats.snapshot()["counters"]
+            assert c.get("device_aggregations", 0) == 1, c
+
+    def test_new_dictionary_of_one_bucket_compiles_nothing(
+            self, device_kernels_on):
+        import daft_tpu
+
+        def q12ish(values, wanted):
+            s = _dict_table(values, True, seed=len(values))
+            df = daft_tpu.from_pydict(
+                {"s": s.to_pydict()["s"], "x": [1.0] * len(s)}
+            ).where(col("s").is_in(wanted) & (col("x") > 0.5)).groupby(
+                "s").agg(col("x").sum().alias("t")).sort("s")
+            out = df.collect().to_pydict()
+            return out, _lookup_counters(df)
+
+        modes = ["AIR", "FOB", "MAIL", "RAIL", "REG AIR", "SHIP", "TRUCK"]
+        out, (packed, gathered, _) = q12ish(modes, ["MAIL", "SHIP"])
+        assert out["s"] == ["MAIL", "SHIP"] and (packed, gathered) == (1, 0)
+        # another dictionary of the same bucket (one word; the same bucket
+        # of 16 groups too), other contents and other hit positions: the
+        # words are a traced input
+        others = [f"N{i:02d}" for i in range(11)] + ["MAIL", "SHIP"]
+        out, (packed, gathered, compiles) = q12ish(others, ["MAIL", "SHIP"])
+        assert out["s"] == ["MAIL", "SHIP"]
+        assert (packed, gathered, compiles) == (1, 0, 0)
+
+    def test_literal_list_is_no_constant_of_the_program(self, jaxprs):
+        # program caches are keyed by node key, literals included, so a new
+        # literal list is a new closure; what this mechanism owes is that
+        # the list's table is an input and not a constant: the two closures
+        # trace to the same jaxpr (a persistent cache then serves the second)
+        t = _dict_table(["AIR", "FOB", "MAIL", "RAIL", "SHIP"], True)
+        for wanted in (["MAIL", "SHIP"], ["AIR", "FOB", "RAIL"]):
+            got, want = _device_vs_host(t, [col("s").is_in(wanted).alias("p")])
+            assert got == want
+        assert len(jaxprs) == 2 and jaxprs[0] == jaxprs[1]
+
+
+# A device attempt's staged arrays and outputs have to die with the query,
+# by reference count: a recursive local closure that captured `env` kept them
+# until the cyclic collector next ran, and the peak of device memory followed
+# the collector's timing (tpch1-join read +4.3% for an edit elsewhere, PR 28).
+def _orders_and_lines():
+    import daft_tpu
+
+    n = 4000
+    rng = np.random.RandomState(3)
+    orders = daft_tpu.from_pydict({
+        "o_key": np.arange(n // 4, dtype=np.int64),
+        "o_seg": [["BUILDING", "AUTOMOBILE", "MACHINERY"][i % 3]
+                  for i in range(n // 4)]}).collect()
+    lines = daft_tpu.from_pydict({
+        "l_key": rng.randint(0, n // 4, n).astype(np.int64),
+        "l_price": rng.rand(n), "l_disc": rng.rand(n) / 10,
+        "l_mode": [["MAIL", "SHIP", "AIR", "RAIL"][i % 4] for i in range(n)],
+        "l_qty": rng.randint(1, 50, n).astype(np.int64)}).collect()
+    return orders, lines
+
+
+QUERY_SHAPES = {
+    "projection": lambda o, li: li.select(
+        (col("l_price") * (1 - col("l_disc"))).alias("rev")),
+    "filter_agg": lambda o, li: li.where(
+        col("l_mode").is_in(["MAIL", "SHIP"]) & (col("l_price") > 0.1)
+    ).groupby("l_mode").agg(col("l_price").sum().alias("s")),
+    "string_transform": lambda o, li: li.where(
+        col("l_mode").str.lower() == "mail").select(col("l_qty")),
+    "join_agg": lambda o, li: o.where(col("o_seg") == "BUILDING").join(
+        li, left_on="o_key", right_on="l_key").select(
+        col("o_key"), (col("l_price") * (1 - col("l_disc"))).alias("rev")
+    ).groupby("o_key").agg(col("rev").sum().alias("rev")).sort("rev").limit(5),
+}
+
+
+@pytest.mark.parametrize("x64", [True, False], ids=["x64", "x32"])
+@pytest.mark.parametrize("shape", list(QUERY_SHAPES))
+def test_device_arrays_die_with_their_query(shape, x64, device_kernels_on):
+    import gc
+
+    import jax
+
+    x64_was = bool(jax.config.jax_enable_x64)
+    jax.config.update("jax_enable_x64", x64)
+    try:
+        orders, lines = _orders_and_lines()
+        QUERY_SHAPES[shape](orders, lines).collect()  # warm: stage caches
+        gc.collect()
+        alive = len(jax.live_arrays())
+        gc.disable()
+        try:
+            df = QUERY_SHAPES[shape](orders, lines)
+            out = df.collect().to_pydict()
+            c = df.stats.snapshot()["counters"]
+            assert any(k.startswith("device_") and not k.endswith("_ns")
+                       and v for k, v in c.items()), c
+            del df, out
+            assert len(jax.live_arrays()) == alive
+        finally:
+            gc.enable()
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+
+
+def test_int64_wrap_safe_lets_go_of_env():
+    # its interval walk is recursive closures over `env`: they are dropped
+    # on return, so the attempt's arrays do not wait for the collector
+    import gc
+    import weakref
+
+    import jax
+
+    class Env(dict):
+        pass
+
+    x64_was = bool(jax.config.jax_enable_x64)
+    jax.config.update("jax_enable_x64", False)
+    gc.collect()
+    gc.disable()
+    try:
+        t = Table.from_pydict({"a": np.arange(100, dtype=np.int64)})
+        nodes = dev.normalize_and_check([(col("a") * col("a") + 1).alias("x")],
+                                        t.schema)
+        env = Env(dev.stage_table_columns(t, ["a"], 1024, None)[0])
+        alive = weakref.ref(env)
+        assert dev.int64_wrap_safe(nodes, t.schema, env, None, 1024)
+        del env
+        assert alive() is None
+    finally:
+        gc.enable()
+        jax.config.update("jax_enable_x64", x64_was)
