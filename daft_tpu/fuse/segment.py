@@ -406,8 +406,8 @@ def run_segment_async(table, prog: SegmentProgram,
     raises only for real device failures (the breaker's concern)."""
     from ..kernels.device import (_stage_and_run, fetch, int64_wrap_safe,
                                   size_bucket)
-    from ..kernels.device_agg import (_compile_agg, _finish_agg,
-                                      group_codes_cached)
+    from ..kernels.device_agg import (_finish_agg, group_codes_cached,
+                                      launch_agg)
 
     # runtime firing point of the fuse.segment fault site: the resident
     # handoff (the compile-time firing point is _try_compile_segment)
@@ -437,28 +437,17 @@ def run_segment_async(table, prog: SegmentProgram,
     # filtered first-occurrence group order the host path produces
     codes_dev, uniq, num_groups = group_codes_cached(
         table, prog.gb_inputs, stage_cache, n, b, stats)
-    gbk = max(16, 1 << (num_groups - 1).bit_length())
-
-    use_pallas = bool(getattr(cfg, "use_pallas_segment_sums", False))
-    run = _compile_agg(prog.child_nodes, prog.pred_node, prog.inter_schema,
-                       prog.input_names, prog.kinds, prog.modes, gbk,
-                       use_pallas)
-
-    nkey = ("nrows", n)
-    n_dev = stage_cache.get(nkey) if stage_cache is not None else None
-    if n_dev is None:
-        import jax.numpy as jnp
-
-        n_dev = jnp.int32(n)
-        if stage_cache is not None:
-            stage_cache[nkey] = n_dev
 
     hbm = sum(int(v.nbytes) + int(m.nbytes) for v, m in env2.values())
     if stats is not None:
         stats.bump_max("hbm_resident_bytes_high_water", hbm)
     _proc_max("hbm_resident_bytes_high_water", hbm)
 
-    outs_dev = run(env2, codes_dev, n_dev)  # async: device computes from here
+    outs_dev = launch_agg(
+        prog.child_nodes, prog.pred_node, prog.inter_schema, prog.input_names,
+        prog.kinds, prog.modes, num_groups,
+        bool(getattr(cfg, "use_pallas_segment_sums", False)),
+        env2, codes_dev, n, stage_cache)  # async: device computes from here
 
     def resolve():
         import numpy as np

@@ -2693,12 +2693,25 @@ def segment_aggregate(values: jax.Array, valid: jax.Array, codes: jax.Array,
 _ONEHOT_MAX_SEGMENTS = 4096
 _REDUCE_CHUNK = 8192
 
+# Up to this many segments every reduction takes the dense form
+# (``_dense_reduce``): the largest bucket at which it beat both the one-hot
+# forms and the pallas kernel over 64M rows and tied them over a 128k-row
+# morsel, compile time included (tools/segment_sum_sweep.py on a v5e; the
+# table is in PERF.md). At 64 it loses to the one-hot form by a factor of two.
+DENSE_MAX_SEGMENTS = 32
+_LANES = 128
+# sublane rows a dense chunk: each lane adds this many floats in sequence
+# before the pairwise combine, so a float32 partial stays short
+_DENSE_ROWS = 1024
+
 
 def segment_reduce(values: jax.Array, valid: jax.Array, codes: jax.Array,
                    num_segments: int, kind: str) -> Tuple[jax.Array, jax.Array]:
     """TPU-tuned masked segment reduction -> (per-group values, per-group valid).
 
-    Low-cardinality strategy: chunked one-hot compare-reduce with a
+    Small buckets (``DENSE_MAX_SEGMENTS``): one masked reduction a group with
+    the rows on the lane axis, float sums combined pairwise across chunks.
+    Low-cardinality strategy above that: chunked one-hot compare-reduce with a
     Kahan-compensated cross-chunk combine for float sums (accumulation error
     stays at the float32 representation floor, ~5e-8 relative, instead of
     growing with rows — required for TPC-H money-sum parity in 32-bit mode).
@@ -2707,7 +2720,9 @@ def segment_reduce(values: jax.Array, valid: jax.Array, codes: jax.Array,
     if kind == "count":
         cnt = _segment_count(valid, codes, num_segments)
         return cnt, jnp.ones(num_segments, dtype=bool)
-    if num_segments <= _ONEHOT_MAX_SEGMENTS and values.ndim == 1:
+    if values.ndim == 1 and _dense_rows(codes.shape[0], num_segments):
+        out = _dense_reduce(values, valid, codes, num_segments, kind)
+    elif num_segments <= _ONEHOT_MAX_SEGMENTS and values.ndim == 1:
         out = _onehot_reduce(values, valid, codes, num_segments, kind)
     elif kind == "sum" and jnp.issubdtype(values.dtype, jnp.floating) and values.ndim == 1:
         out = _scatter_sum_kahan(jnp.where(valid, values, 0), codes, num_segments)
@@ -2722,6 +2737,8 @@ def _count_dtype():
 
 
 def _segment_count(valid, codes, num_segments):
+    if _dense_rows(codes.shape[0], num_segments):
+        return _dense_reduce(valid, valid, codes, num_segments, "count")
     if num_segments <= _ONEHOT_MAX_SEGMENTS:
         b = valid.shape[0]
         chunk = min(_REDUCE_CHUNK, b)
@@ -2731,6 +2748,85 @@ def _segment_count(valid, codes, num_segments):
             & valid.reshape(nch, chunk, 1)
         return jnp.sum(jnp.sum(sel, axis=1, dtype=_count_dtype()), axis=0)
     return jax.ops.segment_sum(valid.astype(_count_dtype()), codes, num_segments)
+
+
+def _dense_rows(b: int, num_segments: int) -> int:
+    """Sublane rows of one dense chunk over ``b`` rows, or 0 where the dense
+    form does not apply: the bucket is over ``DENSE_MAX_SEGMENTS`` or ``b``
+    does not split into whole (rows, 128) chunks (every size bucket does)."""
+    if num_segments > DENSE_MAX_SEGMENTS or b % _LANES:
+        return 0
+    rows = min(_DENSE_ROWS, b // _LANES)
+    return rows if b % (rows * _LANES) == 0 else 0
+
+
+def _pairwise_sum(p):
+    """Sum over the last axis (a power of two long) as a balanced tree:
+    float32 error grows with the tree's depth, not with its width."""
+    while p.shape[-1] > 1:
+        half = p.shape[-1] // 2
+        p = p[..., :half] + p[..., half:]
+    return p[..., 0]
+
+
+def _dense_hits(valid, codes, num_segments):
+    """bool (groups, chunks, rows, 128): the row is valid and of the group.
+    The columns are viewed in place, the rows on the lane axis. The group
+    axis is what keeps the compiler from moving the reshape below the
+    ``where``: a lone group is best given a bucket of two (with the axis
+    gone the reduction is left unfused: 9.4 against 4.6 ms over 64M rows
+    and seven columns, tools/segment_sum_sweep.py on a v5e)."""
+    b = codes.shape[0]
+    rows = _dense_rows(b, num_segments)
+    shape = (1, b // (rows * _LANES), rows, _LANES)
+    groups = jnp.arange(num_segments, dtype=codes.dtype)
+    return valid.reshape(shape) & (
+        codes.reshape(shape) == groups.reshape(-1, 1, 1, 1))
+
+
+def _dense_reduce(values, valid, codes, num_segments, kind):
+    """One masked reduction a group: each group's ``where`` reduces over the
+    rows of a chunk, and the (group, chunk, lane) partials combine at the
+    end, float sums pairwise. The compare, the mask and whatever elementwise
+    work produced ``values`` fuse into the reduction, and sibling
+    reductions over the same rows share one pass: nothing is stacked,
+    padded or multiplied by a one-hot matrix."""
+    hit = _dense_hits(valid, codes, num_segments)
+    if kind == "count":
+        part = jnp.sum(hit, axis=2, dtype=_count_dtype())
+        return jnp.sum(part.reshape(num_segments, -1), axis=1)
+    v = values.reshape((1,) + hit.shape[1:])
+    if kind == "sum":
+        part = jnp.sum(jnp.where(hit, v, jnp.zeros_like(v)), axis=2)
+        part = part.reshape(num_segments, -1)
+        if jnp.issubdtype(values.dtype, jnp.floating):
+            return _pairwise_sum(part)
+        return jnp.sum(part, axis=1)
+    if kind not in ("min", "max"):
+        raise ValueError(kind)
+    ident, reduce_ = ((_type_max, jnp.min) if kind == "min"
+                      else (_type_min, jnp.max))
+    part = reduce_(jnp.where(hit, v, jnp.full_like(v, ident(values.dtype))),
+                   axis=2)
+    return reduce_(part.reshape(num_segments, -1), axis=1)
+
+
+def segment_first_index(valid: jax.Array, codes: jax.Array,
+                        num_segments: int) -> jax.Array:
+    """int32 per segment: the least index of its valid rows (int32's
+    maximum where it has none)."""
+    b = codes.shape[0]
+    if not _dense_rows(b, num_segments):
+        idx = jnp.arange(b, dtype=jnp.int32)
+        return segment_reduce(idx, valid, codes, num_segments, "min")[0]
+    hit = _dense_hits(valid, codes, num_segments)
+    # the index is built in the view's shape: an iota reshaped from one
+    # dimension is written out and read back
+    chunk, row, lane = (jax.lax.broadcasted_iota(jnp.int32, hit.shape, d)
+                        for d in (1, 2, 3))
+    idx = (chunk * hit.shape[2] + row) * _LANES + lane
+    part = jnp.min(jnp.where(hit, idx, _type_max(jnp.int32)), axis=2)
+    return jnp.min(part.reshape(num_segments, -1), axis=1)
 
 
 def _kahan_combine(partials):

@@ -35,9 +35,13 @@ import jax
 import jax.numpy as jnp
 
 from ..datatypes import DataType
+from ..profile import timeline
 from .device import (
+    _ONEHOT_MAX_SEGMENTS,
+    DENSE_MAX_SEGMENTS,
     compile_projection,
     fetch,
+    segment_first_index,
     segment_reduce,
     size_bucket,
     stage_table_columns,
@@ -344,7 +348,6 @@ def device_grouped_agg_async(table, to_agg, group_by,
     b = size_bucket(n)
     codes_dev, uniq, num_groups = group_codes_cached(table, group_by,
                                                      stage_cache, n, b, stats)
-    gb = max(16, 1 << (num_groups - 1).bit_length())  # static segment bucket
 
     # --- stage inputs -----------------------------------------------------
     from .device import (device_required_columns, epoch_cmp_env,
@@ -388,22 +391,12 @@ def device_grouped_agg_async(table, to_agg, group_by,
     # --- compile + run ONE fused program ---------------------------------
     from ..context import get_context
 
-    kinds = tuple(s[1] for s in specs)
-    modes = tuple(s[3] for s in specs)
-    _cfg = get_context().execution_config
-    use_pallas = bool(_cfg.use_pallas_segment_sums)
-    run = _compile_agg(tuple(child_nodes), pred_nodes[0] if pred_nodes else None,
-                       schema, tuple(sorted(needed)), kinds, modes, gb,
-                       use_pallas)
-    # the row-count scalar lives on device with the partition, so a warm
-    # query makes zero uploads and ONE result fetch
-    nkey = ("nrows", n)
-    n_dev = stage_cache.get(nkey) if stage_cache is not None else None
-    if n_dev is None:
-        n_dev = jnp.int32(n)
-        if stage_cache is not None:
-            stage_cache[nkey] = n_dev
-    outs_dev = run(env, codes_dev, n_dev)  # async: device computes from here
+    outs_dev = launch_agg(
+        tuple(child_nodes), pred_nodes[0] if pred_nodes else None, schema,
+        tuple(sorted(needed)), tuple(s[1] for s in specs),
+        tuple(s[3] for s in specs), num_groups,
+        bool(get_context().execution_config.use_pallas_segment_sums),
+        env, codes_dev, n, stage_cache)  # async: device computes from here
 
     def resolve():
         outs = fetch(outs_dev)
@@ -467,6 +460,49 @@ class _ExprView:
         return self._node.name()
 
 
+def _sum_form(gb: int, use_pallas: bool) -> str:
+    """The form an aggregate program's float sums take over a bucket of
+    ``gb`` segments: "dense" (per-group masked reductions, every small
+    bucket), "kernel" (the batched pallas one-hot matmul: 32-bit mode, up to
+    segment_reduce's one-hot cap, past which even a one-lane-tile one-hot
+    block outgrows VMEM) or "segment" (segment_reduce's own routes)."""
+    if gb <= DENSE_MAX_SEGMENTS:
+        return "dense"
+    if use_pallas and not x64_enabled() and gb <= _ONEHOT_MAX_SEGMENTS:
+        return "kernel"
+    return "segment"
+
+
+def launch_agg(child_nodes, pred_node, schema, input_names, kinds, modes,
+               num_groups: int, use_pallas: bool, env, codes_dev, n: int,
+               stage_cache: Optional[dict]):
+    """Compile (once a plan shape and segment bucket) and launch the fused
+    aggregate program over staged inputs; returns its device outputs
+    without waiting. Shared by the staged path and the resident segment
+    runtime (fuse/segment.py). Bumps ``agg_reduce_dense`` or
+    ``agg_reduce_kernel`` by the form the program's float sums take."""
+    # static segment bucket: the power of two over the groups (seven sum
+    # columns over 64M rows: 4.6 ms at 2 and 4, 5.3 at 8, 8.6 at 16, 20.9 at
+    # 32; tools/segment_sum_sweep.py), and never one (device._dense_hits)
+    gb = max(2, 1 << (num_groups - 1).bit_length())
+    run = _compile_agg(child_nodes, pred_node, schema, input_names, kinds,
+                       modes, gb, use_pallas)
+    form = _sum_form(gb, use_pallas)
+    if form == "dense" or (form == "kernel" and any(
+            kind in ("sum", "mean") and nd.to_field(schema).dtype.is_floating()
+            for nd, kind in zip(child_nodes, kinds))):
+        timeline.add(f"agg_reduce_{form}", 1)
+    # the row-count scalar lives on device with the partition, so a warm
+    # query makes zero uploads and ONE result fetch
+    nkey = ("nrows", n)
+    n_dev = stage_cache.get(nkey) if stage_cache is not None else None
+    if n_dev is None:
+        n_dev = jnp.int32(n)
+        if stage_cache is not None:
+            stage_cache[nkey] = n_dev
+    return run(env, codes_dev, n_dev)
+
+
 def _compile_agg(child_nodes, pred_node, schema, input_names, kinds, modes, gb,
                  use_pallas: bool = False):
     key = (tuple(n._key() for n in child_nodes),
@@ -481,8 +517,9 @@ def _compile_agg(child_nodes, pred_node, schema, input_names, kinds, modes, gb,
     if pred_node is not None:
         pred_run, _ = compile_projection([pred_node], schema, input_names)
 
-    from .device import _ONEHOT_MAX_SEGMENTS
     from .pallas_ops import segment_sums_lanes
+
+    pallas_ok = _sum_form(gb, use_pallas) == "kernel"
 
     @jax.jit
     def run(env, codes, n):
@@ -493,13 +530,10 @@ def _compile_agg(child_nodes, pred_node, schema, input_names, kinds, modes, gb,
         else:
             sel = inbounds
         # In 32-bit mode every float sum accumulates in float32 anyway, so
-        # the batched pallas kernel (ALL float-sum columns in ONE one-hot
-        # MXU pass, pallas_ops.py) keeps the segment_sum route's accuracy
-        # contract; x64 mode keeps exact float64 segment sums. The
-        # group-cardinality bound mirrors segment_reduce's one-hot cap
-        # (past it even a one-lane-tile one-hot block outgrows VMEM).
-        pallas_ok = (use_pallas and not x64_enabled()
-                     and gb <= _ONEHOT_MAX_SEGMENTS)
+        # above the dense bound the batched pallas kernel (ALL float-sum
+        # columns in ONE one-hot MXU pass, pallas_ops.py) keeps the
+        # segment_sum route's accuracy contract; x64 mode keeps exact
+        # float64 segment sums.
         fused_sums = []  # (slot in outs, pre-masked float32 column, cnt)
         outs = []
         for (v, m), kind, mode in zip(child_run(env), kinds, modes):
@@ -557,9 +591,7 @@ def _compile_agg(child_nodes, pred_node, schema, input_names, kinds, modes, gb,
             # reorder survivors by first selected row (host semantics:
             # first-occurrence order of the filtered table)
             sel_cnt, _ = segment_reduce(sel, sel, codes, gb, "count")
-            idx = jnp.arange(codes.shape[0], dtype=jnp.int32)
-            first_idx, _ = segment_reduce(idx, sel, codes, gb, "min")
-            outs.append((sel_cnt, first_idx))
+            outs.append((sel_cnt, segment_first_index(sel, codes, gb)))
         return outs
 
     _AGG_CACHE[key] = run

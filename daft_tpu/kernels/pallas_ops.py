@@ -1,11 +1,23 @@
 """Pallas TPU kernels for the aggregation hot path.
 
-The TPC-H-Q1-shaped pipeline (filter mask -> K weighted segment sums over
-small group cardinality) is one fused MXU program here: each grid step loads a
-block of rows into VMEM, forms the one-hot group matrix, and accumulates its
-contraction with the values into a (K, groups) VMEM accumulator — so ALL K
-aggregate columns ride a single data pass through the systolic array, instead
-of K separate `segment_sum` lowerings touching HBM K times.
+K weighted segment sums over a middling group cardinality are one MXU program
+here: each grid step loads a block of rows into VMEM, forms the one-hot group
+matrix, and accumulates its contraction with the values into a (K, groups)
+VMEM accumulator — so ALL K aggregate columns ride a single data pass through
+the systolic array, instead of K separate `segment_sum` lowerings touching HBM
+K times.
+
+Which buckets it serves: a fused aggregate program (device_agg._compile_agg)
+batches its float sums here in 32-bit mode when its segment bucket is over
+``device.DENSE_MAX_SEGMENTS`` (32) and at most ``_ONEHOT_MAX_SEGMENTS``
+(4096). At or under the dense bound the compiler's own code is faster: the
+kernel's cost is flat in the group count (one matmul against a 128-lane
+one-hot tile at ``Precision.HIGHEST`` a block of rows, whatever the groups),
+42-59 ms over 64M rows for one to seven columns, where per-group masked
+reductions take 2-9 ms up to 16 groups and 6-21 ms at 32. Against
+segment_reduce's one-hot form it wins from 128 groups with several columns
+(96 against 284 ms at seven) and loses at 64 and with a single column at
+1024 (tools/segment_sum_sweep.py on a v5e; the table is in PERF.md).
 
 Layout: rows run along the LANE axis everywhere — codes are (1, n), values
 are (K, n), the one-hot is (groups, block). A (n, 1) or (n, K) operand is

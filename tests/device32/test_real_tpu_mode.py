@@ -603,9 +603,10 @@ class TestDeviceJoin:
 
 
 class TestPallasFusedSums:
-    """The batched pallas one-hot matmul path (32-bit mode) must produce the
-    same float32-accumulated sums as the segment_sum route, and must actually
-    be the route taken (kernels/device_agg.py fused_sums batch)."""
+    """The batched pallas one-hot matmul path (32-bit mode, segment buckets
+    over DENSE_MAX_SEGMENTS) must produce the same float32-accumulated sums
+    as the segment_sum route, and must actually be the route taken
+    (kernels/device_agg.py fused_sums batch)."""
 
     def test_parity_with_segment_sum_route(self, host_mode):
         import daft_tpu as dt
@@ -614,7 +615,7 @@ class TestPallasFusedSums:
         cfg = dt.context.get_context().execution_config
         rng = np.random.RandomState(5)
         n = 6000
-        data = {"g": rng.randint(0, 12, n).astype(np.int32),
+        data = {"g": rng.randint(0, 40, n).astype(np.int32),
                 "a": rng.rand(n).astype(np.float32),
                 "b": (rng.rand(n) * 100).astype(np.float32)}
 
@@ -628,11 +629,13 @@ class TestPallasFusedSums:
         cfg.use_pallas_segment_sums = True
         q1 = q(); got = q1.collect().to_pydict()
         assert q1.stats.snapshot()["counters"].get("device_aggregations", 0) >= 1
+        assert _agg_forms(q1) == (0, 1)
         device_agg._AGG_CACHE.clear()
         cfg.use_pallas_segment_sums = False
         try:
             q2 = q(); want = q2.collect().to_pydict()
             assert q2.stats.snapshot()["counters"].get("device_aggregations", 0) >= 1
+            assert _agg_forms(q2) == (0, 0)  # segment_reduce's one-hot form
         finally:
             cfg.use_pallas_segment_sums = True
             device_agg._AGG_CACHE.clear()
@@ -656,7 +659,7 @@ class TestPallasFusedSums:
         device_agg._AGG_CACHE.clear()
         rng = np.random.RandomState(6)
         n = 5000
-        df = dt.from_pydict({"g": rng.randint(0, 8, n).astype(np.int32),
+        df = dt.from_pydict({"g": rng.randint(0, 40, n).astype(np.int32),
                              "x": rng.rand(n).astype(np.float32),
                              "y": rng.rand(n).astype(np.float32)})
         q = df.groupby("g").agg(col("x").sum().alias("sx"),
@@ -665,6 +668,151 @@ class TestPallasFusedSums:
         device_agg._AGG_CACHE.clear()
         assert q.stats.snapshot()["counters"].get("device_aggregations", 0) >= 1
         assert calls and calls[0][0] == 2, calls  # both sums in ONE batch
+        assert _agg_forms(q) == (0, 1)
+
+
+def _agg_forms(df):
+    """(agg_reduce_dense, agg_reduce_kernel) of a collected query."""
+    c = _counters(df)
+    return c.get("agg_reduce_dense", 0), c.get("agg_reduce_kernel", 0)
+
+
+def _money_frame(groups, n=65536, seed=32):
+    """64k rows of money-sized float64 values with nulls, keyed 0..groups-1;
+    returns (pydict for the engine, numpy views for the reference)."""
+    import pyarrow as pa
+
+    rng = np.random.RandomState(seed + groups)
+    g = rng.randint(0, groups, n).astype(np.int32)
+    price = rng.uniform(900.0, 105000.0, n)
+    disc = rng.randint(0, 11, n) / 100.0
+    price_null = rng.rand(n) < 0.03
+    disc_null = rng.rand(n) < 0.03
+    frame = dt.from_arrow(pa.table({
+        "g": pa.array(g),
+        "price": pa.array(price, mask=price_null),
+        "disc": pa.array(disc, mask=disc_null),
+    }))
+    return frame, (g, price, disc, ~price_null, ~disc_null)
+
+
+class TestDenseSegmentReduce32:
+    """Segment buckets up to DENSE_MAX_SEGMENTS take per-group masked
+    reductions with the rows on the lane axis (kernels/device._dense_reduce)
+    for every reduction of the fused aggregate program: float32 sums within
+    1e-6 of a float64 numpy sum, counts, survival counts and first indices
+    exact, and the form is the one the counters say."""
+
+    @pytest.mark.parametrize("groups", [1, 2, 7, 16, 32])
+    def test_sums_and_counts_against_float64(self, groups):
+        frame, (g, price, disc, pv, dv) = _money_frame(groups)
+        disc_price = col("price") * (1 - col("disc"))
+        out = (frame.groupby("g").agg(
+            col("price").sum().alias("sum_price"),
+            disc_price.sum().alias("sum_disc_price"),
+            col("price").mean().alias("avg_price"),  # the sum's column again
+            col("disc").mean().alias("avg_disc"),
+            col("price").count().alias("n_price"),
+            col("price").min().alias("min_price"),
+            col("price").max().alias("max_price"),
+        ).sort("g").collect())
+        assert _counters(out).get("device_aggregations", 0) >= 1
+        assert _agg_forms(out) == (1, 0)
+        got = out.to_pydict()
+        assert got["g"] == list(range(groups))
+        for k in range(groups):
+            p = pv & (g == k)
+            both = p & dv
+            d = dv & (g == k)
+            assert got["n_price"][k] == int(p.sum())
+            want = {
+                "sum_price": price[p].sum(),
+                "sum_disc_price": (price[both] * (1 - disc[both])).sum(),
+                "avg_price": price[p].mean(),
+                "avg_disc": disc[d].mean(),
+                "min_price": price[p].min(),
+                "max_price": price[p].max(),
+            }
+            for name, w in want.items():
+                np.testing.assert_allclose(got[name][k], w, rtol=1e-6,
+                                           err_msg=f"{name}[{k}]")
+
+    def test_predicate_empties_a_group(self, host_mode):
+        frame, (g, price, _disc, pv, _dv) = _money_frame(7)
+
+        def q():
+            return (frame.where((col("g") != 3) & (col("price") > 50000.0))
+                    .groupby("g").agg(col("price").sum().alias("s"),
+                                      col("price").count().alias("c")))
+        dev, host = _run_both(q, host_mode)
+        assert _agg_forms(dev) == (1, 0)
+        d, h = dev.to_pydict(), host.to_pydict()
+        # the emptied group is gone; survivors in first-selected-row order
+        assert 3 not in d["g"] and d["g"] == h["g"] and d["c"] == h["c"]
+        np.testing.assert_allclose(d["s"], h["s"], rtol=1e-6)
+        keep = pv & (price > 50000.0)
+        for k, c, s_ in zip(d["g"], d["c"], d["s"]):
+            assert c == int((keep & (g == k)).sum())
+            np.testing.assert_allclose(s_, price[keep & (g == k)].sum(),
+                                       rtol=1e-6)
+
+    @pytest.mark.parametrize("groups,forms", [(32, (1, 0)), (33, (0, 1))])
+    def test_form_on_each_side_of_the_bound(self, groups, forms):
+        from daft_tpu.kernels.device import DENSE_MAX_SEGMENTS
+
+        assert DENSE_MAX_SEGMENTS == 32
+        rng = np.random.RandomState(groups)
+        n = 6000
+        g = np.concatenate([np.arange(groups), rng.randint(0, groups,
+                                                           n - groups)])
+        x = rng.rand(n) * 1000.0
+        out = (dt.from_pydict({"g": g.astype(np.int32), "x": x})
+               .groupby("g").agg(col("x").sum().alias("s")).sort("g")
+               .collect())
+        assert _counters(out).get("device_aggregations", 0) >= 1
+        assert _agg_forms(out) == forms
+        want = np.bincount(g, weights=x, minlength=groups)
+        np.testing.assert_allclose(out.to_pydict()["s"], want, rtol=1e-6)
+
+    @pytest.mark.parametrize("groups", [2, 8, 32])
+    @pytest.mark.parametrize("kind", ["sum", "min", "max", "count", "first"])
+    def test_same_answers_as_the_one_hot_form(self, groups, kind,
+                                              monkeypatch):
+        import jax.numpy as jnp
+
+        from daft_tpu.kernels import device as dev
+
+        rng = np.random.RandomState(groups)
+        n = 1 << 18  # two dense chunks
+        codes = rng.randint(0, groups, n).astype(np.int32)
+        codes[codes == groups - 1] = 0  # one group has no rows at all
+        valid = rng.rand(n) < 0.9
+        values = rng.randint(-1000, 1000, n).astype(np.int32)
+
+        def reduce_():
+            if kind == "first":
+                return dev.segment_first_index(
+                    jnp.asarray(valid), jnp.asarray(codes), groups), None
+            return dev.segment_reduce(jnp.asarray(values), jnp.asarray(valid),
+                                      jnp.asarray(codes), groups, kind)
+        assert dev._dense_rows(n, groups) == dev._DENSE_ROWS
+        dense, dense_valid = reduce_()
+        monkeypatch.setattr(dev, "DENSE_MAX_SEGMENTS", 0)
+        onehot, onehot_valid = reduce_()
+        assert dense.dtype == onehot.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(dense), np.asarray(onehot))
+        if dense_valid is not None:
+            np.testing.assert_array_equal(np.asarray(dense_valid),
+                                          np.asarray(onehot_valid))
+        # and against numpy, group by group
+        for k in range(groups):
+            rows = np.nonzero(valid & (codes == k))[0]
+            want = {"count": len(rows),
+                    "sum": int(values[rows].sum()),
+                    "min": values[rows].min() if len(rows) else 2**31 - 1,
+                    "max": values[rows].max() if len(rows) else -2**31,
+                    "first": rows[0] if len(rows) else 2**31 - 1}[kind]
+            assert int(dense[k]) == want, (kind, k)
 
 
 class TestTpchJoinRungs32:

@@ -1,0 +1,101 @@
+"""The dense segment reductions compiled for a v5e at the scan cell's size,
+here, without the chip (the TPU's compiler is installed; nothing runs).
+
+What a CPU run cannot show: whether the chip's compiler fuses a fused
+aggregate program's fan of per-group reductions into a few passes over the
+staged columns, or writes a 64M-row temporary a reduction (a Python loop over
+the groups did: 10 GB of temporaries; one variadic reduce did not fit the
+chip at all). The programs below are Q1's and Q6's shape through the
+program's own ``segment_reduce``; the compiler's own account of their
+temporaries is the guard. No time is read here.
+"""
+
+import pytest
+
+ROWS = 1 << 26  # tpch10-scan-agg's bucket: 60M rows of LINEITEM
+GIB = 1 << 30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no compiler for the chip on this machine
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, dtypes):
+    import jax
+
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep it out
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        args = [jax.ShapeDtypeStruct((ROWS,), dt, sharding=one_chip)
+                for dt in dtypes]
+        return jax.jit(fn).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+
+
+def _sums_and_counts(columns, sel, codes, groups):
+    """What ``_compile_agg`` asks of ``segment_reduce`` for float sums."""
+    from daft_tpu.kernels import device as dev
+
+    outs = []
+    for v, m in columns:
+        m = m & sel
+        outs.append((dev.segment_reduce(v, m, codes, groups, "sum"),
+                     dev.segment_reduce(m, m, codes, groups, "count")[0]))
+    outs.append(dev.segment_reduce(sel, sel, codes, groups, "count")[0])
+    outs.append(dev.segment_first_index(sel, codes, groups))
+    return outs
+
+
+def test_q1_shape_fuses_into_few_passes(one_chip):
+    import jax.numpy as jnp
+
+    def q1(qty, price, disc, tax, ship, codes, vq, vp, vd, vt, vs):
+        sel = (ship <= 10471) & vs
+        disc_price = price * (1 - disc)
+        charge = disc_price * (1 + tax)
+        columns = [(qty, vq), (price, vp), (disc_price, vp & vd),
+                   (charge, vp & vd & vt), (disc, vd)]
+        return _sums_and_counts(columns, sel, codes, 8)
+
+    f32, i32, b = jnp.float32, jnp.int32, jnp.bool_
+    compiled = _compile(q1, one_chip,
+                        [f32, f32, f32, f32, i32, i32, b, b, b, b, b])
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # the compiler's code, no kernel
+    # no stacked or padded (K, rows) operand, no minor dimension of groups
+    assert "f32[8,67108864]" not in text and "f32[7,67108864]" not in text
+    # the masks and the two products are still written out (0.81 GiB); a
+    # temporary a reduction would be tens of GiB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 * GIB
+
+
+def test_ungrouped_shape_stays_fused(one_chip):
+    import jax.numpy as jnp
+
+    # Q6: a lone group is given a bucket of two (device._dense_hits): with
+    # the group axis gone the compiler writes the value column out and
+    # reduces it unfused
+    def q6(price, disc, qty, codes, vp, vd, vq):
+        sel = (disc >= 0.05) & (disc <= 0.07) & (qty < 24) & vd & vq
+        return _sums_and_counts([(price * disc, vp & vd)], sel, codes, 2)
+
+    f32, i32, b = jnp.float32, jnp.int32, jnp.bool_
+    compiled = _compile(q6, one_chip, [f32, f32, f32, i32, b, b, b])
+    entry = compiled.as_text().split("ENTRY", 1)[1]
+    written = [line.split(" = ", 1)[0].strip() for line in entry.splitlines()
+               if " fusion(" in line
+               and "f32[67108864]" in line.split(" fusion(", 1)[0]]
+    assert not written, written  # no float column is written out
+    assert compiled.memory_analysis().temp_size_in_bytes < GIB // 2

@@ -632,9 +632,9 @@ class TestDictLookup:
         out, (packed, gathered, _) = q12ish(modes, ["MAIL", "SHIP"])
         assert out["s"] == ["MAIL", "SHIP"] and (packed, gathered) == (1, 0)
         # another dictionary of the same bucket (one word; the same bucket
-        # of 16 groups too), other contents and other hit positions: the
+        # of 8 groups too), other contents and other hit positions: the
         # words are a traced input
-        others = [f"N{i:02d}" for i in range(11)] + ["MAIL", "SHIP"]
+        others = [f"N{i:02d}" for i in range(4)] + ["MAIL", "SHIP"]
         out, (packed, gathered, compiles) = q12ish(others, ["MAIL", "SHIP"])
         assert out["s"] == ["MAIL", "SHIP"]
         assert (packed, gathered, compiles) == (1, 0, 0)
