@@ -6,10 +6,11 @@ partition-task generator chain). Each PhysicalOp.execute is a generator;
 composing them yields a fully streaming pipeline with early-stop (limit) and
 bounded buffering at pipeline breakers.
 
-The ExecutionContext also owns the device-kernel routing decision: eligible
-projections run through kernels/device.py (jit'd XLA) when enabled, host
-pyarrow otherwise — the TPU analog of the reference's fused
-pipeline_instruction execution.
+The ExecutionContext also owns the device-kernel routing decision, once for
+every shape: an operator declares a physical.DeviceStep, and
+ExecutionContext.launch / run send its partitions through the jit'd XLA
+kernels when eligible, its host (pyarrow) kernel otherwise — the TPU analog
+of the reference's fused pipeline_instruction execution.
 """
 
 from __future__ import annotations
@@ -726,7 +727,7 @@ class ExecutionContext:
         return False
 
     def _device_eligible(self, part: MicroPartition) -> bool:
-        if not self.cfg.use_device_kernels:
+        if not self.device_path_on():
             return False
         n = part.num_rows_or_none()
         if n is None and not self.foreign_owned(part):
@@ -743,15 +744,16 @@ class ExecutionContext:
         convention); a None result is a decline (probe slot released,
         breaker untouched). A non-None result records success —
         unless `launch` is set, in which case the caller owns the outcome
-        (async dispatch: the launch succeeding says nothing about the
-        deferred computation, whose resolver records for real)."""
+        (a step's launch succeeding says nothing about the computation it
+        started, whose finisher records for real)."""
         from . import faults
         from .kernels.compile_cache import configure_compile_cache
         from .profile.timeline import DeviceFrame
 
         configure_compile_cache()
         # device_dispatch_ns: this attempt's wall less the staging (and, for
-        # a synchronous attempt, the waits and copies) recorded inside it
+        # the whole-in-one sort and distinct attempts, the waits and copies)
+        # recorded inside it
         with DeviceFrame(self.stats, "dispatch", "device_dispatch_ns"):
             try:
                 faults.check("device.kernel", self.stats)
@@ -775,15 +777,8 @@ class ExecutionContext:
         with DeviceFrame(self.stats, "gather", "gather_ns"):
             return resolve()
 
-    def _resolve_now(self, resolve):
-        """A synchronous attempt's second half: resolve what it has just
-        launched, inside the attempt (a failure is the attempt's), with
-        the wait and gather accounting of a deferred resolve. None (the
-        launch declined) stays None."""
-        return None if resolve is None else self._device_resolve(resolve)
-
     def _device_failed(self, site: str, exc: BaseException) -> None:
-        """A device attempt (launch or deferred resolve) raised: report the
+        """A device attempt (launch or resolve) raised: report the
         exception and inform the breaker. The caller falls back to the host
         path — the answer stays right, the cause stays visible."""
         self.stats.note_device_error(site, exc)
@@ -794,442 +789,155 @@ class ExecutionContext:
         of a multi-process run owns its rows). Single-process: never."""
         return False
 
-    def _defer_projection(self, part: MicroPartition, exprs):
-        """Foreign-owned unloaded partition: append the projection to the
-        partition's pending op chain instead of reading the file (per-host
-        scan locality through map chains; the owner evaluates for real)."""
-        from .schema import Schema
+    # ------------------------------------------------------------------
+    # the device path: ONE launch/resolve contract for every DeviceStep
+    # (physical.DeviceStep: projection, filter, fused map, aggregate,
+    # sketch build, resident segment, join probe). A step says what differs
+    # between shapes; everything below is the policy, written once.
+    # ------------------------------------------------------------------
 
-        exprs = list(exprs)
-        schema = Schema([e._node.to_field(part.schema) for e in exprs])
-        return part.with_pending_op(
-            lambda t: t.eval_expression_list(exprs), schema,
-            count_preserving=True)
+    def device_path_on(self) -> bool:
+        """Is the device path switched on? The operators' one question:
+        no execution-time code outside this class reads the knob."""
+        return self.cfg.use_device_kernels
 
-    def eval_projection(self, part: MicroPartition, exprs) -> MicroPartition:
-        """Route a projection through the device kernel layer when eligible,
-        else the host path."""
+    def _collective_answer(self, step, parts) -> Optional[MicroPartition]:
+        """Hook for runners with a device mesh: a step answered by one
+        collective across the mesh, asked before the per-partition device
+        path. Single-host base context: never."""
+        return None
+
+    def launch(self, step, *parts):
+        """Launch `step` over `parts` on the device without blocking.
+        Returns a zero-arg finisher yielding the output partition (it falls
+        back to the step's host kernel itself, counters truthful), or None
+        when nothing was launched: the caller answers with ``step.host``.
+
+        The only place that asks eligibility, runs the attempt (one
+        ``dispatch`` frame, ``faults.check("device.kernel")`` once) and
+        bumps the step's counters. ``dispatches`` counts launches, whether
+        the caller resolves at once (``run``) or later."""
+        part = parts[0]
         if self.foreign_owned(part) and not part.is_loaded():
-            return self._defer_projection(part, exprs)
-        if self._device_eligible(part):
-            def _run():
-                from .kernels.device import eval_projection_device_async
-
-                return self._resolve_now(eval_projection_device_async(
-                    part.table(), list(exprs),
-                    stage_cache=part.device_stage_cache()))
-
-            out = self._device_attempt(_run)
-            if out is not None:
-                self.stats.bump("device_projections")
-                return part._wrap(out)
-        self.stats.bump("host_projections")
-        return part.eval_expression_list(exprs)
-
-    def eval_projection_dispatch(self, part: MicroPartition, exprs):
-        """Launch a device projection without blocking; returns a zero-arg
-        resolver yielding the output MicroPartition, or None when the device
-        path is ineligible (caller falls back to the synchronous
-        eval_projection). The resolver itself falls back to the host kernel
-        if the deferred device computation fails at materialization."""
-        if self.foreign_owned(part) and not part.is_loaded():
-            deferred = self._defer_projection(part, exprs)
-            return lambda: deferred
-        if not self._device_eligible(part):
+            # per-host scan locality: never read another process's file
+            deferred = step.defer(part)
+            if deferred is not None:
+                return lambda: deferred
+        done = self._collective_answer(step, parts)
+        if done is not None:
+            return lambda: done
+        if not step.has_program or not (
+                self._join_eligible(step, *parts) if len(parts) == 2
+                else self._device_eligible(part)):
             return None
-
-        def _launch():
-            from .kernels.device import eval_projection_device_async
-
-            return eval_projection_device_async(
-                part.table(), list(exprs), stage_cache=part.device_stage_cache())
-
-        resolve = self._device_attempt(_launch, launch=True)
+        resolve = self._device_attempt(
+            lambda: step.launch(self, *parts), launch=True)
         if resolve is None:
+            # failed (reported, breaker informed) or declined (probe slot
+            # released) before anything was launched
+            if step.counts_failed_launch:
+                return lambda: step.fall_back(self, *parts)
             return None
-        self.stats.bump("device_projections")
-        self.stats.bump("device_projection_dispatches")
+        step.count(self.stats, 1)
+        if step.dispatches is not None:
+            self.stats.bump(step.dispatches)
+        # the one finisher: it holds the step, the resolver and the
+        # partitions, never itself or a staged `env`, so the attempt's
+        # device arrays die with it (no wait for the cyclic collector)
+        return lambda: self._finish_step(step, resolve, parts)
 
-        def finish() -> MicroPartition:
+    def run(self, step, *parts) -> MicroPartition:
+        """`step` over `parts`, blocking: launched and finished at once
+        when eligible, else the step's host kernel. One code path for the
+        synchronous callers (worker pool, dist workers, streaming, lineage
+        recompute) and the pipelined ones."""
+        fin = self.launch(step, *parts)
+        return fin() if fin is not None else step.host(self, *parts)
+
+    def _finish_step(self, step, resolve, parts) -> MicroPartition:
+        """A launched attempt's second half: resolve (one ``gather``
+        frame), then the step's ``finish``. A raised exception is a device
+        failure (reported at the step's site, breaker informed); a None
+        result a decline (the aggregate's overflow guard, a segment
+        decline: probe slot released). Either way the partition was NOT
+        computed on the device after all: the counters say so and the host
+        kernel answers. All of it under the step's phase span, if it names
+        one (the segment's ``fuse.segment``)."""
+        from .profile.spans import _NOOP
+
+        with (self.stats.profiler.span(step.span, kind="phase")
+              if step.span is not None else _NOOP):
             try:
-                out = part._wrap(self._device_resolve(resolve))
+                out = self._device_resolve(resolve)
+                if out is not None and step.finish_falls_back:
+                    out = step.finish(self, out, *parts)
             except Exception as e:
-                # the partition was NOT computed on device after all: keep
-                # the counters truthful (same attribution the synchronous
-                # path's fallback produces)
-                self._device_failed("device.projection", e)
-                self.stats.bump("device_projections", -1)
-                self.stats.bump("device_projection_fallbacks")
-                self.stats.bump("host_projections")
-                return part.eval_expression_list(exprs)
+                out = None
+                self._device_failed(step.site, e)
+            else:
+                if out is None:
+                    self.device_health.release_probe()
+            if out is None:
+                step.count(self.stats, -1)
+                return step.fall_back(self, *parts)
             self.device_health.record_success(self.stats)
+            if not step.finish_falls_back:
+                out = step.finish(self, out, *parts)
             return out
 
-        return finish
+    def _take_by_device_index(self, part: MicroPartition, attempt,
+                              counter: str) -> Optional[MicroPartition]:
+        """The rows of `part` at the indices one whole-in-one device
+        attempt computes (launch and resolve inside the attempt's frame),
+        or None when the host must answer."""
+        if self._device_eligible(part):
+            idx = self._device_attempt(attempt)
+            if idx is not None:
+                import numpy as np
 
-    def _defer_fused(self, part: MicroPartition, program):
-        """Foreign-owned unloaded partition: the whole fused program joins
-        the pending op chain (one deferred single-pass map), preserving
-        per-host scan locality exactly like the unfused chain's deferred
-        Project/Filter ops would."""
-        return part.with_pending_op(
-            lambda t: program.run_host(t), program.out_schema,
-            count_preserving=program.count_preserving)
+                from .series import Series
 
-    def _eval_fused_host(self, part: MicroPartition, program) -> MicroPartition:
-        """Host single-pass evaluation of a fused chain. The legacy per-op
-        class counters advance by the chain's op counts so per-path
-        attribution stays comparable with the unfused engine."""
-        self.stats.bump("host_fused_maps")
-        g = program.graph
-        if g.n_project_ops:
-            self.stats.bump("host_projections", g.n_project_ops)
-        if g.n_filter_ops:
-            self.stats.bump("host_filters", g.n_filter_ops)
-        return part._wrap(program.run_host(part.table()))
-
-    def _bump_fused_device(self, program, n: int = 1) -> None:
-        g = program.graph
-        self.stats.bump("device_fused_maps", n)
-        if g.n_project_ops:
-            self.stats.bump("device_projections", n * g.n_project_ops)
-        if g.n_filter_ops:
-            self.stats.bump("device_filters", n * g.n_filter_ops)
-
-    def eval_fused(self, part: MicroPartition, program) -> MicroPartition:
-        """Route a fused map chain through the device kernel layer as ONE
-        jit program when eligible, else the segmented host pass."""
-        if self.foreign_owned(part) and not part.is_loaded():
-            return self._defer_fused(part, program)
-        if program.device_exprs is not None and self._device_eligible(part):
-            def _run():
-                from .kernels.device import eval_projection_device_async
-                from .profile import timeline
-
-                out = self._resolve_now(eval_projection_device_async(
-                    part.table(), program.device_exprs,
-                    stage_cache=part.device_stage_cache()))
-                if out is None:
-                    return None
-                # the chain's host half (mask compaction): the operator's
-                # own time, inside the attempt only so that a failure of it
-                # still falls back to the host pass
-                with timeline.timed("fuse.assemble"):
-                    return program.assemble_device(out)
-
-            out = self._device_attempt(_run)
-            if out is not None:
-                self._bump_fused_device(program)
-                return part._wrap(out)
-        return self._eval_fused_host(part, program)
-
-    def eval_fused_dispatch(self, part: MicroPartition, program):
-        """Non-blocking launch of the fused device program; same resolver
-        contract as eval_projection_dispatch (host fallback inside,
-        truthful counters)."""
-        if self.foreign_owned(part) and not part.is_loaded():
-            deferred = self._defer_fused(part, program)
-            return lambda: deferred
-        if program.device_exprs is None or not self._device_eligible(part):
-            return None
-
-        def _launch():
-            from .kernels.device import eval_projection_device_async
-
-            return eval_projection_device_async(
-                part.table(), program.device_exprs,
-                stage_cache=part.device_stage_cache())
-
-        resolve = self._device_attempt(_launch, launch=True)
-        if resolve is None:
-            return None
-        self._bump_fused_device(program)
-        self.stats.bump("device_fused_map_dispatches")
-
-        def finish() -> MicroPartition:
-            try:
-                out = program.assemble_device(self._device_resolve(resolve))
-            except Exception as e:
-                # the chain was NOT computed on device after all: keep the
-                # counters truthful, inform the breaker, host pass takes over
-                self._device_failed("device.fused_map", e)
-                self._bump_fused_device(program, -1)
-                self.stats.bump("device_fused_map_fallbacks")
-                return self._eval_fused_host(part, program)
-            self.device_health.record_success(self.stats)
-            return part._wrap(out)
-
-        return finish
+                self.stats.bump(counter)
+                return MicroPartition.from_table(part.table().take(
+                    Series.from_numpy(idx.astype(np.uint64), "indices")))
+        return None
 
     def eval_sort(self, part: MicroPartition, sort_by, descending=None,
                   nulls_first=None) -> MicroPartition:
         """Route a per-partition sort through the device argsort when
         eligible: keys compile + sort on device, only the payload take runs
         on host. Host pyarrow sort otherwise."""
-        if self._device_eligible(part):
-            def _run():
-                from .kernels.device import device_table_argsort
+        def _run():
+            from .kernels.device import device_table_argsort
 
-                return device_table_argsort(
-                    part.table(), sort_by, descending, nulls_first,
-                    stage_cache=part.device_stage_cache())
+            return device_table_argsort(
+                part.table(), sort_by, descending, nulls_first,
+                stage_cache=part.device_stage_cache())
 
-            idx = self._device_attempt(_run)
-            if idx is not None:
-                import numpy as np
-
-                from .series import Series
-
-                self.stats.bump("device_sorts")
-                tbl = part.table().take(
-                    Series.from_numpy(idx.astype(np.uint64), "indices"))
-                return MicroPartition.from_table(tbl)
+        out = self._take_by_device_index(part, _run, "device_sorts")
+        if out is not None:
+            return out
         self.stats.bump("host_sorts")
         return part.sort(sort_by, descending, nulls_first)
 
     def eval_distinct(self, part: MicroPartition, subset) -> MicroPartition:
         """Route distinct through the device group-codes kernel when the keys
         are device-eligible; host dictionary encode otherwise."""
-        if self._device_eligible(part):
-            def _run():
-                from .expressions import col
-                from .kernels.device_agg import device_distinct_indices
+        def _run():
+            from .expressions import col
+            from .kernels.device_agg import device_distinct_indices
 
-                keys = list(subset) if subset else [
-                    col(n) for n in part.column_names]
-                return device_distinct_indices(
-                    part.table(), keys, part.device_stage_cache(),
-                    len(part.table()))
+            keys = list(subset) if subset else [
+                col(n) for n in part.column_names]
+            return device_distinct_indices(
+                part.table(), keys, part.device_stage_cache(),
+                len(part.table()))
 
-            idx = self._device_attempt(_run)
-            if idx is not None:
-                import numpy as np
-
-                from .series import Series
-
-                self.stats.bump("device_distincts")
-                tbl = part.table().take(
-                    Series.from_numpy(idx.astype(np.uint64), "idx"))
-                return MicroPartition.from_table(tbl)
+        out = self._take_by_device_index(part, _run, "device_distincts")
+        if out is not None:
+            return out
         self.stats.bump("host_distincts")
         return part.distinct(subset)
-
-    def _sketch_build_device(self, part: MicroPartition, aggregations,
-                             groupby, predicate):
-        """Stage-1 sketch builds (all-sketch_hll agg lists) run their
-        register scatter on device when eligible — behind the same
-        DeviceHealth breaker + device.kernel fault site as every other
-        device kernel. The agg-kind gate runs FIRST, before any breaker or
-        fault-site touch, so non-sketch aggregations never consume a probe
-        slot or a planned fault. Returns a zero-arg resolver (launch
-        already dispatched; the resolver fetches + assembles, host-fallback
-        inside) or None = declined (the normal agg routing takes over)."""
-        from .sketch.device import aggs_all_sketch_hll
-
-        if (predicate is not None
-                or not aggs_all_sketch_hll(aggregations)
-                or not self._device_eligible(part)):
-            return None
-
-        def _launch():
-            from .sketch.device import hll_build_table_device_launch
-
-            return hll_build_table_device_launch(
-                part.table(), list(aggregations), list(groupby or []))
-
-        resolve = self._device_attempt(_launch, launch=True)
-        if resolve is None:
-            return None
-        self.stats.bump("device_sketch_builds")
-
-        def finish() -> MicroPartition:
-            try:
-                out = self._device_resolve(resolve)
-            except Exception as e:
-                # the scatter was NOT computed on device: truthful counters,
-                # breaker informed, host build takes over
-                self._device_failed("device.sketch", e)
-                self.stats.bump("device_sketch_builds", -1)
-                self.stats.bump("device_sketch_fallbacks")
-                return self._eval_agg_host(part, aggregations, groupby,
-                                           predicate)
-            self.device_health.record_success(self.stats)
-            return MicroPartition.from_table(out)
-
-        return finish
-
-    def eval_agg(self, part: MicroPartition, aggregations, groupby,
-                 predicate=None) -> MicroPartition:
-        """Route a (optionally filter-fused) grouped aggregation through the
-        fused device kernel when eligible, else the host path (host applies
-        the predicate first when one was fused)."""
-        fin = self._sketch_build_device(part, aggregations, groupby,
-                                        predicate)
-        if fin is not None:
-            return fin()
-        if self._device_eligible(part):
-            def _run():
-                from .kernels.device_agg import device_grouped_agg_async
-
-                return self._resolve_now(device_grouped_agg_async(
-                    part.table(), list(aggregations), list(groupby or []),
-                    stage_cache=part.device_stage_cache(),
-                    predicate=predicate, stats=self.stats))
-
-            out = self._device_attempt(_run)
-            if out is not None:
-                self.stats.bump("device_aggregations")
-                return MicroPartition.from_table(out)
-        return self._eval_agg_host(part, aggregations, groupby, predicate)
-
-    def _eval_agg_host(self, part: MicroPartition, aggregations, groupby,
-                       predicate=None) -> MicroPartition:
-        self.stats.bump("host_aggregations")
-        if predicate is not None:
-            tbl = part.table()
-            # acero single-pass pays off when the hash-agg subsumes the
-            # filtered-table materialization; ungrouped reductions are faster
-            # through the pruned filter+agg below (measured on TPC-H Q6)
-            out = tbl.acero_fused_agg(list(aggregations), list(groupby or []),
-                                      predicate) if groupby else None
-            if out is not None:
-                self.stats.bump("fused_host_aggregations")
-                return MicroPartition.from_table(out)
-            # unfused fallback: prune to referenced columns before filtering
-            # so the compaction doesn't copy payload the agg never reads
-            from .expressions import required_columns
-
-            need = set()
-            for e in list(aggregations) + list(groupby or []) + [predicate]:
-                need.update(required_columns(e))
-            if need and need < set(part.column_names):
-                keep = [n for n in part.column_names if n in need]
-                part = MicroPartition.from_table(tbl.select_columns(keep))
-            part = part.filter([predicate])
-        return part.agg(aggregations, groupby or None)
-
-    def eval_agg_dispatch(self, part: MicroPartition, aggregations, groupby,
-                          predicate=None):
-        """Non-blocking launch of the fused device aggregation; returns a
-        zero-arg resolver (host-fallback inside, truthful counters) or None
-        when ineligible — same contract as eval_projection_dispatch."""
-        fin = self._sketch_build_device(part, aggregations, groupby,
-                                        predicate)
-        if fin is not None:
-            return fin  # scatter already dispatched; resolver fetches
-        if not self._device_eligible(part):
-            return None
-
-        def _launch():
-            from .kernels.device_agg import device_grouped_agg_async
-
-            return device_grouped_agg_async(
-                part.table(), list(aggregations), list(groupby or []),
-                stage_cache=part.device_stage_cache(), predicate=predicate,
-                stats=self.stats)
-
-        resolve = self._device_attempt(_launch, launch=True)
-        if resolve is None:
-            return None
-        self.stats.bump("device_aggregations")
-        self.stats.bump("device_agg_dispatches")
-
-        def finish() -> MicroPartition:
-            try:
-                out = self._device_resolve(resolve)
-            except Exception as e:
-                out = None
-                self._device_failed("device.agg", e)
-            if out is not None:
-                self.device_health.record_success(self.stats)
-                return MicroPartition.from_table(out)
-            # overflow guard (a decline, not a device failure) or deferred
-            # failure: partition was NOT aggregated on device — keep the
-            # counters truthful
-            self.device_health.release_probe()
-            self.stats.bump("device_aggregations", -1)
-            self.stats.bump("device_agg_fallbacks")
-            return self._eval_agg_host(part, aggregations, groupby, predicate)
-
-        return finish
-
-    def eval_segment(self, part: MicroPartition, op) -> MicroPartition:
-        """Route a compiled plan segment (fuse/segment.py DeviceSegmentOp)
-        through the HBM-resident pipeline when eligible, else the retained
-        staged per-op path — byte-identical either way."""
-        with self.stats.profiler.span("fuse.segment", kind="phase"):
-            fin = self.eval_segment_dispatch(part, op)
-            if fin is not None:
-                return fin()
-            # device-ineligible partition (size/breaker/foreign): plain
-            # routing to the staged pipeline, not a degradation
-            return self._eval_segment_staged(part, op, degraded=False)
-
-    def eval_segment_dispatch(self, part: MicroPartition, op):
-        """Non-blocking launch of the resident segment pipeline; returns a
-        zero-arg resolver (staged fallback inside, truthful counters) or
-        None when this partition is device-ineligible. The whole leg sits
-        behind the DeviceHealth breaker: a launch exception (including an
-        armed ``fuse.segment`` fault) records a breaker failure; a decline
-        releases the probe slot."""
-        if self.foreign_owned(part) and not part.is_loaded():
-            return None
-        if not self._device_eligible(part):
-            return None
-
-        def _launch():
-            from .fuse.segment import run_segment_async
-
-            return run_segment_async(part.table(), op.program,
-                                     part.device_stage_cache(),
-                                     stats=self.stats, cfg=self.cfg)
-
-        resolve = self._device_attempt(_launch, launch=True)
-        if resolve is None:
-            # the resident attempt was made and failed/declined: degraded
-            return lambda: self._eval_segment_staged(part, op, degraded=True)
-        self.stats.bump("device_aggregations")
-        self.stats.bump("segment_dispatches")
-
-        def finish() -> MicroPartition:
-            with self.stats.profiler.span("fuse.segment", kind="phase"):
-                try:
-                    out = self._device_resolve(resolve)
-                except Exception as e:
-                    out = None
-                    self._device_failed("fuse.segment", e)
-                if out is not None:
-                    self.device_health.record_success(self.stats)
-                    # ONE boundary crossed resident: the map→agg Arrow
-                    # round-trip of the staged plan did not happen
-                    self.stats.bump("device_handoffs_elided")
-                    op._record_resident(self)
-                    from .fuse.segment import _proc_bump
-
-                    _proc_bump("handoffs_elided")
-                    return MicroPartition.from_table(out)
-                # overflow guard (a decline) or deferred failure: the
-                # segment was NOT executed resident — keep counters truthful
-                self.device_health.release_probe()
-                self.stats.bump("device_aggregations", -1)
-                return self._eval_segment_staged(part, op, degraded=True)
-
-        return finish
-
-    def _eval_segment_staged(self, part: MicroPartition, op,
-                             degraded: bool = True) -> MicroPartition:
-        """The segment as its retained staged ops: the fused map chain,
-        Arrow materialization, then the (filter-fused) aggregation —
-        EXACTLY the plan the segment pass collapsed, so results are
-        byte-identical. `degraded` marks a resident attempt that failed
-        (counted), vs. plain routing of an ineligible partition (not)."""
-        if degraded:
-            self.stats.bump("segment_fallbacks")
-            from .fuse.segment import _proc_bump
-
-            _proc_bump("segment_fallbacks")
-        mid = op.staged_map(part, self)
-        return op.staged_agg(mid, self)
 
     def prepare_broadcast(self, part: MicroPartition, on_exprs,
                           how: str = "inner") -> MicroPartition:
@@ -1239,185 +947,16 @@ class ExecutionContext:
         base context: no-op."""
         return part
 
-    def eval_join(self, lpart: MicroPartition, rpart: MicroPartition,
-                  left_on, right_on, how: str, suffix: str) -> MicroPartition:
-        """Blocking join: the pipelined dispatch-or-declined pair in one
-        call, so there is exactly ONE join code path (kernels/device_join.py
-        when eligible, host acero otherwise)."""
-        fin = self.eval_join_dispatch(lpart, rpart, left_on, right_on, how,
-                                      suffix)
-        if fin is not None:
-            return fin()
-        return self.eval_join_declined(lpart, rpart, left_on, right_on, how,
-                                       suffix)
-
-    def _join_eligible(self, lpart, rpart, left_on, right_on, how) -> bool:
-        return (self.cfg.use_device_kernels
-                and how in ("inner", "left", "semi", "anti")
-                and 1 <= len(left_on) == len(right_on) <= 4
+    def _join_eligible(self, probe, lpart, rpart) -> bool:
+        """The two-input eligibility test of a join pair (`probe` is the
+        operator's physical.JoinProbe)."""
+        return (self.device_path_on()
+                and probe.how in ("inner", "left", "semi", "anti")
+                and 1 <= len(probe.left_on) == len(probe.right_on) <= 4
                 and max(lpart.num_rows_or_none() or 0,
                         rpart.num_rows_or_none() or 0)
                 >= self.cfg.device_min_rows
                 and self._device_allowed())
-
-    def _assemble_join(self, res, lpart, rpart, left_on, right_on, how,
-                       suffix) -> MicroPartition:
-        """(side, hit, bidx) probe result -> output partition (shared by the
-        blocking and pipelined join paths)."""
-        import numpy as np
-
-        from .series import Series
-
-        side, hit, bidx = res
-        ltbl, rtbl = lpart.table(), rpart.table()
-        if side == "expanded":
-            # N:M range join: (lidx, ridx) pairs already expanded on
-            # host from the device range probe (-1 = left-outer miss)
-            out = ltbl.join_from_indices(rtbl, hit, bidx,
-                                         left_on, right_on, suffix)
-        elif side == "right_build":
-            if how == "semi":
-                out = ltbl.filter_with_mask(Series.from_numpy(hit, "m"))
-            elif how == "anti":
-                out = ltbl.filter_with_mask(Series.from_numpy(~hit, "m"))
-            elif how == "inner":
-                lidx = np.nonzero(hit)[0]
-                out = ltbl.join_from_indices(rtbl, lidx, bidx[hit],
-                                             left_on, right_on, suffix)
-            else:  # left outer: every left row, -1 -> null right
-                lidx = np.arange(len(ltbl), dtype=np.int64)
-                ridx = np.where(hit, bidx, -1)
-                out = ltbl.join_from_indices(rtbl, lidx, ridx,
-                                             left_on, right_on, suffix)
-        else:  # left_build (inner only): re-sort to host (lidx, ridx) order
-            ridx = np.nonzero(hit)[0]
-            lidx = bidx[hit]
-            order = np.argsort(lidx, kind="stable")
-            out = ltbl.join_from_indices(rtbl, lidx[order], ridx[order],
-                                         left_on, right_on, suffix)
-        return MicroPartition.from_table(out)
-
-    def eval_join_dispatch(self, lpart: MicroPartition, rpart: MicroPartition,
-                           left_on, right_on, how: str, suffix: str):
-        """Non-blocking join launch: stage both sides' keys and dispatch the
-        right-build range probe now; the returned finisher resolves the
-        probe and assembles the output — the join op stages pair i+1 while
-        pair i probes (same contract as eval_projection_dispatch; PARITY
-        known-gap 36). Returns None when ineligible (caller joins
-        synchronously)."""
-        if not self._join_eligible(lpart, rpart, left_on, right_on, how):
-            return None
-
-        def _launch():
-            from .kernels.device_join import (device_join_launch,
-                                              join_key_replicas)
-
-            single = len(left_on) == 1
-            return device_join_launch(
-                lpart.table(), rpart.table(), list(left_on), list(right_on),
-                lpart.device_stage_cache(), rpart.device_stage_cache(), how,
-                left_replicas=(join_key_replicas(lpart, left_on[0])
-                               if single else None),
-                right_replicas=(join_key_replicas(rpart, right_on[0])
-                                if single else None))
-
-        launch = self._device_attempt(_launch, launch=True)
-        if launch is None:
-            return None
-        self.stats.bump("device_join_dispatches")
-
-        def finish() -> MicroPartition:
-            try:
-                res = self._device_resolve(launch)
-            except Exception as e:
-                self._device_failed("device.join", e)
-                self.stats.bump("device_join_fallbacks")
-                self.stats.bump("host_joins")
-                return lpart.hash_join(rpart, left_on, right_on, how, suffix)
-            self.device_health.record_success(self.stats)
-            # assembly runs OUTSIDE the catch-all: a defect there must crash
-            # loudly, not silently recompute on host (same error contract
-            # as the blocking path)
-            with self.stats.profiler.span("join.assemble", kind="phase"):
-                out = self._assemble_join(res, lpart, rpart, left_on,
-                                          right_on, how, suffix)
-            self.stats.bump("device_join_probes")
-            return out
-
-        return finish
-
-    def eval_join_declined(self, lpart, rpart, left_on, right_on, how,
-                           suffix) -> MicroPartition:
-        """Host join for a pair the dispatch already proved device-
-        ineligible — never re-stage a doomed attempt (the
-        map_partition_declined convention)."""
-        self.stats.bump("host_joins")
-        return lpart.hash_join(rpart, left_on, right_on, how, suffix)
-
-    def _defer_filter(self, part: MicroPartition, predicate):
-        return part.with_pending_op(
-            lambda t: t.filter([predicate]), part.schema,
-            count_preserving=False)
-
-    def eval_filter(self, part: MicroPartition, predicate) -> MicroPartition:
-        """Filter a partition: when eligible, the predicate mask is computed on
-        device and only the compaction happens on host."""
-        if self.foreign_owned(part) and not part.is_loaded():
-            return self._defer_filter(part, predicate)
-        if self._device_eligible(part):
-            def _run():
-                from .kernels.device import eval_projection_device_async
-
-                return self._resolve_now(eval_projection_device_async(
-                    part.table(), [predicate],
-                    stage_cache=part.device_stage_cache()))
-
-            out = self._device_attempt(_run)
-            if out is not None:
-                self.stats.bump("device_filters")
-                mask = out._columns[0]
-                return part._wrap(part.table().filter_with_mask(mask))
-        self.stats.bump("host_filters")
-        return part.filter([predicate])
-
-    def eval_filter_dispatch(self, part: MicroPartition, predicate):
-        """Non-blocking launch of the device filter mask; the resolver pulls
-        the mask back and compacts on host — same contract as
-        eval_projection_dispatch."""
-        if self.foreign_owned(part) and not part.is_loaded():
-            deferred = self._defer_filter(part, predicate)
-            return lambda: deferred
-        if not self._device_eligible(part):
-            return None
-
-        def _launch():
-            from .kernels.device import eval_projection_device_async
-
-            return eval_projection_device_async(
-                part.table(), [predicate],
-                stage_cache=part.device_stage_cache())
-
-        resolve = self._device_attempt(_launch, launch=True)
-        if resolve is None:
-            return None
-        self.stats.bump("device_filters")
-        self.stats.bump("device_filter_dispatches")
-
-        def finish() -> MicroPartition:
-            try:
-                out = self._device_resolve(resolve)
-                mask = out._columns[0]
-                result = part._wrap(part.table().filter_with_mask(mask))
-            except Exception as e:
-                self._device_failed("device.filter", e)
-                self.stats.bump("device_filters", -1)
-                self.stats.bump("device_filter_fallbacks")
-                self.stats.bump("host_filters")
-                return part.filter([predicate])
-            self.device_health.record_success(self.stats)
-            return result
-
-        return finish
 
 
 _QUERY_SEQ = itertools.count(1)
@@ -1713,7 +1252,7 @@ def _adaptive_device_map(op: PhysicalOp, child: Iterator[MicroPartition],
                          trace: bool) -> Iterator[MicroPartition]:
     """Peek at the first partition: if it accepts the device dispatch, run the
     whole stream through the double-buffered sequential driver (the launched
-    resolver is handed over as `_primed`, nothing recomputes); if it declines
+    finisher is handed over as `_primed`, nothing recomputes); if it declines
     (below device_min_rows, staging failure, ...), thread fan-out would have
     been the better strategy after all — delegate the stream, first partition
     included, to the worker pool.
@@ -1733,6 +1272,9 @@ def _adaptive_device_map(op: PhysicalOp, child: Iterator[MicroPartition],
         yield from _parallel_map(op, itertools.chain([first], it), ctx)
         return
     stream = op._map_execute([it], ctx, _primed=dispatch)
+    # the driver's pending slot owns the finisher now: held here too, its
+    # device arrays would outlive its call by the whole stream
+    del dispatch, first
     if trace:
         stream = _traced(op, stream, ctx)
     yield from stream

@@ -28,7 +28,8 @@ from typing import List, Optional, Tuple
 
 from .. import faults
 from ..expressions import Alias, Expression, col, required_columns
-from ..physical import PhysicalOp, summarize_exprs
+from ..physical import (DeviceStep, PhysicalOp, _exprs_compile,
+                        _launch_exprs, summarize_exprs)
 from ..schema import Field, Schema
 from .graph import (
     MASK_PREFIX,
@@ -149,14 +150,55 @@ def compile_chain(stages, input_schema: Schema,
     return FusedProgram(graph, out_schema)
 
 
-class FusedMapOp(PhysicalOp):
+def record_fusion(ctx, g: FusedGraph, device_program: bool) -> None:
+    """The chain-level counters of one fused chain, and its compile outcome
+    as a typed profile event: what fused, how much it collapsed, and
+    whether a one-program device plan exists."""
+    ctx.stats.bump("fused_chains")
+    ctx.stats.bump("fused_ops_eliminated", g.n_ops - 1)
+    if g.cse_hits:
+        ctx.stats.bump("cse_hits", g.cse_hits)
+    if ctx.stats.profiler.armed:
+        ctx.stats.profiler.event("fusion", ops=g.n_ops, cse_hits=g.cse_hits,
+                                 device_program=device_program)
+
+
+class QueryLatches:
+    """Once-a-query latches of an operator: plain bool attributes (the op
+    tree is rebuilt per translate and adapt/plancache.clone_plan resets
+    them, so instance state is query-scoped) behind one lock. The lock is
+    per-process coordination state, not program identity: it is dropped
+    when the operator ships over the dist/ worker transport (the receiving
+    process records against ITS stats)."""
+
+    def __getstate__(self):
+        state = dict(self.__dict__)
+        state.pop("_record_lock", None)
+        return state
+
+    def __setstate__(self, state):
+        self.__dict__.update(state)
+        self._record_lock = threading.Lock()
+
+    def _first(self, latch: str) -> bool:
+        """True for exactly one caller a query."""
+        if getattr(self, latch):
+            return False
+        with self._record_lock:
+            if getattr(self, latch):
+                return False
+            setattr(self, latch, True)
+        return True
+
+
+class FusedMapOp(QueryLatches, DeviceStep, PhysicalOp):
     """A maximal Project/Filter chain collapsed to one single-pass operator.
 
-    Executes through ExecutionContext.eval_fused (device one-program path
-    when eligible, segmented host pass otherwise) with the same pipelined
-    dispatch contract as ProjectOp/FilterOp. Byte-identical to the chain it
-    replaced; `fused_chains` / `fused_ops_eliminated` / `cse_hits` counters
-    make the collapse visible in every plan dump."""
+    Its DeviceStep is the whole chain as ONE jit program when eligible, the
+    segmented host pass otherwise, driven like ProjectOp's and FilterOp's.
+    Byte-identical to the chain it replaced; `fused_chains` /
+    `fused_ops_eliminated` / `cse_hits` counters make the collapse visible
+    in every plan dump."""
 
     # the fused program is a composition of row-local projections and
     # filters, so the chain streams morsel-wise exactly like its
@@ -171,72 +213,64 @@ class FusedMapOp(PhysicalOp):
         self._recorded = False
         self._record_lock = threading.Lock()
 
-    def __getstate__(self):
-        # the record lock is per-process coordination state, not program
-        # identity: drop it so a fused op can ship over the dist/ worker
-        # transport (the receiving process records against ITS stats)
-        state = dict(self.__dict__)
-        state.pop("_record_lock", None)
-        return state
-
-    def __setstate__(self, state):
-        self.__dict__.update(state)
-        self._record_lock = threading.Lock()
-
     def _record(self, ctx) -> None:
-        """Chain-level counters, once per query (the op tree is rebuilt per
-        translate, so instance state is query-scoped)."""
-        if self._recorded:
-            return
-        with self._record_lock:
-            if self._recorded:
-                return
-            self._recorded = True
+        """Chain-level counters, once per query."""
+        if self._first("_recorded"):
+            record_fusion(ctx, self.program.graph, self.has_program)
+
+    # ------------------------------------------------------ the device step
+    dispatches = "device_fused_map_dispatches"
+    fallbacks = "device_fused_map_fallbacks"
+    site = "device.fused_map"
+
+    @property
+    def has_program(self) -> bool:
+        return self.program.device_exprs is not None
+
+    def compilable(self) -> bool:
+        return self.has_program and _exprs_compile(
+            self.program.device_exprs, self.children[0].schema)
+
+    def count(self, stats, n: int) -> None:
+        # the legacy per-op class counters advance by the chain's op counts
+        # so per-path attribution stays comparable with the unfused engine
         g = self.program.graph
-        ctx.stats.bump("fused_chains")
-        ctx.stats.bump("fused_ops_eliminated", g.n_ops - 1)
-        if g.cse_hits:
-            ctx.stats.bump("cse_hits", g.cse_hits)
-        if ctx.stats.profiler.armed:
-            # compile outcome as a typed profile event: what fused, how much
-            # it collapsed, and whether a one-program device plan exists
-            ctx.stats.profiler.event(
-                "fusion", ops=g.n_ops, cse_hits=g.cse_hits,
-                device_program=self.program.device_exprs is not None)
+        stats.bump("device_fused_maps", n)
+        if g.n_project_ops:
+            stats.bump("device_projections", n * g.n_project_ops)
+        if g.n_filter_ops:
+            stats.bump("device_filters", n * g.n_filter_ops)
 
-    def map_partition(self, part, ctx):
-        self._record(ctx)
-        return ctx.eval_fused(part, self.program)
+    def launch(self, ctx, part):
+        return _launch_exprs(part, self.program.device_exprs)
 
-    def map_partition_dispatch(self, part, ctx):
-        self._record(ctx)
-        return ctx.eval_fused_dispatch(part, self.program)
+    def finish(self, ctx, out, part):
+        # the chain's host half (mask compaction): the operator's own time
+        with ctx.stats.profiler.span("fuse.assemble", kind="phase"):
+            return part._wrap(self.program.assemble_device(out))
 
-    def map_partition_declined(self, part, ctx):
-        # dispatch already proved this partition device-ineligible
-        return ctx._eval_fused_host(part, self.program)
+    def host(self, ctx, part):
+        g = self.program.graph
+        ctx.stats.bump("host_fused_maps")
+        if g.n_project_ops:
+            ctx.stats.bump("host_projections", g.n_project_ops)
+        if g.n_filter_ops:
+            ctx.stats.bump("host_filters", g.n_filter_ops)
+        return part._wrap(self.program.run_host(part.table()))
 
-    def device_pipelinable(self, ctx) -> bool:
-        if not ctx.cfg.use_device_kernels:
-            return False
-        if self.program.device_exprs is None:
-            return False
-        try:
-            from ..kernels.device import normalize_and_check
-
-            return normalize_and_check(self.program.device_exprs,
-                                       self.children[0].schema) is not None
-        except Exception:
-            return False
+    def defer(self, part):
+        # the whole fused program joins the pending op chain (one deferred
+        # single-pass map), preserving per-host scan locality exactly like
+        # the unfused chain's deferred Project/Filter ops would
+        program = self.program
+        return part.with_pending_op(
+            lambda t: program.run_host(t), program.out_schema,
+            count_preserving=program.count_preserving)
 
     def _map_exprs(self):
         # the ORIGINAL chain expressions: UDF parallel-safety and resource
         # accounting see exactly what the unfused chain declared
         return self.program.graph.source_exprs
-
-    def execute(self, inputs, ctx):
-        self._record(ctx)
-        return self._map_execute(inputs, ctx)
 
     def describe(self) -> str:
         g = self.program.graph

@@ -45,13 +45,15 @@ from ..expressions import Alias, BinaryOp, Column, Expression
 from ..micropartition import MicroPartition
 from ..physical import (
     AggregateOp,
+    DeviceStep,
     FilterOp,
     FusedFilterAggregateOp,
     PhysicalOp,
     ProjectOp,
 )
 from ..schema import Field, Schema
-from .compile import FusedMapOp, FusedProgram, compile_chain
+from .compile import (FusedMapOp, FusedProgram, QueryLatches, compile_chain,
+                      record_fusion)
 from .graph import MASK_PREFIX
 
 __all__ = ["DeviceSegmentOp", "SegmentProgram", "compile_plan_segments",
@@ -279,14 +281,14 @@ def compile_plan_segments(op: PhysicalOp, cfg, stats=None) -> PhysicalOp:
 # the physical operator
 # ---------------------------------------------------------------------------
 
-class DeviceSegmentOp(PhysicalOp):
+class DeviceSegmentOp(QueryLatches, DeviceStep, PhysicalOp):
     """A project→filter→agg plan segment compiled for whole-segment device
-    residency. Executes through ``ExecutionContext.eval_segment``: the
-    resident pipeline when the partition is device-eligible, the retained
-    staged ops (``map_op`` then ``agg_op``) otherwise — byte-identical
-    either way. NOT morsel-streamable: the aggregation is a pipeline
-    breaker; the morsel stream runs BELOW it (device-morsel mode in
-    stream/pipeline.py) and re-chunks at this op's boundary."""
+    residency. Its DeviceStep is the resident pipeline when the partition
+    is device-eligible, the retained staged ops (``map_op`` then
+    ``agg_op``) otherwise — byte-identical either way. NOT
+    morsel-streamable: the aggregation is a pipeline breaker; the morsel
+    stream runs BELOW it (device-morsel mode in stream/pipeline.py) and
+    re-chunks at this op's boundary."""
 
     morsel_streamable = False
 
@@ -301,75 +303,59 @@ class DeviceSegmentOp(PhysicalOp):
         self._resident_recorded = False
         self._record_lock = threading.Lock()
 
-    def __getstate__(self):
-        # per-process coordination state, not program identity (the same
-        # contract as FusedMapOp: a shipped op records against the
-        # receiving process's stats)
-        state = dict(self.__dict__)
-        state.pop("_record_lock", None)
-        return state
-
-    def __setstate__(self, state):
-        self.__dict__.update(state)
-        self._record_lock = threading.Lock()
-
     def _record(self, ctx) -> None:
         """Once per query: the fusion counters the staged plan would have
         bumped (the chain IS still fused — residency only changes where its
         outputs live), so counter-level dashboards read identically with
         residency on or off."""
-        if self._recorded:
-            return
-        with self._record_lock:
-            if self._recorded:
-                return
-            self._recorded = True
-        if isinstance(self.map_op, FusedMapOp):
-            g = self.map_op.program.graph
-            ctx.stats.bump("fused_chains")
-            ctx.stats.bump("fused_ops_eliminated", g.n_ops - 1)
-            if g.cse_hits:
-                ctx.stats.bump("cse_hits", g.cse_hits)
-            if ctx.stats.profiler.armed:
-                ctx.stats.profiler.event(
-                    "fusion", ops=g.n_ops, cse_hits=g.cse_hits,
-                    device_program=True)
+        if isinstance(self.map_op, FusedMapOp) and self._first("_recorded"):
+            record_fusion(ctx, self.map_op.program.graph, True)
 
     def _record_resident(self, ctx) -> None:
         """Once per query, on the FIRST successful resident execution."""
-        if self._resident_recorded:
-            return
-        with self._record_lock:
-            if self._resident_recorded:
-                return
-            self._resident_recorded = True
-        ctx.stats.bump("device_resident_segments")
-        _proc_bump("resident_segments")
+        if self._first("_resident_recorded"):
+            ctx.stats.bump("device_resident_segments")
+            _proc_bump("resident_segments")
 
-    # ----------------------------------------------------------- execution
-    def map_partition(self, part, ctx):
-        self._record(ctx)
-        return ctx.eval_segment(part, self)
+    # ------------------------------------------------------ the device step
+    # the whole leg sits behind the DeviceHealth breaker: a launch exception
+    # (an armed ``fuse.segment`` fault included) records a breaker failure,
+    # a decline releases the probe slot, and either is answered by the
+    # staged ops and counted a fallback, like a failed resolve
+    counter = "device_aggregations"
+    dispatches = "segment_dispatches"
+    fallbacks = "segment_fallbacks"
+    site = "fuse.segment"
+    span = "fuse.segment"
+    counts_failed_launch = True
+    # no static `compilable` check is declared, so `device_pipelinable` is
+    # False: execute_plan runs segments on the worker pool, through
+    # `ExecutionContext.run` (ROADMAP D3 carries the question)
 
-    def map_partition_dispatch(self, part, ctx):
-        self._record(ctx)
-        return ctx.eval_segment_dispatch(part, self)
+    def launch(self, ctx, part):
+        return run_segment_async(part.table(), self.program,
+                                 part.device_stage_cache(),
+                                 stats=ctx.stats, cfg=ctx.cfg)
 
-    def map_partition_declined(self, part, ctx):
-        # dispatch already proved this partition device-ineligible: plain
-        # routing to the staged per-op pipeline, NOT a degradation
-        return ctx._eval_segment_staged(part, self, degraded=False)
+    def finish(self, ctx, out, part):
+        # ONE boundary crossed resident: the map→agg Arrow round-trip of
+        # the staged plan did not happen
+        ctx.stats.bump("device_handoffs_elided")
+        self._record_resident(ctx)
+        _proc_bump("handoffs_elided")
+        return MicroPartition.from_table(out)
 
-    def staged_map(self, part, ctx):
-        """The staged map stage, WITHOUT re-recording the fusion counters
-        (this op's ``_record`` already did — FusedMapOp.map_partition has
-        its own once-per-query latch that a fallback must not double-bump)."""
-        if isinstance(self.map_op, FusedMapOp):
-            return ctx.eval_fused(part, self.map_op.program)
-        return self.map_op.map_partition(part, ctx)
+    def host(self, ctx, part):
+        """The segment as its retained staged ops: the fused map chain,
+        Arrow materialization, then the (filter-fused) aggregation, EXACTLY
+        the plan the segment pass collapsed, so results are byte-identical.
+        Through the driver and not the ops' ``map_partition``: the fusion
+        counters are this op's ``_record`` to bump, once."""
+        return ctx.run(self.agg_op, ctx.run(self.map_op, part))
 
-    def staged_agg(self, mid, ctx):
-        return self.agg_op.map_partition(mid, ctx)
+    def fall_back(self, ctx, part):
+        _proc_bump("segment_fallbacks")
+        return super().fall_back(ctx, part)
 
     def map_empty(self, ctx):
         # same contract as the staged AggregateOp: a global agg over zero
@@ -380,10 +366,6 @@ class DeviceSegmentOp(PhysicalOp):
 
     def _map_exprs(self):
         return list(self.map_op._map_exprs()) + list(self.agg_op._map_exprs())
-
-    def execute(self, inputs, ctx):
-        self._record(ctx)
-        return self._map_execute(inputs, ctx)
 
     def describe(self) -> str:
         p = self.program

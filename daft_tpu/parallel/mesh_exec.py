@@ -241,7 +241,7 @@ class MeshExecutionContext(ExecutionContext):
         keys over the link per partition (reference role: broadcast_join's
         small-side replication, daft/execution/physical_plan.py:374)."""
         if (self.cfg.use_device_kernels and self.n_devices > 1
-                and how in ("inner", "left", "semi", "anti")  # eval_join's gate
+                and how in ("inner", "left", "semi", "anti")  # _join_eligible's gate
                 and on_exprs and len(on_exprs) == 1
                 and (part.num_rows_or_none() or 0) > 0):
             try:
@@ -753,35 +753,21 @@ class MeshExecutionContext(ExecutionContext):
         self.stats.bump("collective_sketch_merges")
         return out
 
-    def _collective_merge_eligible(self, groupby, predicate) -> bool:
+    def _collective_answer(self, step, parts):
+        """Global merge_sketch_hll stages (the gathered stage 2 of a
+        multi-partition approx_count_distinct) merge on the mesh when
+        eligible; the merge resolves synchronously (one tiny all_gather).
+        Everything else takes the base routing."""
+        from ..physical import AggregateOp
+
         # no min-rows gate: a stage-2 input is one sketch row per partition
         # BY DESIGN — routing those few wide rows through ICI is the point.
         # Multi-process declines HERE, before the partition materializes and
         # the sketches decode (try_sketch_register_merge would refuse anyway)
-        return (not groupby and predicate is None
-                and self.cfg.use_device_kernels and not self._multiproc)
-
-    def eval_agg(self, part, aggregations, groupby, predicate=None):
-        """Global merge_sketch_hll stages (the gathered stage 2 of a
-        multi-partition approx_count_distinct) merge on the mesh when
-        eligible; everything else takes the base routing."""
-        if self._collective_merge_eligible(groupby, predicate):
-            out = self._sketch_merge_collective(part, aggregations)
-            if out is not None:
-                return out
-        return super().eval_agg(part, aggregations, groupby,
-                                predicate=predicate)
-
-    def eval_agg_dispatch(self, part, aggregations, groupby, predicate=None):
-        """The executor's non-blocking driver probes HERE first; the
-        collective merge resolves synchronously (one tiny all_gather), so
-        it hands back an already-resolved thunk."""
-        if self._collective_merge_eligible(groupby, predicate):
-            out = self._sketch_merge_collective(part, aggregations)
-            if out is not None:
-                return lambda: out
-        return super().eval_agg_dispatch(part, aggregations, groupby,
-                                         predicate=predicate)
+        if (isinstance(step, AggregateOp) and not step.groupby
+                and self.cfg.use_device_kernels and not self._multiproc):
+            return self._sketch_merge_collective(parts[0], step.aggregations)
+        return None
 
     def _sketch_merge_collective(self, part, aggregations):
         from ..datatypes import DataType

@@ -62,15 +62,104 @@ def summarize_exprs(exprs, limit: int = 120) -> str:
     return ", ".join(parts)
 
 
+class DeviceStep:
+    """What ONE device-able shape tells ``ExecutionContext.launch`` / ``run``:
+    only what differs between shapes. The policy (who is eligible, the
+    breaker, the ``device.kernel`` fault site, the counters' arithmetic, the
+    fallback) is the driver's, written once there.
+
+    A per-partition map operator declares a step by inheriting this beside
+    PhysicalOp: the step is then built with the plan, cloned and pickled
+    with it, keeps the operator's per-query latches, and nothing is built
+    per partition. A join operator holds one (``JoinProbe``). ``parts`` is
+    one partition, or the two sides of a join pair."""
+
+    counter: Optional[str] = None     # answered by the device: +1 a launch,
+    #                                   -1 when the host answered after all
+    dispatches: Optional[str] = None  # launches, resolved at once or later
+    fallbacks: Optional[str] = None   # launches the host answered after all
+    site = "device.attempt"           # where a failed resolve is reported
+    span: Optional[str] = None        # phase span round the finisher
+    has_program = True        # False: never compiles; the breaker is not asked
+    finish_falls_back = True  # False: a defect in ``finish`` raises
+    counts_failed_launch = False  # True: a failed launch is a fallback too
+
+    def compilable(self) -> bool:
+        """Static check against the child schema (no data, no staging):
+        does the shape compile for the device? Says whether the
+        double-buffered driver is worth preferring over thread fan-out."""
+        return False
+
+    def launch(self, ctx, *parts):
+        """The kernel call: stage and dispatch now, return a zero-arg
+        resolver of the device output, or None to decline."""
+        raise NotImplementedError
+
+    def finish(self, ctx, out, *parts) -> MicroPartition:
+        """The resolved device output -> the output partition."""
+        return MicroPartition.from_table(out)
+
+    def host(self, ctx, *parts) -> MicroPartition:
+        """The host kernel, with its own ``host_*`` counters."""
+        raise NotImplementedError
+
+    def defer(self, part: MicroPartition) -> Optional[MicroPartition]:
+        """A foreign-owned unloaded partition with this step appended to
+        its pending chain (the owner evaluates for real), or None when the
+        shape cannot be deferred."""
+        return None
+
+    def count(self, stats, n: int) -> None:
+        if self.counter is not None:
+            stats.bump(self.counter, n)
+
+    def fall_back(self, ctx, *parts) -> MicroPartition:
+        """The host's answer to an attempt the device did not answer."""
+        if self.fallbacks is not None:
+            ctx.stats.bump(self.fallbacks)
+        return self.host(ctx, *parts)
+
+
+def _exprs_compile(exprs, schema: Schema) -> bool:
+    try:
+        from .kernels.device import normalize_and_check
+
+        return normalize_and_check(exprs, schema) is not None
+    except Exception:
+        return False
+
+
+def _launch_exprs(part: MicroPartition, exprs):
+    from .kernels.device import eval_projection_device_async
+
+    return eval_projection_device_async(
+        part.table(), exprs, stage_cache=part.device_stage_cache())
+
+
 class PhysicalOp:
     """Base: children + a generator-producing execute().
 
-    Ops that are pure per-partition maps set `map_partition` (a method
-    (part, ctx) -> part); the executor then runs them morsel-parallel across
-    a worker pool (reference: worker-per-core IntermediateOps,
+    Ops that are pure per-partition maps have a `map_partition` ((part, ctx)
+    -> part: their own method, or the one below when they declare a
+    DeviceStep); the executor then runs them morsel-parallel across a worker
+    pool (reference: worker-per-core IntermediateOps,
     intermediate_op.rs:71) instead of calling execute()."""
 
-    map_partition = None  # type: ignore[assignment]
+    @property
+    def map_partition(self):
+        """None for an operator that is no per-partition map. One that
+        declares a DeviceStep is: its partitions go through the context's
+        one driver (device when eligible, else the step's host kernel)."""
+        return self._run_step if isinstance(self, DeviceStep) else None
+
+    def _run_step(self, part, ctx):
+        self._record(ctx)
+        return ctx.run(self, part)
+
+    def _record(self, ctx) -> None:
+        """Once-a-query counters of the operator's plan-time work (the
+        fused operators'), bumped where its partitions start to arrive:
+        by the sequential driver, or by the first `map_partition`."""
 
     # The morsel contract (daft_tpu/stream/, README "Streaming execution"):
     # True declares map_partition ROW-LOCAL — applying it per fixed-size
@@ -104,66 +193,73 @@ class PhysicalOp:
         own worker-pool driver over the same map_partition; device-pipelinable
         ops are routed HERE instead — see execute_plan). Honors UDF resource
         requests (fail-fast on impossible ones; reference: pyrunner.py:352-370).
-        `_primed` is the already-launched resolver of a first partition the
+        `_primed` is the already-launched finisher of a first partition the
         caller consumed while deciding the execution strategy.
 
-        Device double-buffering: ops that implement map_partition_dispatch
-        launch partition i+1's staging + compute BEFORE partition i's result
+        Device double-buffering: an operator with a DeviceStep launches
+        partition i+1's staging + compute BEFORE partition i's result
         is pulled back from the device, overlapping host↔HBM transfer with
         device compute (reference role: the channelled pipeline of
         daft-local-execution intermediate_op.rs:71+). Output order is
         preserved; a host-path partition first drains the pending device one.
+        The one pending slot is cleared as its finisher is called, so a
+        resolved partition's device arrays die before its output is consumed.
         """
         from .execution import op_resource_request
 
+        self._record(ctx)
         req = op_resource_request(self)
         if req:
             ctx.accountant.check(req)
         saw = _primed is not None
-        pending = _primed  # deferred resolver of the previous device partition
+        # finisher of the previous device partition, in ONE slot
+        pending, _primed = _primed, None
         for part in inputs[0]:
             saw = True
             if req:
                 ctx.accountant.admit(req)
             try:
-                # resource-requested ops never defer: the resolver would run
+                # resource-requested ops never defer: the finisher would run
                 # outside the accountant's admission window
                 dispatch = None if req else self.map_partition_dispatch(part, ctx)
+                if pending is not None:
+                    out, pending = pending(), None
+                    yield out
                 if dispatch is not None:
-                    if pending is not None:
-                        yield pending()
                     pending = dispatch
                     continue
-                if pending is not None:
-                    yield pending()
-                    pending = None
                 out = self.map_partition_declined(part, ctx)
             finally:
                 if req:
                     ctx.accountant.release(req)
             yield out
         if pending is not None:
-            yield pending()
+            out, pending = pending(), None
+            yield out
         if not saw:
             yield from self.map_empty(ctx)
 
     def map_partition_dispatch(self, part, ctx):
-        """Optional non-blocking launch for map_partition: return a zero-arg
-        resolver, or None to take the synchronous path."""
-        return None
+        """Non-blocking launch of one partition: a zero-arg finisher, or
+        None to take the synchronous path (always, without a DeviceStep)."""
+        if not isinstance(self, DeviceStep):
+            return None
+        return ctx.launch(self, part)
 
     def map_partition_declined(self, part, ctx):
-        """Synchronous evaluation AFTER map_partition_dispatch returned None.
-        Ops whose dispatch already proved the device path ineligible override
-        this to skip a doomed second device attempt."""
+        """Synchronous evaluation AFTER map_partition_dispatch returned None:
+        a step's host kernel at once, never a doomed second device attempt."""
+        if isinstance(self, DeviceStep):
+            return self.host(ctx, part)
         return self.map_partition(part, ctx)
 
     def device_pipelinable(self, ctx) -> bool:
-        """True when this op's kernels compile for the device against its
+        """True when this op's step compiles for the device against its
         child schema — execute_plan then prefers the double-buffered
         sequential driver over thread fan-out (device compute serializes on
         one chip; the pipeline keeps the host link busy instead)."""
-        return False
+        return (isinstance(self, DeviceStep) and ctx.device_path_on()
+                and self.compilable())
 
     def __init__(self, children: List["PhysicalOp"], schema: Schema, num_partitions: int):
         self.children = children
@@ -174,7 +270,11 @@ class PhysicalOp:
         return type(self).__name__
 
     def execute(self, inputs: List[PartStream], ctx) -> PartStream:
-        raise NotImplementedError
+        """A per-partition map runs the sequential driver; every other
+        operator has its own."""
+        if self.map_partition is None:
+            raise NotImplementedError
+        return self._map_execute(inputs, ctx)
 
     def display_tree(self, indent: str = "") -> str:
         out = [indent + ("* " if indent else "") + self.describe()]
@@ -247,45 +347,44 @@ class InMemoryOp(PhysicalOp):
 # streaming unary ops
 # ---------------------------------------------------------------------------
 
-class ProjectOp(PhysicalOp):
+class ProjectOp(DeviceStep, PhysicalOp):
     # row-local projection: per-morsel evaluation + re-chunk is
     # byte-identical to per-partition evaluation (the streaming driver
     # still declines UDF-bearing instances — a batch-dependent UDF sees
     # whole partitions on the partition-granular path)
     morsel_streamable = True
 
+    counter = "device_projections"
+    dispatches = "device_projection_dispatches"
+    fallbacks = "device_projection_fallbacks"
+    site = "device.projection"
+
     def __init__(self, child: PhysicalOp, exprs: List[Expression], schema: Schema):
         super().__init__([child], schema, child.num_partitions)
         self.exprs = exprs
 
-    def map_partition(self, part, ctx):
-        return ctx.eval_projection(part, self.exprs)
+    def compilable(self) -> bool:
+        return _exprs_compile(self.exprs, self.children[0].schema)
 
-    def map_partition_dispatch(self, part, ctx):
-        return ctx.eval_projection_dispatch(part, self.exprs)
+    def launch(self, ctx, part):
+        return _launch_exprs(part, list(self.exprs))
 
-    def map_partition_declined(self, part, ctx):
-        # dispatch already proved this partition device-ineligible: go
-        # straight to the host kernel instead of re-staging a doomed attempt
+    def finish(self, ctx, out, part):
+        return part._wrap(out)
+
+    def host(self, ctx, part):
         ctx.stats.bump("host_projections")
         return part.eval_expression_list(self.exprs)
 
-    def device_pipelinable(self, ctx) -> bool:
-        if not ctx.cfg.use_device_kernels:
-            return False
-        try:
-            from .kernels.device import normalize_and_check
-
-            return normalize_and_check(self.exprs,
-                                       self.children[0].schema) is not None
-        except Exception:
-            return False
+    def defer(self, part):
+        exprs = list(self.exprs)
+        schema = Schema([e._node.to_field(part.schema) for e in exprs])
+        return part.with_pending_op(
+            lambda t: t.eval_expression_list(exprs), schema,
+            count_preserving=True)
 
     def _map_exprs(self):
         return self.exprs
-
-    def execute(self, inputs, ctx) -> PartStream:
-        return self._map_execute(inputs, ctx)
 
     def describe(self):
         return "Project: " + summarize_exprs(self.exprs)
@@ -387,42 +486,42 @@ def _route_batched_udfs(op: PhysicalOp) -> PhysicalOp:
     return op
 
 
-class FilterOp(PhysicalOp):
+class FilterOp(DeviceStep, PhysicalOp):
     # row-local predicate: a row's fate depends only on its own values, so
     # morsel-wise compaction concatenates to the partition-granular result
     morsel_streamable = True
+
+    counter = "device_filters"
+    dispatches = "device_filter_dispatches"
+    fallbacks = "device_filter_fallbacks"
+    site = "device.filter"
 
     def __init__(self, child: PhysicalOp, predicate: Expression):
         super().__init__([child], child.schema, child.num_partitions)
         self.predicate = predicate
 
-    def map_partition(self, part, ctx):
-        return ctx.eval_filter(part, self.predicate)
+    def compilable(self) -> bool:
+        return _exprs_compile([self.predicate], self.children[0].schema)
 
-    def map_partition_dispatch(self, part, ctx):
-        return ctx.eval_filter_dispatch(part, self.predicate)
+    def launch(self, ctx, part):
+        return _launch_exprs(part, [self.predicate])
 
-    def map_partition_declined(self, part, ctx):
-        # dispatch already proved this partition device-ineligible
+    def finish(self, ctx, out, part):
+        # the mask was computed on the device; the compaction is the host's
+        return part._wrap(part.table().filter_with_mask(out._columns[0]))
+
+    def host(self, ctx, part):
         ctx.stats.bump("host_filters")
         return part.filter([self.predicate])
 
-    def device_pipelinable(self, ctx) -> bool:
-        if not ctx.cfg.use_device_kernels:
-            return False
-        try:
-            from .kernels.device import normalize_and_check
-
-            return normalize_and_check([self.predicate],
-                                       self.children[0].schema) is not None
-        except Exception:
-            return False
+    def defer(self, part):
+        predicate = self.predicate
+        return part.with_pending_op(
+            lambda t: t.filter([predicate]), part.schema,
+            count_preserving=False)
 
     def _map_exprs(self):
         return (self.predicate,)
-
-    def execute(self, inputs, ctx) -> PartStream:
-        return self._map_execute(inputs, ctx)
 
     def describe(self):
         return f"Filter: {self.predicate._node.display()}"
@@ -480,9 +579,6 @@ class ExplodeOp(PhysicalOp):
     def _map_exprs(self):
         return list(self.exprs)
 
-    def execute(self, inputs, ctx) -> PartStream:
-        return self._map_execute(inputs, ctx)
-
 
 class UnpivotOp(PhysicalOp):
     """Map-class since the DTL006 burn-down (same driver instrumentation
@@ -501,9 +597,6 @@ class UnpivotOp(PhysicalOp):
 
     def _map_exprs(self):
         return list(self.ids) + list(self.values)
-
-    def execute(self, inputs, ctx) -> PartStream:
-        return self._map_execute(inputs, ctx)
 
 
 class SampleOp(PhysicalOp):
@@ -1273,7 +1366,94 @@ class SortOp(PhysicalOp):
         return "Sort: " + ", ".join(e._node.display() for e in self.sort_by)
 
 
-class AggregateOp(PhysicalOp):
+class _AggStep(DeviceStep):
+    """The step of a (filter-fused) per-partition aggregation: the fused
+    grouped-aggregate program, or, for a stage-1 list of nothing but
+    ``sketch_hll``, the register scatter of the sketch build. Which of the
+    two is fixed by the aggregate list, so it is decided once, at plan time,
+    before any breaker or fault site is touched."""
+
+    counter = "device_aggregations"
+    dispatches = "device_agg_dispatches"
+    fallbacks = "device_agg_fallbacks"
+    site = "device.agg"
+    predicate: Optional[Expression] = None
+    sketch_build = False
+
+    def _declare_step(self) -> None:
+        from .sketch.device import aggs_all_sketch_hll
+
+        if self.predicate is None and aggs_all_sketch_hll(self.aggregations):
+            self.sketch_build = True
+            self.counter, self.dispatches = "device_sketch_builds", None
+            self.fallbacks, self.site = "device_sketch_fallbacks", "device.sketch"
+
+    def compilable(self) -> bool:
+        try:
+            from .kernels.device_agg import agg_plan_device_compilable
+        except Exception:
+            return False
+        return agg_plan_device_compilable(self.aggregations,
+                                          self.children[0].schema,
+                                          predicate=self.predicate)
+
+    def launch(self, ctx, part):
+        if self.sketch_build:
+            from .sketch.device import hll_build_table_device_launch
+
+            return hll_build_table_device_launch(
+                part.table(), list(self.aggregations), list(self.groupby))
+        from .kernels.device_agg import device_grouped_agg_async
+
+        return device_grouped_agg_async(
+            part.table(), list(self.aggregations), list(self.groupby),
+            stage_cache=part.device_stage_cache(), predicate=self.predicate,
+            stats=ctx.stats)
+
+    def host(self, ctx, part):
+        """Host aggregation (applies the predicate first when one was
+        fused)."""
+        ctx.stats.bump("host_aggregations")
+        predicate, groupby = self.predicate, self.groupby
+        if predicate is not None:
+            tbl = part.table()
+            # acero single-pass pays off when the hash-agg subsumes the
+            # filtered-table materialization; ungrouped reductions are faster
+            # through the pruned filter+agg below (measured on TPC-H Q6)
+            out = tbl.acero_fused_agg(list(self.aggregations), list(groupby),
+                                      predicate) if groupby else None
+            if out is not None:
+                ctx.stats.bump("fused_host_aggregations")
+                return MicroPartition.from_table(out)
+            # unfused fallback: prune to referenced columns before filtering
+            # so the compaction doesn't copy payload the agg never reads
+            from .expressions import required_columns
+
+            need = set()
+            for e in list(self.aggregations) + list(groupby) + [predicate]:
+                need.update(required_columns(e))
+            if need and need < set(part.column_names):
+                keep = [n for n in part.column_names if n in need]
+                part = MicroPartition.from_table(tbl.select_columns(keep))
+            part = part.filter([predicate])
+        return part.agg(self.aggregations, groupby or None)
+
+    def map_empty(self, ctx):
+        # global agg over zero partitions still yields one row (count=0 etc.)
+        if not self.groupby:
+            yield MicroPartition.empty(self.children[0].schema).agg(self.aggregations, None)
+
+    def _map_exprs(self):
+        pred = [] if self.predicate is None else [self.predicate]
+        return pred + list(self.aggregations) + list(self.groupby)
+
+    def _describe_aggs(self) -> str:
+        a = ", ".join(e._node.display() for e in self.aggregations)
+        g = ", ".join(e._node.display() for e in self.groupby)
+        return a + (f" by [{g}]" if g else "")
+
+
+class AggregateOp(_AggStep, PhysicalOp):
     """Full aggregation per partition (single-partition finals and stage
     executions both use this)."""
 
@@ -1282,46 +1462,13 @@ class AggregateOp(PhysicalOp):
         super().__init__([child], schema, child.num_partitions)
         self.aggregations = aggregations
         self.groupby = groupby
-
-    def map_partition(self, part, ctx):
-        return ctx.eval_agg(part, self.aggregations, self.groupby or None)
-
-    def map_partition_dispatch(self, part, ctx):
-        return ctx.eval_agg_dispatch(part, self.aggregations,
-                                     self.groupby or None)
-
-    def device_pipelinable(self, ctx) -> bool:
-        if not ctx.cfg.use_device_kernels:
-            return False
-        try:
-            from .kernels.device_agg import agg_plan_device_compilable
-        except Exception:
-            return False
-        return agg_plan_device_compilable(self.aggregations,
-                                          self.children[0].schema)
-
-    def map_partition_declined(self, part, ctx):
-        # dispatch already proved this partition device-ineligible
-        return ctx._eval_agg_host(part, self.aggregations, self.groupby or None)
-
-    def map_empty(self, ctx):
-        # global agg over zero partitions still yields one row (count=0 etc.)
-        if not self.groupby:
-            yield MicroPartition.empty(self.children[0].schema).agg(self.aggregations, None)
-
-    def _map_exprs(self):
-        return list(self.aggregations) + list(self.groupby)
-
-    def execute(self, inputs, ctx) -> PartStream:
-        return self._map_execute(inputs, ctx)
+        self._declare_step()
 
     def describe(self):
-        a = ", ".join(e._node.display() for e in self.aggregations)
-        g = ", ".join(e._node.display() for e in self.groupby)
-        return f"Aggregate: {a}" + (f" by [{g}]" if g else "")
+        return "Aggregate: " + self._describe_aggs()
 
 
-class FusedFilterAggregateOp(PhysicalOp):
+class FusedFilterAggregateOp(_AggStep, PhysicalOp):
     """Filter fused into a grouped aggregation: on the device path the
     predicate stays a mask feeding masked segment reductions — no host
     compaction or intermediate materialization (the TPU analog of the
@@ -1336,45 +1483,9 @@ class FusedFilterAggregateOp(PhysicalOp):
         self.aggregations = aggregations
         self.groupby = groupby
 
-    def map_partition(self, part, ctx):
-        return ctx.eval_agg(part, self.aggregations, self.groupby or None,
-                            predicate=self.predicate)
-
-    def map_partition_dispatch(self, part, ctx):
-        return ctx.eval_agg_dispatch(part, self.aggregations,
-                                     self.groupby or None,
-                                     predicate=self.predicate)
-
-    def device_pipelinable(self, ctx) -> bool:
-        if not ctx.cfg.use_device_kernels:
-            return False
-        try:
-            from .kernels.device_agg import agg_plan_device_compilable
-        except Exception:
-            return False
-        return agg_plan_device_compilable(self.aggregations,
-                                          self.children[0].schema,
-                                          predicate=self.predicate)
-
-    def map_partition_declined(self, part, ctx):
-        return ctx._eval_agg_host(part, self.aggregations, self.groupby or None,
-                                  predicate=self.predicate)
-
-    def map_empty(self, ctx):
-        if not self.groupby:
-            yield MicroPartition.empty(self.children[0].schema).agg(self.aggregations, None)
-
-    def _map_exprs(self):
-        return [self.predicate] + list(self.aggregations) + list(self.groupby)
-
-    def execute(self, inputs, ctx) -> PartStream:
-        return self._map_execute(inputs, ctx)
-
     def describe(self):
-        a = ", ".join(e._node.display() for e in self.aggregations)
-        g = ", ".join(e._node.display() for e in self.groupby)
-        return (f"FusedFilterAggregate: where {self.predicate._node.display()} agg {a}"
-                + (f" by [{g}]" if g else ""))
+        return (f"FusedFilterAggregate: where {self.predicate._node.display()}"
+                f" agg {self._describe_aggs()}")
 
 
 class GatherOp(PhysicalOp):
@@ -1439,24 +1550,97 @@ class ConcatOp(PhysicalOp):
             yield part.cast_to_schema(self.schema)
 
 
-def _pipelined_join(ctx, pairs, how: str, suffix: str):
-    """Shared double-buffered join driver: for each (l, r, lon, ron) pair,
-    pair i+1's keys stage and its probe LAUNCHES while pair i's result
-    resolves (one pending slot bounds the extra HBM to one in-flight
-    pair). A declined dispatch goes straight to the host join — never
-    re-staging the attempt dispatch just proved doomed."""
+class JoinProbe(DeviceStep):
+    """The step of one join pair: the right-build range probe of
+    kernels/device_join.py on the device, acero's hash join on the host.
+    Held by the join operators (a pair is two partitions, so the operator
+    is no per-partition map); stateless, shared by a cached plan's clones."""
+
+    dispatches = "device_join_dispatches"
+    fallbacks = "device_join_fallbacks"
+    site = "device.join"
+    # assembly runs OUTSIDE the driver's catch-all: a defect there must
+    # crash loudly, not silently recompute on host
+    finish_falls_back = False
+
+    def __init__(self, left_on, right_on, how: str, suffix: str):
+        self.left_on = left_on
+        self.right_on = right_on
+        self.how = how
+        self.suffix = suffix
+
+    def launch(self, ctx, lpart, rpart):
+        from .kernels.device_join import device_join_launch, join_key_replicas
+
+        left_on, right_on = self.left_on, self.right_on
+        single = len(left_on) == 1
+        return device_join_launch(
+            lpart.table(), rpart.table(), list(left_on), list(right_on),
+            lpart.device_stage_cache(), rpart.device_stage_cache(), self.how,
+            left_replicas=(join_key_replicas(lpart, left_on[0])
+                           if single else None),
+            right_replicas=(join_key_replicas(rpart, right_on[0])
+                            if single else None))
+
+    def finish(self, ctx, res, lpart, rpart):
+        """(side, hit, bidx) probe result -> output partition."""
+        with ctx.stats.profiler.span("join.assemble", kind="phase"):
+            out = self._assemble(res, lpart.table(), rpart.table())
+        ctx.stats.bump("device_join_probes")
+        return MicroPartition.from_table(out)
+
+    def _assemble(self, res, ltbl, rtbl):
+        from .series import Series
+
+        side, hit, bidx = res
+        how, args = self.how, (self.left_on, self.right_on, self.suffix)
+        if side == "expanded":
+            # N:M range join: (lidx, ridx) pairs already expanded on
+            # host from the device range probe (-1 = left-outer miss)
+            return ltbl.join_from_indices(rtbl, hit, bidx, *args)
+        if side == "right_build":
+            if how == "semi":
+                return ltbl.filter_with_mask(Series.from_numpy(hit, "m"))
+            if how == "anti":
+                return ltbl.filter_with_mask(Series.from_numpy(~hit, "m"))
+            if how == "inner":
+                lidx = np.nonzero(hit)[0]
+                return ltbl.join_from_indices(rtbl, lidx, bidx[hit], *args)
+            # left outer: every left row, -1 -> null right
+            lidx = np.arange(len(ltbl), dtype=np.int64)
+            ridx = np.where(hit, bidx, -1)
+            return ltbl.join_from_indices(rtbl, lidx, ridx, *args)
+        # left_build (inner only): re-sort to host (lidx, ridx) order
+        ridx = np.nonzero(hit)[0]
+        lidx = bidx[hit]
+        order = np.argsort(lidx, kind="stable")
+        return ltbl.join_from_indices(rtbl, lidx[order], ridx[order], *args)
+
+    def host(self, ctx, lpart, rpart):
+        ctx.stats.bump("host_joins")
+        return lpart.hash_join(rpart, self.left_on, self.right_on, self.how,
+                               self.suffix)
+
+
+def _pipelined_join(ctx, pairs, probe: JoinProbe):
+    """Shared double-buffered join driver: for each (l, r) pair, pair i+1's
+    keys stage and its probe LAUNCHES while pair i's result resolves (one
+    pending slot, cleared as its finisher is called, bounds the extra HBM
+    to one in-flight pair). A declined launch goes straight to the host
+    join — never re-staging the attempt the launch just proved doomed."""
     pending = None
-    for l, r, lon, ron in pairs:
-        fin = ctx.eval_join_dispatch(l, r, lon, ron, how, suffix)
+    for l, r in pairs:
+        fin = ctx.launch(probe, l, r)
         if pending is not None:
-            yield pending()
-            pending = None
+            out, pending = pending(), None
+            yield out
         if fin is not None:
             pending = fin
         else:
-            yield ctx.eval_join_declined(l, r, lon, ron, how, suffix)
+            yield probe.host(ctx, l, r)
     if pending is not None:
-        yield pending()
+        out, pending = pending(), None
+        yield out
 
 
 class HashJoinOp(PhysicalOp):
@@ -1466,10 +1650,8 @@ class HashJoinOp(PhysicalOp):
     def __init__(self, left: PhysicalOp, right: PhysicalOp, left_on, right_on,
                  how: str, schema: Schema, suffix: str = "right."):
         super().__init__([left, right], schema, max(left.num_partitions, right.num_partitions))
-        self.left_on = left_on
-        self.right_on = right_on
         self.how = how
-        self.suffix = suffix
+        self.probe = JoinProbe(left_on, right_on, how, suffix)
 
     def execute(self, inputs, ctx) -> PartStream:
         lbuf = ctx.partition_buffer()
@@ -1495,9 +1677,9 @@ class HashJoinOp(PhysicalOp):
                     l = MicroPartition.empty(lschema)
                 if r is None:
                     r = MicroPartition.empty(rschema)
-                yield l, r, self.left_on, self.right_on
+                yield l, r
 
-        yield from _pipelined_join(ctx, pairs(), self.how, self.suffix)
+        yield from _pipelined_join(ctx, pairs(), self.probe)
 
     def describe(self):
         return f"HashJoin[{self.how}]"
@@ -1520,7 +1702,8 @@ class BroadcastJoinOp(PhysicalOp):
         self.small_on = small_on
         self.how = how
         self.small_is_left = small_is_left
-        self.suffix = suffix
+        self.probe = (JoinProbe(small_on, big_on, how, suffix) if small_is_left
+                      else JoinProbe(big_on, small_on, how, suffix))
 
     def _filter_prunable(self) -> bool:
         """Whether the streamed (big) side may be pruned by a filter built
@@ -1595,12 +1778,9 @@ class BroadcastJoinOp(PhysicalOp):
             for part in inputs[0]:
                 if jf is not None:
                     part = prune_partition(part, jf, self.big_on, ctx)
-                if self.small_is_left:
-                    yield small, part, self.small_on, self.big_on
-                else:
-                    yield part, small, self.big_on, self.small_on
+                yield (small, part) if self.small_is_left else (part, small)
 
-        yield from _pipelined_join(ctx, pairs(), self.how, self.suffix)
+        yield from _pipelined_join(ctx, pairs(), self.probe)
 
     def describe(self):
         return f"BroadcastJoin[{self.how}]"

@@ -156,9 +156,3 @@ def hll_build_table_device_launch(table, aggregations, groupby):
         return Table(Schema(fields), cols)
 
     return finish
-
-
-def hll_build_table_device(table, aggregations, groupby):
-    """Blocking variant of hll_build_table_device_launch."""
-    fin = hll_build_table_device_launch(table, aggregations, groupby)
-    return None if fin is None else fin()

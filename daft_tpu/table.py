@@ -569,7 +569,8 @@ class Table:
         n = len(self)
         if n == 0 or not group_by:
             # ungrouped reductions measure faster through the pruned
-            # filter-then-agg path (see eval_agg); no fused variant exists
+            # filter-then-agg path (see physical._AggStep.host); no fused
+            # variant exists
             return None
         exprs_all = list(group_by) + list(to_agg) + ([predicate] if predicate is not None else [])
         refs = set()

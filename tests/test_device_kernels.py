@@ -680,6 +680,9 @@ QUERY_SHAPES = {
     ).groupby("l_mode").agg(col("l_price").sum().alias("s")),
     "string_transform": lambda o, li: li.where(
         col("l_mode").str.lower() == "mail").select(col("l_qty")),
+    "filter": lambda o, li: li.where(col("l_price") > 0.5),
+    "fused_map": lambda o, li: li.where(col("l_qty") > 10).select(
+        (col("l_price") * col("l_qty")).alias("gross"), col("l_key")),
     "join_agg": lambda o, li: o.where(col("o_seg") == "BUILDING").join(
         li, left_on="o_key", right_on="l_key").select(
         col("o_key"), (col("l_price") * (1 - col("l_disc"))).alias("rev")

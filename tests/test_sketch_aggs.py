@@ -536,16 +536,19 @@ class TestDevicePaths:
             part = MicroPartition.from_pydict(
                 {"v": list(range(2000)) * 2})
             from daft_tpu.expressions import AggExpr, Expression
+            from daft_tpu.physical import AggregateOp, InMemoryOp
 
             aggs = [Expression(AggExpr("sketch_hll", col("v")._node))
                     .alias("s")]
-            out = ctx.eval_agg(part, aggs, None)
+            op = AggregateOp(InMemoryOp([part], part.schema), aggs, [],
+                             part.schema)
+            out = ctx.run(op, part)
             assert ctx.stats.counters.get("device_sketch_builds") == 1
             # breaker path: an injected device fault falls back to host
             # with an identical sketch
             ctx2 = ExecutionContext(cfg)
             with faults.inject("device.kernel", "always"):
-                out2 = ctx2.eval_agg(part, aggs, None)
+                out2 = ctx2.run(op, part)
             assert not ctx2.stats.counters.get("device_sketch_builds")
             assert out.to_pydict() == out2.to_pydict()
         finally:

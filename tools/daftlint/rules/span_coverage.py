@@ -22,9 +22,11 @@ Since the morsel-driven streaming executor (daft_tpu/stream/) the rule
 also pins the *morsel contract* and the stream driver's coverage:
 
 - a class declaring ``morsel_streamable = True`` must define
-  ``map_partition`` in the same class body — claiming streamability
-  without the per-morsel entry point means the driver would silently fall
-  back to whole-partition materialization inside a streaming stage;
+  ``map_partition`` in the same class body, or declare a ``DeviceStep``
+  among its bases (``PhysicalOp.map_partition`` then runs the step through
+  ``ExecutionContext.run``) — claiming streamability without the
+  per-morsel entry point means the driver would silently fall back to
+  whole-partition materialization inside a streaming stage;
 - the stream driver's producer entry point (a function named
   ``_produce_partition``) must itself open a profiler span, so morsel
   work is never an attribution blind spot on the pool workers.
@@ -97,12 +99,13 @@ _WORKER_TASK_FNS = {"_execute_task"}
 # batched inference is a per-batch attribution blind spot
 _BATCH_EXEC_FNS = {"_run_flush"}
 
-# resident-segment executor entry point (daft_tpu/execution.py): every
-# DeviceSegmentOp partition routes through here, and its "fuse.segment"
-# span — parented to the driving op, zero orphans — is what attributes
-# whole-segment resident execution (stage + map + agg + gather as ONE
-# phase) in the merged trace
-_SEGMENT_EXEC_FNS = {"eval_segment"}
+# the device-step driver's second half (daft_tpu/execution.py): every
+# launched step resolves, finishes or falls back through here, under the
+# step's own phase span when it names one. DeviceSegmentOp does
+# ("fuse.segment"): parented to the driving op, zero orphans, it is what
+# attributes whole-segment resident execution (gather + the staged fallback
+# as ONE phase) in the merged trace
+_SEGMENT_EXEC_FNS = {"_finish_step"}
 
 
 def _delegates_to_stream_driver(fn: ast.FunctionDef) -> bool:
@@ -139,10 +142,10 @@ class SpanCoverageRule(Rule):
     name = "span-coverage"
     description = ("every *Op.execute(self, inputs, ctx) entry point "
                    "delegates to _map_execute or opens a profiler span; "
-                   "morsel_streamable ops implement map_partition; the "
-                   "stream driver's producer, the distributed worker's "
-                   "task entry point, and the resident-segment executor "
-                   "open spans")
+                   "morsel_streamable ops implement map_partition or "
+                   "declare a DeviceStep; the stream driver's producer, "
+                   "the distributed worker's task entry point, and the "
+                   "device-step finisher open spans")
 
     def run(self, project: Project) -> List[Finding]:
         out: List[Finding] = []
@@ -184,7 +187,7 @@ class SpanCoverageRule(Rule):
                     if not _execute_is_covered(node):
                         out.append(self.finding(
                             rel, node.lineno,
-                            f"segment-executor entry `{node.name}` opens "
+                            f"device-step finisher `{node.name}` opens "
                             "no profiler span — HBM-resident segment "
                             "execution must carry fuse.segment attribution"))
                     continue
@@ -193,8 +196,11 @@ class SpanCoverageRule(Rule):
                     continue
                 methods = {item.name for item in node.body
                            if isinstance(item, ast.FunctionDef)}
+                bases = {(dotted_name(b) or "").split(".")[-1]
+                         for b in node.bases}
                 if _claims_morsel_streamable(node) \
-                        and "map_partition" not in methods:
+                        and "map_partition" not in methods \
+                        and "DeviceStep" not in bases:
                     out.append(self.finding(
                         rel, node.lineno,
                         f"`{node.name}` claims `morsel_streamable = True` "
