@@ -54,12 +54,14 @@ LAYER_COUNTERS = (
 def layer_times(counters: dict) -> str:
     """``plan 1ms stage 12ms ... staged 3.1MB in 2 columns ... compiles 0
     (0ms) cache loads 0 dict lookups 1 packed 0 gathered agg reduce 1 dense
-    0 kernel``: the layer counters of one query, for its printed line. A
+    0 kernel probe levels 56 compared 17 gathered``: the layer counters of
+    one query, for its printed line. A
     warm query that stages columns lost its stage cache; one that compiles
     (and for how long) met a shape the warm-up did not; one that gathers a
     dictionary predicate met a dictionary over ``DICT_PACKED_MAX_ENTRIES``;
     one whose aggregate took the kernel grouped into a bucket over
-    ``DENSE_MAX_SEGMENTS``."""
+    ``DENSE_MAX_SEGMENTS``; the levels of its join probes' searches that
+    gathered are those beyond ``PROBE_COMPARE_LEVELS`` of each build."""
     parts = [f"{label} {counters.get(key, 0) / 1e6:.0f}ms"
              for label, key in LAYER_COUNTERS]
     parts.append(f"staged {counters.get('stage_bytes', 0) / 1e6:.1f}MB "
@@ -71,7 +73,10 @@ def layer_times(counters: dict) -> str:
                  f"dict lookups {counters.get('dict_lookup_packed', 0)} packed "
                  f"{counters.get('dict_lookup_gather', 0)} gathered "
                  f"agg reduce {counters.get('agg_reduce_dense', 0)} dense "
-                 f"{counters.get('agg_reduce_kernel', 0)} kernel")
+                 f"{counters.get('agg_reduce_kernel', 0)} kernel "
+                 f"probe levels {counters.get('join_probe_compare_levels', 0)} "
+                 f"compared {counters.get('join_probe_gather_levels', 0)} "
+                 f"gathered")
     return " ".join(parts)
 
 

@@ -5,8 +5,13 @@ Semantic spec: the reference's probe table
 side, stream the other, null keys never match) and hash_join
 (ops/joins/hash_join.rs). The TPU formulation avoids a hash table entirely:
 no data-dependent control flow fits XLA, so the build side is SORTED once
-(cached with the partition, like column staging) and every probe is a
-vectorized `searchsorted` — O(P log B) fully on the VPU with static shapes.
+(cached with the partition, like column staging) and every probe row makes
+ONE lower-bound search over it, with static shapes: its first
+PROBE_COMPARE_LEVELS levels are compares against pivots at static positions
+of the sorted keys (no gather), the remaining levels one gather each
+(_lower_bound). Where the row's run of equal keys ends is read from the
+build (one segmented scan of its lanes, _left_in_run), not searched for a
+second time (_match_ranges).
 
 Scope: 1-4 keys — integer/date values, and plain STRING columns via
 joint-dictionary recoding (_stage_key_pair) — with multi-column keys packed
@@ -41,6 +46,142 @@ from ..profile import timeline
 from .device import fetch, is_device_dtype, size_bucket, stage_table_columns
 
 
+# The first levels of a probe's binary search are resolved by COMPARES against
+# pivots at static positions of the sorted build keys, the rest by gathers. On
+# a v5e a gather of every probe lane costs 7.5 ns a lane whatever the table's
+# size, a compare against one more pivot 0.6 ps a lane, so level l (2**l more
+# pivots) is cheaper compared up to l = 13: fixed by tools/join_probe_sweep.py
+# (PERF.md, PR 35). A build of at most 2**L lanes is searched with no gather.
+PROBE_COMPARE_LEVELS = 14
+# pivots compared in one pass over the probe lanes: a pass costs about 25 us
+# of its own on a v5e and reads and writes the lanes once, so more is faster
+# there; where the compare is not fused into its reduction (XLA's CPU
+# backend) a pass holds this many int32 a lane
+_PIVOT_CHUNK = 64
+# lanes a row of _left_in_run's scan
+_SCAN_ROW = 1024
+
+
+def probe_search_levels(b: int) -> Tuple[int, int]:
+    """(compare levels, gather levels) of the search over a build of ``b``
+    lanes: static, from the build's size bucket alone."""
+    n = max(b - 1, 0).bit_length()
+    c = min(PROBE_COMPARE_LEVELS, n)
+    return c, n - c
+
+
+def _count_below(pivots, v):
+    """Per lane of ``v``, how many of the sorted ``pivots`` are < v:
+    ``_PIVOT_CHUNK`` pivots a pass over the lanes, each pass one
+    compare-and-reduce with the pivots on the major axis (a row a pivot,
+    accumulated lane by lane). The v5e compiler fuses the compare into the
+    reduction and holds nothing beyond the lanes' counts; no backend holds
+    more than lanes x ``_PIVOT_CHUNK``, never lanes x pivots. (A Python
+    loop of scalar compares costs the v5e compiler 32 KB of temporaries a
+    pivot.)"""
+    def count(chunk, c):
+        return c + jnp.sum(chunk[:, None] < v[None, :], axis=0,
+                           dtype=jnp.int32)
+
+    zero = jnp.zeros(v.shape, jnp.int32)
+    n = pivots.shape[0]
+    if n <= _PIVOT_CHUNK:
+        return count(pivots, zero)
+    pad = -n % _PIVOT_CHUNK
+    if pad:  # a pivot of iinfo.max is below no key
+        pivots = jnp.concatenate(
+            [pivots, jnp.full(pad, jnp.iinfo(pivots.dtype).max, pivots.dtype)])
+    return jax.lax.fori_loop(
+        0, (n + pad) // _PIVOT_CHUNK,
+        lambda i, c: count(jax.lax.dynamic_slice(
+            pivots, (i * _PIVOT_CHUNK,), (_PIVOT_CHUNK,)), c),
+        zero)
+
+
+def _lower_bound(sk, v):
+    """``searchsorted(sk, v, side="left")`` as int32: the first
+    ``probe_search_levels`` levels pick each lane's block of ``stride`` build
+    lanes by counting the block-end pivots below it, the remaining levels
+    halve the block with one gather each. Lanes past the end of ``sk`` read
+    as +infinity, so any build size is searched exactly."""
+    b = sk.shape[0]
+    _, gather_levels = probe_search_levels(b)
+    stride = 1 << gather_levels
+    # the last lane of every whole block (none, if one block holds sk)
+    pivots = jax.lax.slice(sk, (min(stride - 1, b),), (b,), (stride,))
+    pos = _count_below(pivots, v) * stride
+    if not gather_levels:
+        return pos
+
+    def halve(_, carry):
+        pos, step = carry
+        step = step // 2
+        idx = pos + (step - 1)
+        below = (idx < b) & (sk[jnp.minimum(idx, b - 1)] < v)
+        return pos + jnp.where(below, step, 0), step
+
+    # a loop, not gather_levels unrolled steps: unrolled, the v5e compiler
+    # keeps three arrays of P lanes live where the loop's carry is one
+    return jax.lax.fori_loop(0, gather_levels, halve,
+                             (pos, jnp.int32(stride)))[0]
+
+
+def _left_in_run(count, last):
+    """Per lane, the sum of ``count`` (>= 0) from the lane to the end of its
+    run (``last`` marks each run's final lane): ONE segmented scan from the
+    far end, by doubling inside rows of ``_SCAN_ROW`` lanes (each step a
+    lane whose run is still open takes in twice as many lanes), then over
+    what the rows' first lanes came to. A lane's sum and whether its run has
+    closed travel in one int32 (``~sum`` once closed), so a step shifts one
+    array. Plain elementwise work that compiles in a second at any size:
+    the v5e compiler takes 10-75 s over ONE cumulative window
+    (``jnp.cumsum``, ``lax.cummin``) of 256k to 2M lanes, and a program's
+    code is held in HBM, a megabyte a scan of 8M lanes."""
+    n = count.shape[0]
+    width = min(n, _SCAN_ROW)
+    # lanes past the end close every run and add nothing (~0)
+    acc = jnp.concatenate([jnp.where(last, ~count, count),
+                           jnp.full(-n % width, -1, count.dtype)])
+
+    def take_in(acc, nearer):
+        # an open lane adds what ``nearer`` came to, and closes if it has
+        total = acc + jnp.where(nearer < 0, ~nearer, nearer)
+        return jnp.where(acc < 0, acc, jnp.where(nearer < 0, ~total, total))
+
+    acc = acc.reshape(-1, width)
+    reach = 1
+    while reach < width:
+        acc = take_in(acc, jnp.pad(acc[:, reach:], ((0, 0), (0, reach))))
+        reach *= 2
+    if acc.shape[0] > 1:
+        # the rows after this one, for the runs still open at its end
+        first = acc[:, 0]
+        after = jnp.concatenate([
+            _left_in_run(jnp.where(first < 0, ~first, first), first < 0)[1:],
+            jnp.zeros(1, count.dtype)])
+        acc = take_in(acc, after[:, None])
+    acc = acc.reshape(-1)[:n]
+    return jnp.where(acc < 0, ~acc, acc)
+
+
+def _match_ranges(sk, sorted_valid, probe_vals, probe_valid):
+    """(lo, counts) of each probe row over the SORTED build keys ``sk`` (valid
+    lanes first within a run of equal keys): ``lo`` its lower bound, searched
+    for once; ``counts`` the valid lanes of the run of its own key starting
+    there. Where a run ends is a property of the build, so the valid lanes
+    left in its run from each build lane on are counted over the B build
+    lanes (_left_in_run), and a probe row reads them where the key at its
+    lower bound equals its own."""
+    b = sk.shape[0]
+    run_ends = jnp.concatenate([sk[1:] != sk[:-1], jnp.ones(1, bool)])
+    run_valid = _left_in_run(sorted_valid.astype(jnp.int32), run_ends)
+    lo = _lower_bound(sk, probe_vals)
+    # lo == B reads the last build key, which is then below the probe's
+    at = jnp.minimum(lo, b - 1)
+    counts = jnp.where(probe_valid & (sk[at] == probe_vals), run_valid[at], 0)
+    return lo, counts
+
+
 @functools.partial(jax.jit, static_argnames=())
 def _range_probe_kernel(build_vals, build_valid, probe_vals, probe_valid):
     """Per-probe-row match RANGE over the sorted build keys: (lo [P], counts
@@ -52,22 +193,20 @@ def _range_probe_kernel(build_vals, build_valid, probe_vals, probe_valid):
 
     Valid lanes sort before null/padding lanes within an equal-key run
     (lexsort secondary key), so each run's valid matches are a contiguous
-    prefix and the cumulative-valid counter turns [lo, hi) into an exact
-    valid-match count. The variable-size expansion happens on the HOST
-    (data-dependent shapes cannot live under XLA): reference semantic is the
-    multi-row probe of src/daft-table/src/probe_table/mod.rs."""
+    prefix, counted by _match_ranges. The variable-size expansion happens
+    on the HOST (data-dependent shapes cannot live under XLA): reference
+    semantic is the multi-row probe of
+    src/daft-table/src/probe_table/mod.rs."""
     big = jnp.iinfo(build_vals.dtype).max
     k = jnp.where(build_valid, build_vals, big)
-    perm = jnp.lexsort((~build_valid, k))
-    sk = k[perm]
-    sorted_valid = build_valid[perm]
+    # lexsort((~build_valid, k)) is this sort's last output; its first two
+    # are k[perm] and ~build_valid[perm], which cost a gather of B lanes each
+    sk, sorted_null, perm = jax.lax.sort(
+        (k, ~build_valid, jnp.arange(k.shape[0], dtype=jnp.int32)), num_keys=2)
+    sorted_valid = ~sorted_null
     dup = jnp.any((sk[1:] == sk[:-1]) & sorted_valid[1:] & sorted_valid[:-1])
-    vp = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                          jnp.cumsum(sorted_valid.astype(jnp.int32))])
-    lo = jnp.searchsorted(sk, probe_vals, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(sk, probe_vals, side="right").astype(jnp.int32)
-    counts = jnp.where(probe_valid, vp[hi] - vp[lo], 0)
-    return lo, counts, perm.astype(jnp.int32), dup
+    lo, counts = _match_ranges(sk, sorted_valid, probe_vals, probe_valid)
+    return lo, counts, perm, dup
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -582,10 +721,20 @@ def device_join_launch(left_table, right_table, left_keys, right_keys,
     return _launch_probe(lv, lm, rv, rm, ln, rn, how)
 
 
+def _probe(build_vals, build_valid, probe_vals, probe_valid):
+    """Launch one range probe and count the levels its search resolves by
+    compares and by gathers (static, from the build's size)."""
+    compares, gathers = probe_search_levels(int(build_vals.shape[0]))
+    timeline.add("join_probe_compare_levels", compares)
+    timeline.add("join_probe_gather_levels", gathers)
+    return _range_probe_kernel(build_vals, build_valid, probe_vals,
+                               probe_valid)
+
+
 def _launch_probe(lv, lm, rv, rm, ln: int, rn: int, how: str):
     """Dispatch the right-build range probe now (async); return the
     resolver that makes the dup decision and finishes the probe."""
-    lo, counts, perm, dup = _range_probe_kernel(rv, rm, lv, lm)
+    lo, counts, perm, dup = _probe(rv, rm, lv, lm)
 
     def resolve():
         # build=right first (probe order == host output order); ONE sort
@@ -595,7 +744,7 @@ def _launch_probe(lv, lm, rv, rm, ln: int, rn: int, how: str):
             return ("right_build", np.asarray(hit)[:ln],
                     np.asarray(bidx)[:ln].astype(np.int64))
         if how == "inner":
-            lo2, counts2, perm2, dup2 = _range_probe_kernel(lv, lm, rv, rm)
+            lo2, counts2, perm2, dup2 = _probe(lv, lm, rv, rm)
             if not bool(fetch(dup2)):
                 hit, bidx = fetch(_pk_outputs(lo2, counts2, perm2))
                 return ("left_build", np.asarray(hit)[:rn],

@@ -7,7 +7,8 @@ staged columns, or writes a 64M-row temporary a reduction (a Python loop over
 the groups did: 10 GB of temporaries; one variadic reduce did not fit the
 chip at all). The programs below are Q1's and Q6's shape through the
 program's own ``segment_reduce``; the compiler's own account of their
-temporaries is the guard. No time is read here.
+temporaries is the guard. The join probe's search (PR 35) is held the same
+way at ``tpch1-join``'s Q5 shape. No time is read here.
 """
 
 import pytest
@@ -29,7 +30,7 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, one_chip, dtypes):
+def _compile(fn, one_chip, dtypes, rows=None):
     import jax
 
     # a compile for a described chip is written to the persistent cache but
@@ -37,8 +38,8 @@ def _compile(fn, one_chip, dtypes):
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        args = [jax.ShapeDtypeStruct((ROWS,), dt, sharding=one_chip)
-                for dt in dtypes]
+        args = [jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+                for dt, n in zip(dtypes, rows or [ROWS] * len(dtypes))]
         return jax.jit(fn).lower(*args).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
@@ -99,3 +100,19 @@ def test_ungrouped_shape_stays_fused(one_chip):
                and "f32[67108864]" in line.split(" fusion(", 1)[0]]
     assert not written, written  # no float column is written out
     assert compiled.memory_analysis().temp_size_in_bytes < GIB // 2
+
+
+def test_join_probe_search_keeps_one_probe_sized_temporary(one_chip):
+    import jax.numpy as jnp
+
+    from daft_tpu.kernels import device_join as dj
+
+    # Q5's second orientation: 8M probe lanes (LINEITEM) over a 64k build.
+    # peak_hbm_gib is set inside a probe: with the gather levels unrolled
+    # the compiler kept three arrays of P lanes live (102 MB); as a loop,
+    # and with the pivots compared a chunk a pass, it keeps one (34 MB)
+    p, b = 1 << 23, 1 << 16
+    compiled = _compile(lambda *a: dj._match_ranges(*a), one_chip,
+                        [jnp.int32, jnp.bool_, jnp.int32, jnp.bool_],
+                        rows=[b, b, p, p])
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 4 * p
