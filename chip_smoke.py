@@ -54,14 +54,17 @@ LAYER_COUNTERS = (
 def layer_times(counters: dict) -> str:
     """``plan 1ms stage 12ms ... staged 3.1MB in 2 columns ... compiles 0
     (0ms) cache loads 0 dict lookups 1 packed 0 gathered agg reduce 1 dense
-    0 kernel probe levels 56 compared 17 gathered``: the layer counters of
-    one query, for its printed line. A
+    0 kernel probe levels 56 compared 17 gathered sql plan 1.2ms 2
+    subqueries 1 scalar subquery joins 1 (1 on the device)``: the layer
+    counters of one query, for its printed line. A
     warm query that stages columns lost its stage cache; one that compiles
     (and for how long) met a shape the warm-up did not; one that gathers a
     dictionary predicate met a dictionary over ``DICT_PACKED_MAX_ENTRIES``;
     one whose aggregate took the kernel grouped into a bucket over
     ``DENSE_MAX_SEGMENTS``; the levels of its join probes' searches that
-    gathered are those beyond ``PROBE_COMPARE_LEVELS`` of each build."""
+    gathered are those beyond ``PROBE_COMPARE_LEVELS`` of each build; a query
+    that came as SQL text says what its front end took and what its
+    subqueries became (0 throughout for one built through the API)."""
     parts = [f"{label} {counters.get(key, 0) / 1e6:.0f}ms"
              for label, key in LAYER_COUNTERS]
     parts.append(f"staged {counters.get('stage_bytes', 0) / 1e6:.1f}MB "
@@ -76,7 +79,13 @@ def layer_times(counters: dict) -> str:
                  f"{counters.get('agg_reduce_kernel', 0)} kernel "
                  f"probe levels {counters.get('join_probe_compare_levels', 0)} "
                  f"compared {counters.get('join_probe_gather_levels', 0)} "
-                 f"gathered")
+                 f"gathered "
+                 f"sql plan {counters.get('sql_plan_ns', 0) / 1e6:.1f}ms "
+                 f"{counters.get('sql_subqueries', 0)} subqueries "
+                 f"{counters.get('sql_scalar_subqueries', 0)} scalar "
+                 f"subquery joins {counters.get('sql_subquery_joins', 0)} "
+                 f"({counters.get('sql_subquery_joins_device', 0)} "
+                 f"on the device)")
     return " ".join(parts)
 
 
@@ -166,6 +175,13 @@ Q6_SQL = """
     FROM lineitem
     WHERE l_shipdate >= DATE '1994-01-01' AND l_shipdate < DATE '1995-01-01'
       AND l_discount BETWEEN 0.05 AND 0.07 AND l_quantity < 24
+"""
+
+# a subquery: dt.sql decorrelates EXISTS into a semi join of its own making
+EXISTS_SQL = """
+    SELECT COUNT(*) AS n FROM orders
+    WHERE EXISTS (SELECT * FROM lineitem
+                  WHERE l_orderkey = o_orderkey AND l_quantity >= 50)
 """
 
 
@@ -263,6 +279,19 @@ class Smoke:
                       f"{name}: sql device counters {d} != DataFrame API's "
                       f"{self.api_counters.get(name)}")
             leg.note(f"{name}: smoke timing {wall:.2f}s")
+            leg.note(f"{name} sql: {layer_times(c)}")
+        import pyarrow.compute as pc
+
+        li = self.tables["lineitem"]
+        want = len(pc.unique(li.filter(pc.greater_equal(
+            li["l_quantity"], 50))["l_orderkey"]))
+        (got, c), wall = timed(lambda: run_query(lambda: self.dt.sql(
+            EXISTS_SQL, orders=self.orders, lineitem=self.li)))
+        leg.check(got == {"n": [want]}, f"exists sql: {got} != {want}")
+        leg.counters_clean("exists sql", c, device_join_probes=1,
+                           sql_subqueries=1, sql_subquery_joins_device=1)
+        leg.note(f"exists: smoke timing {wall:.2f}s")
+        leg.note(f"exists sql: {layer_times(c)}")
         return leg.finish()
 
     # ---------------------------------------------------------------- scan

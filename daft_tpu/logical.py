@@ -442,10 +442,15 @@ def join_output_schema(left: Schema, right: Schema, left_on: List[Expression],
 
 
 class Join(LogicalPlan):
+    # who made this join, when it was not the user: "sql_subquery" for the
+    # joins decorrelation makes (sql_subquery.py). An instance attribute only
+    # when set, so an untagged join's fingerprint is what it always was.
+    origin: Optional[str] = None
+
     def __init__(self, left: LogicalPlan, right: LogicalPlan,
                  left_on: List[Expression], right_on: List[Expression],
                  how: str = "inner", strategy: Optional[str] = None,
-                 suffix: str = "right."):
+                 suffix: str = "right.", origin: Optional[str] = None):
         if how not in ("inner", "left", "right", "outer", "semi", "anti", "cross"):
             raise ValueError(f"unknown join type {how!r}")
         if strategy not in (None, "hash", "sort_merge", "broadcast"):
@@ -462,6 +467,8 @@ class Join(LogicalPlan):
         self.how = how
         self.strategy = strategy
         self.suffix = suffix
+        if origin is not None:
+            self.origin = origin
         if how == "cross":
             fields = list(left.schema)
             lnames = set(left.schema.field_names())
@@ -476,7 +483,8 @@ class Join(LogicalPlan):
         return [self.left, self.right]
 
     def with_children(self, c):
-        return Join(c[0], c[1], self.left_on, self.right_on, self.how, self.strategy, self.suffix)
+        return Join(c[0], c[1], self.left_on, self.right_on, self.how, self.strategy, self.suffix,
+                    self.origin)
 
     def num_partitions(self) -> int:
         return max(self.left.num_partitions(), self.right.num_partitions())

@@ -164,7 +164,64 @@ def _push_down_filter(p: LogicalPlan) -> Optional[LogicalPlan]:
     return None
 
 
+def _filter_into_cross_join(f: Filter, j: Join) -> Optional[LogicalPlan]:
+    """A filter over a cross join (SQL's ``FROM a, b WHERE ...``): a
+    conjunct ``l == r`` between one column of each side, of one type, becomes
+    a key of an inner join, and a one-sided conjunct goes to its side. The
+    cross join's schema is kept: the inner join drops its right keys, so a
+    projection gives each back as its left key under the old name."""
+    from .expressions import BinaryOp, Column
+
+    lschema, rschema = j.left.schema, j.right.schema
+    lnames = set(lschema.field_names())
+    # output name of every right column -> its name on the right side
+    rnames = {(n if n not in lnames else f"{j.suffix}{n}"): n
+              for n in rschema.field_names()}
+    to_left: List[Expression] = []
+    to_right: List[Expression] = []
+    keep: List[Expression] = []
+    keys: Dict[str, str] = {}  # right output name -> left key
+    for c in _split_conjuncts(f.predicate):
+        cols = expr_input_columns(c)
+        n = c._node
+        if expr_has_special(c) or not cols:
+            keep.append(c)
+        elif all(x in lnames for x in cols):
+            to_left.append(c)
+        elif all(x in rnames for x in cols):
+            to_right.append(substitute_columns(
+                c, {out: col(orig) for out, orig in rnames.items()}))
+        elif (isinstance(n, BinaryOp) and n.op == "=="
+              and isinstance(n.left, Column) and isinstance(n.right, Column)):
+            a, b = n.left.cname, n.right.cname
+            if a not in lnames:
+                a, b = b, a
+            if (a in lnames and b in rnames and b not in keys
+                    and a not in keys.values()
+                    and lschema[a].dtype == rschema[rnames[b]].dtype):
+                keys[b] = a
+            else:
+                keep.append(c)
+        else:
+            keep.append(c)
+    if not (to_left or to_right or keys):
+        return None
+    left = Filter(j.left, _and_all(to_left)) if to_left else j.left
+    right = Filter(j.right, _and_all(to_right)) if to_right else j.right
+    if keys:
+        inner = Join(left, right, [col(a) for a in keys.values()],
+                     [col(rnames[b]) for b in keys], "inner", None, j.suffix)
+        out: LogicalPlan = Project(inner, [
+            col(keys[n]).alias(n) if n in keys else col(n)
+            for n in j.schema.field_names()])
+    else:
+        out = Join(left, right, [], [], "cross", j.strategy, j.suffix)
+    return Filter(out, _and_all(keep)) if keep else out
+
+
 def _filter_into_join(f: Filter, j: Join) -> Optional[LogicalPlan]:
+    if j.how == "cross":
+        return _filter_into_cross_join(f, j)
     if j.how not in ("inner", "semi", "anti", "left", "right"):
         return None
     # map join-output column name -> (side, original name)
@@ -225,7 +282,8 @@ def _filter_into_join(f: Filter, j: Join) -> Optional[LogicalPlan]:
         key_map = {ln: j.right_on[i] for i, ln in enumerate(lk)}
         to_right = [substitute_columns(c, key_map) for c in to_right]
         new_right = Filter(new_right, _and_all(to_right))
-    new_join = Join(new_left, new_right, j.left_on, j.right_on, j.how, j.strategy, j.suffix)
+    new_join = Join(new_left, new_right, j.left_on, j.right_on, j.how, j.strategy, j.suffix,
+                    j.origin)
     if keep:
         return Filter(new_join, _and_all(keep))
     return new_join
@@ -412,7 +470,8 @@ def _prune_columns(p: LogicalPlan, required: Optional[List[str]]) -> LogicalPlan
             rneed = [c for c in p.right.schema.field_names() if c in rneed]
         new_left = _prune_columns(p.left, lneed)
         new_right = _prune_columns(p.right, rneed)
-        return Join(new_left, new_right, p.left_on, p.right_on, p.how, p.strategy, p.suffix)
+        return Join(new_left, new_right, p.left_on, p.right_on, p.how, p.strategy, p.suffix,
+                    p.origin)
 
     if isinstance(p, (Sort, Repartition)):
         need = None if required is None else list(required)

@@ -1562,6 +1562,9 @@ class JoinProbe(DeviceStep):
     # assembly runs OUTSIDE the driver's catch-all: a defect there must
     # crash loudly, not silently recompute on host
     finish_falls_back = False
+    # the logical join's ``origin`` ("sql_subquery"), set by translate: its
+    # pairs count in ``<origin>_joins`` and ``<origin>_joins_device``
+    origin: Optional[str] = None
 
     def __init__(self, left_on, right_on, how: str, suffix: str):
         self.left_on = left_on
@@ -1587,6 +1590,9 @@ class JoinProbe(DeviceStep):
         with ctx.stats.profiler.span("join.assemble", kind="phase"):
             out = self._assemble(res, lpart.table(), rpart.table())
         ctx.stats.bump("device_join_probes")
+        if self.origin is not None:
+            ctx.stats.bump_many({f"{self.origin}_joins": 1,
+                                 f"{self.origin}_joins_device": 1})
         return MicroPartition.from_table(out)
 
     def _assemble(self, res, ltbl, rtbl):
@@ -1618,6 +1624,8 @@ class JoinProbe(DeviceStep):
 
     def host(self, ctx, lpart, rpart):
         ctx.stats.bump("host_joins")
+        if self.origin is not None:
+            ctx.stats.bump(f"{self.origin}_joins")
         return lpart.hash_join(rpart, self.left_on, self.right_on, self.how,
                                self.suffix)
 
@@ -2244,7 +2252,10 @@ def _translate(plan: LogicalPlan, cfg, morsels: bool = False) -> PhysicalOp:
         return ConcatOp(l, r, plan.schema)
 
     if isinstance(plan, Join):
-        return _translate_join(plan, cfg)
+        op = _translate_join(plan, cfg)
+        if plan.origin is not None and hasattr(op, "probe"):
+            op.probe.origin = plan.origin  # counted where the pair is joined
+        return op
 
     raise ValueError(f"cannot translate logical node {plan.name()}")
 

@@ -62,7 +62,14 @@ def arm_for_query(stats, query_id: str, profile=None):
             else get_context().execution_config.enable_profiling)
     live = device_trace_live()
     if want or live or tracing.active():
+        early = stats.profiler
         stats.profiler = Profiler(query_id=query_id, device_timeline=live)
+        if early.armed:
+            # dt.sql() armed these stats while it planned: its front-end
+            # spans (sql.parse, sql.plan, sql.decorrelate) are the query's
+            stats.profiler.splice(
+                [sp.as_dict() for sp in early.spans_snapshot()],
+                early.events_snapshot(), None, 0)
     return want
 
 

@@ -13,12 +13,17 @@ or USING(...), WHERE, GROUP BY (exprs / positions / select aliases), HAVING,
 ORDER BY [ASC|DESC] [NULLS FIRST|LAST], LIMIT, aggregates incl. COUNT(*),
 COUNT(DISTINCT x) and compound agg expressions (SUM(x)*2), CASE, CAST,
 BETWEEN, IN, LIKE/ILIKE, IS [NOT] NULL, COALESCE/NULLIF/IF, and a scalar
-function library over the numeric/string/temporal namespaces.
+function library over the numeric/string/temporal namespaces. As a conjunct
+of WHERE: [NOT] EXISTS (SELECT ...), expr IN (SELECT ...) and
+expr <cmp> (SELECT <aggregate> ...), correlated by an equality with one outer
+column or not at all; ``sql_subquery.py`` turns each into a join while the
+plan is built. Nothing executes here: ``sql()`` returns a lazy DataFrame.
 """
 
 from __future__ import annotations
 
 import re
+import time
 from typing import Dict, List, Optional, Tuple
 
 from .datatypes import DataType
@@ -189,6 +194,13 @@ class Parser:
                     e = ~e
             elif self.eat_kw("IN"):
                 self.expect_op("(")
+                if self.at_kw("SELECT"):
+                    if neg:
+                        from .sql_subquery import not_in_error
+
+                        raise not_in_error()
+                    e = self._subquery("in", e)
+                    continue
                 items = [self._literal_value()]
                 while self.eat_op(","):
                     items.append(self._literal_value())
@@ -297,12 +309,19 @@ class Parser:
             self.next()
             return lit(t.value)
         if self.eat_op("("):
+            if self.at_kw("SELECT"):
+                return self._subquery("scalar")
             e = self.parse_expr()
             self.expect_op(")")
             return e
         if t.kind != "ident":
             raise ValueError(f"unexpected token {t.value!r}")
         up = t.value.upper()
+        if up == "EXISTS" and self.peek(1).value == "(" \
+                and self.peek(2).value.upper() == "SELECT":
+            self.next()
+            self.next()
+            return self._subquery("exists")
         if up == "NULL":
             self.next()
             return lit(None)
@@ -348,11 +367,20 @@ class Parser:
                 if sub.value not in m:
                     raise ValueError(
                         f"column {sub.value!r} not found in table {name!r}")
+                self._qualified(name, m[sub.value])
                 return col(m[sub.value])
             # select list parses before FROM: defer resolution (see
             # _resolve_qualified in _apply_projection)
             return col(f"{name}\x00{sub.value}")
         return col(name)
+
+    def _qualified(self, qualifier: str, column: str) -> None:
+        """``qualifier.x`` resolved to ``column`` (QueryPlanner checks it)."""
+
+    def _subquery(self, kind: str, lhs: Optional[Expression] = None) -> Expression:
+        """At the SELECT of a parenthesised subquery: only a query has the
+        catalog to plan one (QueryPlanner)."""
+        raise ValueError("a subquery needs a query: use sql(), not sql_expr()")
 
     def _case(self) -> Expression:
         self.expect_kw("CASE")
@@ -530,40 +558,71 @@ def _is_agg_tree(node) -> bool:
     return node.is_aggregation()
 
 
+# where a subquery may not stand, by the clause being parsed
+_NO_SUBQUERY = {"select": "the SELECT list", "from": "FROM / JOIN ... ON",
+                "group": "GROUP BY", "having": "HAVING", "order": "ORDER BY"}
+
+
 class QueryPlanner(Parser):
+    def __init__(self, tokens: List[Token], catalog: Dict[str, "object"],
+                 profiler=None):
+        from .profile import DISARMED
+
+        super().__init__(tokens, catalog)
+        self.profiler = DISARMED if profiler is None else profiler
+        self._clause = "select"   # of the SELECT being parsed
+        self._scope: List[str] = []   # columns of the WHERE being parsed
+        self._subs: Dict = {}     # placeholder column -> Subquery, ditto
+        # table aliases of the subquery being planned (None: not in one)
+        self._own: Optional[set] = None
+        # what sql() carries onto the DataFrame's RuntimeStats
+        self.counters = {"sql_subqueries": 0, "sql_scalar_subqueries": 0}
+
     def parse_query(self):
         df = self._select_stmt()
         if self.peek().kind != "eof":
             raise ValueError(f"trailing tokens at {self.peek().value!r}")
         return df
 
-    def _select_stmt(self):
+    def _select_stmt(self, sub: Optional[str] = None,
+                     name: Optional[str] = None):
+        """One SELECT, planned. With ``sub`` it is the subquery ``name`` of
+        that kind, seen from the WHERE of the enclosing SELECT (whose
+        columns are ``self._scope``): the result is its ``Subquery``."""
         # parse every clause first, then plan (ORDER BY may reference columns
         # the projection drops, so sort placement depends on the whole query)
+        outer, enclosing = self._scope, self._clause
         self.expect_kw("SELECT")
+        self._clause = "select"
         distinct = self.eat_kw("DISTINCT")
         items = self._select_list()
+        factors = None
         if self.eat_kw("FROM"):
-            df = self._from_clause()
+            self._clause = "from"
+            df, factors = self._from_clause()
         else:
             from .api import from_pydict
 
             df = from_pydict({"__no_from__": [0]})
+        corr: List[Tuple[str, str]] = []
         if self.eat_kw("WHERE"):
-            df = df.where(self.parse_expr())
+            df = self._where(df, factors, outer if sub else None, corr)
         group_exprs: Optional[List[Expression]] = None
         if self.eat_kw("GROUP"):
+            self._clause = "group"
             self.expect_kw("BY")
             group_exprs = [self._group_item(items, df)]
             while self.eat_op(","):
                 group_exprs.append(self._group_item(items, df))
         having = None
         if self.eat_kw("HAVING"):
+            self._clause = "having"
             having = self.parse_expr()
         order_keys: List[Expression] = []
         desc: List[bool] = []
         nf: List[Optional[bool]] = []
         if self.eat_kw("ORDER"):
+            self._clause = "order"
             self.expect_kw("BY")
             while True:
                 order_keys.append(self._order_item(items))
@@ -589,10 +648,164 @@ class QueryPlanner(Parser):
             if t.kind != "number":
                 raise ValueError("LIMIT requires a number")
             limit = int(t.value)
+        self._clause = enclosing
+        if sub is not None:
+            if order_keys or limit is not None:
+                raise ValueError(
+                    "ORDER BY / LIMIT inside a subquery is not supported")
+            return self._finish_subquery(sub, name, df, items, group_exprs,
+                                         having, distinct, corr)
         df = self._apply_projection(df, items, group_exprs, having,
                                     order_keys, desc, nf, distinct)
         if limit is not None:
             df = df.limit(limit)
+        return df
+
+    # -- subqueries (sql_subquery.py makes the joins) -------------------------
+    def _subquery(self, kind: str, lhs: Optional[Expression] = None) -> Expression:
+        """The parser stands at the SELECT of ``EXISTS (``, ``IN (`` or
+        ``(``. Plans the subquery with the enclosing WHERE's columns in
+        sight and returns the placeholder column that stands for it in the
+        predicate until ``_where`` turns its conjunct into a join."""
+        if self._clause != "where":
+            raise ValueError(
+                f"a subquery in {_NO_SUBQUERY[self._clause]} is not "
+                "supported: only as a conjunct of WHERE")
+        name = f"__sq{self.counters['sql_subqueries']}"
+        self.counters["sql_subqueries"] += 1
+        if kind == "scalar":
+            self.counters["sql_scalar_subqueries"] += 1
+        # the subquery's own table aliases end with it
+        saved = self.catalog, self._alias_cols, self._own
+        self.catalog, self._alias_cols = dict(saved[0]), dict(saved[1])
+        self._own = set()
+        try:
+            sq = self._select_stmt(kind, name)
+        finally:
+            self.catalog, self._alias_cols, self._own = saved
+        self.expect_op(")")
+        sq.lhs = lhs
+        self._subs[name] = sq
+        return col(name)
+
+    def _qualified(self, qualifier: str, column: str) -> None:
+        """Columns go by bare name in a plan, so inside a subquery an outer
+        table's ``o.x`` cannot be told from the subquery's own ``x``."""
+        if self._own is not None and qualifier.lower() not in self._own \
+                and column in self._scope:
+            raise ValueError(
+                f"{qualifier}.{column} names a column of the outer query "
+                f"that the subquery's own FROM has too: correlation between "
+                "two instances of one column is not supported")
+
+    def _finish_subquery(self, kind: str, name: str, df, items, group_exprs,
+                         having, distinct: bool, corr):
+        from .expressions import AggExpr
+        from .sql_subquery import Subquery
+
+        def project(items, group_exprs, having):
+            return self._apply_projection(df, items, group_exprs, having,
+                                          [], [], [], distinct)
+
+        aggregates = group_exprs is not None or having is not None or any(
+            it.expr is not None and _is_agg_tree(it.expr._node)
+            for it in items)
+        if kind == "exists":
+            if aggregates:
+                raise ValueError("EXISTS over GROUP BY / HAVING / aggregates "
+                                 "is not supported")
+            if not corr:
+                raise ValueError(
+                    "an uncorrelated EXISTS is not supported: correlate it "
+                    "(WHERE inner = outer)")
+            return Subquery(kind, name, df, corr)
+        if len(items) != 1 or items[0].star:
+            raise ValueError(f"a subquery after {kind.upper()} has to select "
+                             "exactly one column" if kind == "in" else
+                             "a scalar subquery has to select exactly one "
+                             "value")
+        if kind == "in":
+            if corr:
+                raise ValueError("a correlated IN (SELECT ...) is not "
+                                 "supported: use EXISTS")
+            return Subquery(kind, name, project(items, group_exprs, having), [])
+        expr = items[0].expr
+        if group_exprs is not None or having is not None \
+                or not _is_agg_tree(expr._node):
+            raise ValueError(
+                "a scalar subquery that can return more than one row is not "
+                "supported: it has to select one aggregate, with no GROUP BY "
+                "or HAVING")
+        if not corr:
+            return Subquery(kind, name, project(
+                [_SelectItem(expr=expr, alias=name)], None, None), [])
+
+        def counts(node) -> bool:
+            return (isinstance(node, AggExpr) and "count" in node.kind) or any(
+                counts(c) for c in node.children())
+
+        if counts(expr._node):
+            raise ValueError(
+                "a correlated scalar COUNT subquery is not supported: a row "
+                "without matches counts 0, which needs an outer join")
+        keys = [col(i) for i, _ in corr]
+        return Subquery(kind, name, project(
+            [_SelectItem(expr=k, alias=f"{name}_k{n}")
+             for n, k in enumerate(keys)]
+            + [_SelectItem(expr=expr, alias=name)], keys, None), corr)
+
+    def _where(self, df, factors, outer: Optional[List[str]], corr):
+        """``df`` under its WHERE clause. A conjunct that holds a subquery
+        becomes a join (on the one comma factor of FROM that has every outer
+        column it needs, else on the whole), a conjunct that names a column
+        of ``outer`` (this SELECT is a subquery) goes to ``corr`` and not
+        into the filter."""
+        from .logical import expr_input_columns
+        from .optimizer import _and_all, _split_conjuncts
+        from .sql_subquery import apply_conjunct, correlation, outer_columns
+
+        saved = self._scope, self._subs
+        self._clause, self._scope, self._subs = "where", df.column_names, {}
+        try:
+            # a qualifier no table of this FROM answers to is an error here
+            pred = Expression(self._resolve_qualified(self.parse_expr()._node))
+            subs = self._subs
+        finally:
+            self._scope, self._subs = saved
+        if not subs and outer is None:
+            return df.where(pred)
+        inner = set(df.column_names)
+        plain: List[Expression] = []
+        joined: List[Expression] = []
+        for c in _split_conjuncts(pred):
+            cols = expr_input_columns(c)
+            if any(x in subs for x in cols):
+                joined.append(c)
+            elif outer is not None and any(
+                    x not in inner and x in outer for x in cols):
+                corr.append(correlation(c, inner, set(outer)))
+            else:
+                plain.append(c)
+        with self.profiler.span("sql.decorrelate", kind="phase"):
+            on_top = []
+            moved = False
+            for c in joined:
+                need = outer_columns(c, subs)
+                at = next((i for i, f in enumerate(factors or [])
+                           if all(x in f.column_names for x in need)), None)
+                if at is None:
+                    on_top.append(c)
+                else:
+                    factors[at] = apply_conjunct(factors[at], c, subs)
+                    moved = True
+            if moved:
+                df = factors[0]
+                for f in factors[1:]:
+                    df = df.join(f, how="cross")
+            if plain:
+                df = df.where(_and_all(plain))
+            for c in on_top:
+                df = apply_conjunct(df, c, subs)
         return df
 
     def _select_list(self) -> List[_SelectItem]:
@@ -616,9 +829,14 @@ class QueryPlanner(Parser):
                 return items
 
     def _from_clause(self):
+        """``(df, factors)``: the FROM clause planned, and its comma-separated
+        factors when it is nothing but those and no two share a column name
+        (then ``df`` is their cross join, column for column), else None."""
         df, alias = self._table_factor()
         self._register_alias(alias, df)
+        factors: Optional[list] = [df]
         while True:
+            comma = False
             if self.eat_kw("CROSS"):
                 self.expect_kw("JOIN")
                 how = "cross"
@@ -633,12 +851,17 @@ class QueryPlanner(Parser):
             elif self.eat_kw("JOIN"):
                 how = "inner"
             elif self.eat_op(","):
-                how = "cross"
+                how, comma = "cross", True
             else:
-                return df
+                return df, factors
             right, ralias = self._table_factor()
             self._register_alias(ralias, right)
             pre_left = set(df.column_names)
+            if comma and factors is not None \
+                    and pre_left.isdisjoint(right.column_names):
+                factors.append(right)
+            else:
+                factors = None
             if how == "cross":
                 df = df.join(right, how="cross")
                 self._remap_right_alias(ralias, right, pre_left, {})
@@ -670,7 +893,12 @@ class QueryPlanner(Parser):
 
     def _table_factor(self):
         if self.eat_op("("):
-            sub = self._select_stmt()
+            saved = self._scope, self._subs  # a derived table sees no outer
+            self._scope, self._subs = [], {}
+            try:
+                sub = self._select_stmt()
+            finally:
+                self._scope, self._subs = saved
             self.expect_op(")")
             alias = self._opt_alias()
             return sub, alias
@@ -711,6 +939,11 @@ class QueryPlanner(Parser):
 
     def _register_alias(self, alias: Optional[str], df) -> None:
         if alias:
+            if self._own is not None:
+                # a subquery's alias shadows an outer one of the same name
+                # (both maps are the subquery's own copies)
+                self._own.add(alias.lower())
+                self._alias_cols.pop(alias.lower(), None)
             self.catalog.setdefault(alias.lower(), df)
             self._alias_cols.setdefault(
                 alias.lower(), {c: c for c in df.column_names})
@@ -961,7 +1194,24 @@ def sql(query: str, **catalog):
     """Plan a SQL query over registered DataFrames: sql("SELECT ...", tbl=df)."""
     if not catalog:
         raise ValueError("register at least one table: sql(query, name=df)")
-    return QueryPlanner(tokenize(query), catalog).parse_query()
+    from .execution import RuntimeStats
+    from .profile import arm_for_query
+
+    # the DataFrame's own stats, from here on: the front end's spans (when a
+    # profile or a device trace is wanted) and counters are the query's
+    stats = RuntimeStats()
+    arm_for_query(stats, f"sql-{id(stats):x}")
+    prof = stats.profiler
+    t0 = time.perf_counter_ns()
+    with prof.span("sql.parse", kind="phase"):
+        tokens = tokenize(query)
+    planner = QueryPlanner(tokens, catalog, prof)
+    with prof.span("sql.plan", kind="phase"):  # sql.decorrelate nests in it
+        df = planner.parse_query()
+    df.stats = stats
+    stats.bump_many({"sql_plan_ns": time.perf_counter_ns() - t0,
+                     **planner.counters})
+    return df
 
 
 def sql_expr(text: str) -> Expression:

@@ -5,8 +5,10 @@ MEASURED, not guessed (ISSUE 3 satellite / VERDICT item 3).
 dt.sql() plans (schema inference included) without executing, so this pins
 exactly which query shapes the SQL frontend accepts today. Unsupported
 queries are STRICT xfails with the missing feature named: when the frontend
-grows (scalar/EXISTS/IN subqueries, WITH, strftime, outer-join non-equi
-conditions), the xpass flips loudly and the marker must be removed.
+grows (WITH, strftime, outer-join non-equi conditions, subqueries in HAVING,
+NOT IN, correlation by a non-equality), the xpass flips loudly and the marker
+must be removed. PR 38 took 2, 4, 17, 18, 20 and 22 off the list (EXISTS, IN
+and scalar subqueries as conjuncts of WHERE: tests/test_sql_subquery.py).
 """
 
 import pytest
@@ -16,20 +18,14 @@ from benchmarks import tpch_full, tpch_queries
 
 # why each unsupported query fails to plan today
 UNSUPPORTED = {
-    2: "correlated scalar subquery (= (SELECT MIN(...)))",
-    4: "EXISTS subquery",
     7: "strftime() over date columns",
     8: "strftime() over date columns",
     9: "strftime() over date columns",
     11: "scalar subquery in HAVING",
     13: "non-equi condition in OUTER JOIN ON clause",
     15: "WITH (common table expression)",
-    16: "IN (SELECT ...) subquery",
-    17: "correlated scalar subquery",
-    18: "IN (SELECT ...) subquery",
-    20: "IN (SELECT ...) subquery",
-    21: "EXISTS/NOT EXISTS subqueries",
-    22: "scalar subquery + NOT EXISTS",
+    16: "NOT IN (SELECT ...): not an anti join when a NULL is about",
+    21: "EXISTS correlated by a non-equality (l2.l_suppkey <> l1.l_suppkey)",
 }
 
 
@@ -50,7 +46,7 @@ def test_tpch_sql_parses(qn, catalog, request):
 
 
 def test_supported_breadth_floor():
-    """At least 8 of the 22 official texts must keep planning — a frontend
+    """At least 14 of the 22 official texts must keep planning — a frontend
     regression below this floor fails loudly even if individual xfail
     markers drift."""
     data = tpch_full.generate(scale=0.001, seed=7)
@@ -62,7 +58,7 @@ def test_supported_breadth_floor():
             ok.append(qn)
         except Exception:  # noqa: BLE001
             pass
-    assert len(ok) >= 8, f"SQL frontend breadth regressed: only {ok} parse"
+    assert len(ok) >= 14, f"SQL frontend breadth regressed: only {ok} parse"
 
 
 def test_repeated_sql_calls_stay_callable():
