@@ -6,7 +6,8 @@ entry points a user calls (``set_execution_config``, ``from_arrow``,
 the mesh runner) over TPC-H at ``--scale`` (SF1 by default: 6.0M lineitem,
 1.5M orders, 150k customer rows, generated from ``--seed``), in 32-bit mode
 with the device path switched on. Every answer is compared with the
-``pyarrow.compute`` oracles of ``benchmarks/tpch.py`` at rtol 1e-6, and every
+``pyarrow.compute`` oracles of ``benchmarks/tpch.py`` (Q17 and Q18 with
+``chipbench/queries``' references) at rtol 1e-6, and every
 leg fails if any fallback, breaker, degraded or device-error counter moved:
 on the chip a kernel the compiler refuses still gives the right answer from
 the host path, and only the counters show it.
@@ -54,14 +55,15 @@ LAYER_COUNTERS = (
 def layer_times(counters: dict) -> str:
     """``plan 1ms stage 12ms ... staged 3.1MB in 2 columns ... compiles 0
     (0ms) cache loads 0 dict lookups 1 packed 0 gathered agg reduce 1 dense
-    0 kernel probe levels 56 compared 17 gathered sql plan 1.2ms 2
+    0 kernel 0 sorted probe levels 56 compared 17 gathered sql plan 1.2ms 2
     subqueries 1 scalar subquery joins 1 (1 on the device)``: the layer
     counters of one query, for its printed line. A
     warm query that stages columns lost its stage cache; one that compiles
     (and for how long) met a shape the warm-up did not; one that gathers a
     dictionary predicate met a dictionary over ``DICT_PACKED_MAX_ENTRIES``;
     one whose aggregate took the kernel grouped into a bucket over
-    ``DENSE_MAX_SEGMENTS``; the levels of its join probes' searches that
+    ``DENSE_MAX_SEGMENTS``, one whose float sums took the sorted form into
+    one over 4096; the levels of its join probes' searches that
     gathered are those beyond ``PROBE_COMPARE_LEVELS`` of each build; a query
     that came as SQL text says what its front end took and what its
     subqueries became (0 throughout for one built through the API)."""
@@ -77,6 +79,7 @@ def layer_times(counters: dict) -> str:
                  f"{counters.get('dict_lookup_gather', 0)} gathered "
                  f"agg reduce {counters.get('agg_reduce_dense', 0)} dense "
                  f"{counters.get('agg_reduce_kernel', 0)} kernel "
+                 f"{counters.get('agg_reduce_sorted', 0)} sorted "
                  f"probe levels {counters.get('join_probe_compare_levels', 0)} "
                  f"compared {counters.get('join_probe_gather_levels', 0)} "
                  f"gathered "
@@ -294,6 +297,40 @@ class Smoke:
         leg.note(f"exists sql: {layer_times(c)}")
         return leg.finish()
 
+    # ------------------------------------------------------------ subquery
+    def leg_subquery(self) -> bool:
+        """TPC-H Q17 and Q18 from their SQL text, over chipbench's tables for
+        them (PART and ``c_name`` are theirs) and against its references:
+        float sums over one group a part key and one an order key, the
+        sorted-segment form wherever that is over 4096 groups."""
+        from importlib import import_module
+
+        leg = Leg("subquery")
+        queries = {n: import_module(f"chipbench.queries.{n}")
+                   for n in ("sql17", "sql18")}
+        columns: dict = {}
+        for q in queries.values():
+            for table, cols in q.COLUMNS.items():
+                columns[table] = sorted({*columns.get(table, ()), *cols})
+        tables = import_module("chipbench.datasets.tpch_sql").generate(
+            self.args.scale, self.args.seed, columns)
+        frames = {t: self.dt.from_arrow(a).collect() for t, a in tables.items()}
+        groups = {"sql17": tables["part"].num_rows,
+                  "sql18": tables["orders"].num_rows}
+        for name, q in queries.items():
+            want = q.reference(tables)
+            (got, _), cold = timed(lambda: run_query(lambda: q.build(frames)))
+            (got2, c), warm = timed(lambda: run_query(lambda: q.build(frames)))
+            leg.check(self.tpch.parity(got, want, self.rtol)
+                      and self.tpch.parity(got2, want, self.rtol),
+                      f"{name} != reference")
+            leg.counters_clean(
+                f"{name} run 2", c, device_aggregations=1,
+                agg_reduce_sorted=int(groups[name] > 4096))
+            leg.note(f"{name}: smoke timing cold {cold:.2f}s warm {warm:.2f}s")
+            leg.note(f"{name} warm: {layer_times(c)}")
+        return leg.finish()
+
     # ---------------------------------------------------------------- scan
     def leg_scan(self) -> bool:
         import pyarrow.parquet as papq
@@ -503,8 +540,8 @@ def main(argv=None) -> int:
         enable_result_cache=False)
 
     smoke = Smoke(args, jax)
-    legs = [smoke.leg_resident, smoke.leg_sql, smoke.leg_scan,
-            smoke.leg_serving, smoke.leg_resize]
+    legs = [smoke.leg_resident, smoke.leg_sql, smoke.leg_subquery,
+            smoke.leg_scan, smoke.leg_serving, smoke.leg_resize]
     if device["count"] >= 2:
         legs.append(smoke.leg_mesh)
     failed = []

@@ -2689,7 +2689,8 @@ def segment_aggregate(values: jax.Array, valid: jax.Array, codes: jax.Array,
 # Up to this many segments, the one-hot compare-reduce formulation beats the
 # scatter-based segment_sum by ~1000x on TPU (measured on v5e: the compare,
 # mask and reduction fuse into one HBM-bandwidth pass; XLA's scatter path does
-# not). Beyond it, fall back to scatter.
+# not). Beyond it: scatter for counts, integer sums, min and max, and the
+# sorted-segment form (``_sorted_segment_sum``) for float sums.
 _ONEHOT_MAX_SEGMENTS = 4096
 _REDUCE_CHUNK = 8192
 
@@ -2715,8 +2716,11 @@ def segment_reduce(values: jax.Array, valid: jax.Array, codes: jax.Array,
     Kahan-compensated cross-chunk combine for float sums (accumulation error
     stays at the float32 representation floor, ~5e-8 relative, instead of
     growing with rows — required for TPC-H money-sum parity in 32-bit mode).
-    High-cardinality strategy: scatter segment ops (chunked+compensated for
-    float sums)."""
+    High-cardinality strategy (over ``_ONEHOT_MAX_SEGMENTS``): scatter
+    segment ops, and for float sums the sorted-segment form
+    (``_sorted_segment_sum``): time and memory grow with rows + segments and
+    the error with the logarithm of a group's rows. ``codes`` lie in
+    ``[0, num_segments)``."""
     if kind == "count":
         cnt = _segment_count(valid, codes, num_segments)
         return cnt, jnp.ones(num_segments, dtype=bool)
@@ -2725,7 +2729,7 @@ def segment_reduce(values: jax.Array, valid: jax.Array, codes: jax.Array,
     elif num_segments <= _ONEHOT_MAX_SEGMENTS and values.ndim == 1:
         out = _onehot_reduce(values, valid, codes, num_segments, kind)
     elif kind == "sum" and jnp.issubdtype(values.dtype, jnp.floating) and values.ndim == 1:
-        out = _scatter_sum_kahan(jnp.where(valid, values, 0), codes, num_segments)
+        out = _sorted_segment_sum(jnp.where(valid, values, 0), codes, num_segments)
     else:
         out = _segment_agg(values, valid, codes, num_segments, kind)
     counts = _segment_count(valid, codes, num_segments)
@@ -2866,14 +2870,37 @@ def _onehot_reduce(values, valid, codes, num_segments, kind):
     raise ValueError(kind)
 
 
-def _scatter_sum_kahan(values, codes, num_segments):
+def _sorted_segment_sum(values, codes, num_segments):
+    """Float sums of pre-masked ``values`` by segment for a bucket too wide
+    for a one-hot block: sort the (code, value) pairs by code, scan each run
+    of equal codes by doubling (lane i adds lane i - d where both hold one
+    code, d = 1, 2, 4, ... until no lane adds), and write each run's last
+    lane to its code. One sort, a round for each doubling of the longest
+    group and one scatter: rows + segments in time and memory, where a row
+    of partials a chunk of rows took rows / 8192 x segments (8 GiB for
+    TPC-H Q18's 1.5M order keys at SF1). Every lane's sum is a tree over its
+    group, so the float32 error grows with the logarithm of a group's rows.
+    XLA's own cumulative sums compile erratically here and a prefix sum
+    differenced at the run ends would lose the precision."""
     b = values.shape[0]
-    chunk = min(_REDUCE_CHUNK, b)
-    nch = b // chunk
-    partials = jax.vmap(
-        lambda vv, cd: jax.ops.segment_sum(vv, cd, num_segments))(
-        values.reshape(nch, chunk), codes.reshape(nch, chunk))
-    return _kahan_combine(partials)
+    sc, sv = jax.lax.sort((codes, values), num_keys=1, is_stable=False)
+
+    def back(x, d, fill):
+        # x[i - d], ``fill`` before the first lane; the pad fuses into the
+        # slice's consumer and is never written out
+        padded = jnp.concatenate([jnp.full((b,), fill, x.dtype), x])
+        return jax.lax.dynamic_slice(padded, (b - d,), (b,))
+
+    def double(state):
+        d, s, _ = state
+        same = back(sc, d, -1) == sc
+        return d * 2, s + jnp.where(same, back(s, d, 0), 0), jnp.any(same)
+
+    _, s, _ = jax.lax.while_loop(lambda state: state[2], double,
+                                 (jnp.int32(1), sv, jnp.bool_(True)))
+    last = jnp.concatenate([sc[1:] != sc[:-1], jnp.ones((1,), bool)])
+    return jnp.zeros((num_segments,), values.dtype).at[
+        jnp.where(last, sc, num_segments)].set(s, mode="drop")
 
 
 # ---------------------------------------------------------------------------

@@ -463,14 +463,17 @@ class _ExprView:
 def _sum_form(gb: int, use_pallas: bool) -> str:
     """The form an aggregate program's float sums take over a bucket of
     ``gb`` segments: "dense" (per-group masked reductions, every small
-    bucket), "kernel" (the batched pallas one-hot matmul: 32-bit mode, up to
-    segment_reduce's one-hot cap, past which even a one-lane-tile one-hot
-    block outgrows VMEM) or "segment" (segment_reduce's own routes)."""
+    bucket), "sorted" (segment_reduce's sorted-segment sums: every bucket
+    over its one-hot cap, past which even a one-lane-tile one-hot block
+    outgrows VMEM), "kernel" (the batched pallas one-hot matmul: 32-bit
+    mode, between the two) or "onehot" (segment_reduce's one-hot form)."""
     if gb <= DENSE_MAX_SEGMENTS:
         return "dense"
-    if use_pallas and not x64_enabled() and gb <= _ONEHOT_MAX_SEGMENTS:
+    if gb > _ONEHOT_MAX_SEGMENTS:
+        return "sorted"
+    if use_pallas and not x64_enabled():
         return "kernel"
-    return "segment"
+    return "onehot"
 
 
 def launch_agg(child_nodes, pred_node, schema, input_names, kinds, modes,
@@ -479,8 +482,9 @@ def launch_agg(child_nodes, pred_node, schema, input_names, kinds, modes,
     """Compile (once a plan shape and segment bucket) and launch the fused
     aggregate program over staged inputs; returns its device outputs
     without waiting. Shared by the staged path and the resident segment
-    runtime (fuse/segment.py). Bumps ``agg_reduce_dense`` or
-    ``agg_reduce_kernel`` by the form the program's float sums take."""
+    runtime (fuse/segment.py). Bumps ``agg_reduce_dense``,
+    ``agg_reduce_kernel`` or ``agg_reduce_sorted`` by the form the program's
+    float sums take (the one-hot form has no counter)."""
     # static segment bucket: the power of two over the groups (seven sum
     # columns over 64M rows: 4.6 ms at 2 and 4, 5.3 at 8, 8.6 at 16, 20.9 at
     # 32; tools/segment_sum_sweep.py), and never one (device._dense_hits)
@@ -488,7 +492,7 @@ def launch_agg(child_nodes, pred_node, schema, input_names, kinds, modes,
     run = _compile_agg(child_nodes, pred_node, schema, input_names, kinds,
                        modes, gb, use_pallas)
     form = _sum_form(gb, use_pallas)
-    if form == "dense" or (form == "kernel" and any(
+    if form == "dense" or (form in ("kernel", "sorted") and any(
             kind in ("sum", "mean") and nd.to_field(schema).dtype.is_floating()
             for nd, kind in zip(child_nodes, kinds))):
         timeline.add(f"agg_reduce_{form}", 1)

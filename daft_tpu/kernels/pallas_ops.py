@@ -10,7 +10,9 @@ K times.
 Which buckets it serves: a fused aggregate program (device_agg._compile_agg)
 batches its float sums here in 32-bit mode when its segment bucket is over
 ``device.DENSE_MAX_SEGMENTS`` (32) and at most ``_ONEHOT_MAX_SEGMENTS``
-(4096). At or under the dense bound the compiler's own code is faster: the
+(4096); over that a one-hot block of even one lane tile outgrows VMEM and
+the sums take ``device._sorted_segment_sum`` (sort, segmented scan, one
+scatter). At or under the dense bound the compiler's own code is faster: the
 kernel's cost is flat in the group count (one matmul against a 128-lane
 one-hot tile at ``Precision.HIGHEST`` a block of rows, whatever the groups),
 42-59 ms over 64M rows for one to seven columns, where per-group masked
@@ -86,8 +88,8 @@ def _kernel(codes_ref, vals_ref, out_ref, comp_ref, *, num_groups: int):
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     # Kahan-compensated accumulation ACROSS grid steps: naive float32 adds
-    # drift past 1e-6 relative on TPC-H-scale money sums (the segment_sum
-    # route this kernel replaces compensates too, device.py _sum_kahan)
+    # drift past 1e-6 relative on TPC-H-scale money sums (the one-hot
+    # route this kernel replaces compensates too, device.py _kahan_combine)
     y = block - comp_ref[:]
     t = out_ref[:] + y
     comp_ref[:] = (t - out_ref[:]) - y

@@ -677,6 +677,87 @@ def _agg_forms(df):
     return c.get("agg_reduce_dense", 0), c.get("agg_reduce_kernel", 0)
 
 
+def _sorted_sum_case(case, rows, segments, rng):
+    """(codes, values, valid) of one shape of input to the sorted form."""
+    codes = rng.randint(0, segments, rows).astype(np.int32)
+    values = rng.uniform(900.0, 105000.0, rows).astype(np.float32)
+    valid = rng.rand(rows) < 0.9
+    if case == "one_group_holds_half":
+        codes[rng.rand(rows) < 0.5] = 5
+    elif case == "segments_with_no_row":
+        codes[codes % 3 == 1] -= 1  # a third of the bucket is never named
+    elif case == "group_all_masked":
+        valid[codes == 7] = False
+    elif case == "nan_under_the_mask":
+        values[~valid] = np.nan
+    return codes, values, valid
+
+
+class TestSortedSegmentSum32:
+    """A float sum over more than 4096 segments sorts the (code, value)
+    pairs and scans each run of equal codes by doubling
+    (kernels/device._sorted_segment_sum): within 1e-6 of a float64 sum
+    whatever a group's share of the rows, masked rows (NaN among them)
+    adding nothing, a segment with no valid row reported invalid."""
+
+    @pytest.mark.parametrize("segments", [8192, 1 << 17])
+    @pytest.mark.parametrize("case", [
+        "uniform", "one_group_holds_half", "segments_with_no_row",
+        "group_all_masked", "nan_under_the_mask"])
+    def test_sums_against_float64_bincount(self, case, segments):
+        import jax.numpy as jnp
+
+        from daft_tpu.kernels import device as dev
+
+        rows = 1 << 18
+        assert segments > dev._ONEHOT_MAX_SEGMENTS
+        codes, values, valid = _sorted_sum_case(
+            case, rows, segments, np.random.RandomState(segments % 1000))
+        got, got_valid = dev.segment_reduce(
+            jnp.asarray(values), jnp.asarray(valid), jnp.asarray(codes),
+            segments, "sum")
+        assert got.dtype == jnp.float32 and got.shape == (segments,)
+        want = np.bincount(codes[valid],
+                           weights=values[valid].astype(np.float64),
+                           minlength=segments)
+        counts = np.bincount(codes[valid], minlength=segments)
+        np.testing.assert_array_equal(np.asarray(got_valid), counts > 0)
+        got = np.asarray(got, np.float64)
+        assert (got[counts == 0] == 0.0).all()
+        gap = np.abs(got - want) / np.maximum(np.abs(want), 1e-300)
+        assert gap.max() <= 1e-6, (gap.max(), int(gap.argmax()))
+        if case == "one_group_holds_half":
+            assert counts[5] > rows // 3 and gap[5] <= 1e-6
+
+    def test_mean_over_many_groups_takes_the_sorted_form(self):
+        import pyarrow as pa
+
+        rng = np.random.RandomState(39)
+        groups, n = 6000, 40000
+        g = np.concatenate([np.arange(groups),
+                            rng.randint(0, groups, n - groups)])
+        x = rng.uniform(1.0, 50.0, n)
+        null = rng.rand(n) < 0.05
+        # one partition, no projection under the aggregate: the staged path
+        # (device_agg.device_grouped_agg_async)
+        out = (dt.from_arrow(pa.table({"g": pa.array(g.astype(np.int32)),
+                                       "x": pa.array(x, mask=null)}))
+               .groupby("g").agg(col("x").mean().alias("m"),
+                                 col("x").count().alias("c"))
+               .sort("g").collect())
+        c = _counters(out)
+        assert c.get("device_aggregations", 0) >= 1
+        assert c.get("agg_reduce_sorted") == 1 and _agg_forms(out) == (0, 0)
+        got = out.to_pydict()
+        keep = ~null
+        want_c = np.bincount(g[keep], minlength=groups)
+        want_m = np.bincount(g[keep], weights=x[keep],
+                             minlength=groups) / want_c
+        assert got["g"] == list(range(groups))
+        assert got["c"] == want_c.tolist()
+        np.testing.assert_allclose(got["m"], want_m, rtol=1e-6)
+
+
 def _money_frame(groups, n=65536, seed=32):
     """64k rows of money-sized float64 values with nulls, keyed 0..groups-1;
     returns (pydict for the engine, numpy views for the reference)."""

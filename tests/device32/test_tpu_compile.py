@@ -8,7 +8,9 @@ the groups did: 10 GB of temporaries; one variadic reduce did not fit the
 chip at all). The programs below are Q1's and Q6's shape through the
 program's own ``segment_reduce``; the compiler's own account of their
 temporaries is the guard. The join probe's search (PR 35) is held the same
-way at ``tpch1-join``'s Q5 shape. No time is read here.
+way at ``tpch1-join``'s Q5 shape, and the float sum over more than 4096
+groups (PR 39) at an eighth of ``tpch1-sql-subquery``'s Q18. No time is read
+here.
 """
 
 import pytest
@@ -116,3 +118,27 @@ def test_join_probe_search_keeps_one_probe_sized_temporary(one_chip):
                         [jnp.int32, jnp.bool_, jnp.int32, jnp.bool_],
                         rows=[b, b, p, p])
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 4 * p
+
+
+def test_float_sum_over_many_groups_grows_with_rows_plus_groups(one_chip):
+    import jax.numpy as jnp
+
+    from daft_tpu.kernels import device as dev
+
+    # an eighth of Q18's SUM(l_quantity) GROUP BY l_orderkey at SF1 (8M
+    # lanes, a 2M-segment bucket), to keep the compile short. A row of
+    # partials a chunk of 8192 rows asked for 128 x 262144 floats here, 128
+    # MiB and its Kahan carries (8.06 GiB at Q18's shape); the sorted form
+    # keeps a few row-sized arrays (32.1 MiB at Q18's shape)
+    rows, groups = 1 << 20, 1 << 18
+    assert groups > dev._ONEHOT_MAX_SEGMENTS
+
+    def q18(qty, valid, codes):
+        return (dev.segment_reduce(qty, valid, codes, groups, "sum"),
+                dev.segment_reduce(valid, valid, codes, groups, "count")[0])
+
+    compiled = _compile(q18, one_chip, [jnp.float32, jnp.bool_, jnp.int32],
+                        rows=[rows] * 3)
+    text = compiled.as_text()
+    assert f"f32[{rows // 8192},{groups}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

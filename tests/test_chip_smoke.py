@@ -22,7 +22,7 @@ from daft_tpu import col, faults
 from daft_tpu.context import get_context
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-LEGS = ("resident", "sql", "scan", "serving", "resize", "mesh")
+LEGS = ("resident", "sql", "subquery", "scan", "serving", "resize", "mesh")
 
 
 def _run(args, cwd=REPO, env_extra=None, timeout=600):

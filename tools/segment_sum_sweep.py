@@ -1,12 +1,15 @@
-"""segment-sum-sweep: the measurement behind ``DENSE_MAX_SEGMENTS``.
+"""segment-sum-sweep: the measurement behind ``DENSE_MAX_SEGMENTS`` and
+behind the sorted-segment form over ``_ONEHOT_MAX_SEGMENTS``.
 
     python tools/segment_sum_sweep.py [--rows 131072,67108864]
         [--groups 1,2,...] [--columns 1,7] [--out chiprun_out/...jsonl]
+    python tools/segment_sum_sweep.py --forms scatter,sorted --columns 1
+        --rows 32768,8388608 --groups 8192,32768,262144,2097152
 
 For each row count, segment bucket and number of value columns it times one
 jitted consumer, the masked float32 sums and int32 counts a fused aggregate
 program takes over ``--columns`` value columns, with the sums in each of the
-three forms the program can give them:
+forms the program can give them, and in the one it gave them before:
 
 - ``dense``: ``daft_tpu/kernels/device._dense_reduce`` (per-group masked
   reductions, the rows on the lane axis, pairwise combine);
@@ -14,12 +17,22 @@ three forms the program can give them:
   the Kahan scan over the chunks);
 - ``kernel``: ``pallas_ops.segment_sums_lanes`` over the stacked, pre-masked
   columns, the counts in the one-hot form, as ``device_agg._compile_agg``
-  batches them above the bound.
+  batches them above the bound;
+- ``sorted``: ``_sorted_segment_sum`` (one sort of the (code, value) pairs,
+  a scan of each run of equal codes by doubling, one scatter of the runs'
+  last lanes): what ``segment_reduce`` gives a float sum over a bucket of
+  more than 4096 segments since PR 39;
+- ``scatter``: what it gave there before, kept here to be measured against:
+  a ``segment_sum`` a chunk of 8192 rows into a ``(rows / 8192, G)`` array
+  of partials and a Kahan scan over its rows (8 GiB at 8M rows and 2M
+  segments: ask for it only where it fits).
 
 ``dense`` and ``onehot`` go through the program's own ``segment_reduce``; the
 form is forced by moving the bound round the bucket while the consumer is
-traced. One line of JSON a point: ``<form>_ms`` (median of ``--reps`` calls
-after one warm call, each ending in ``block_until_ready``),
+traced. ``--skew S`` gives group 0 the share ``S`` of the rows (the sorted
+form's rounds grow with the logarithm of the longest group). One line of
+JSON a point: ``<form>_ms`` (median of ``--reps`` calls after one warm call,
+each ending in ``block_until_ready``),
 ``<form>_compile_s`` (the first call: compile + one run), ``<form>_rel_err``
 (the widest gap of any sum from a float64 host sum, as a share of it) and the
 device. Counts must be exact and every gap at or under 1e-6, else exit 1.
@@ -45,8 +58,21 @@ if ROOT not in sys.path:
 DEFAULT_ROWS = (1 << 17, 1 << 26)
 DEFAULT_GROUPS = (1, 2, 4, 8, 16, 32, 64, 128, 1024)
 DEFAULT_COLUMNS = (1, 7)
-FORMS = ("dense", "onehot", "kernel")
+FORMS = ("dense", "onehot", "kernel")  # the default; also: scatter, sorted
 REL_ERR_LIMIT = 1e-6
+
+
+def scatter_sum_kahan(values, codes, num_segments):
+    """``device._scatter_sum_kahan`` as it stood to PR 38."""
+    import jax
+
+    from daft_tpu.kernels import device as dev
+
+    chunk = min(dev._REDUCE_CHUNK, values.shape[0])
+    partials = jax.vmap(
+        lambda vv, cd: jax.ops.segment_sum(vv, cd, num_segments))(
+        values.reshape(-1, chunk), codes.reshape(-1, chunk))
+    return dev._kahan_combine(partials)
 
 
 def build_consumer(form: str, groups: int):
@@ -66,6 +92,11 @@ def build_consumer(form: str, groups: int):
             vk = jnp.stack([jnp.where(valid, v, 0.0) for v in columns])
             sums = pallas_ops.segment_sums_lanes(
                 codes[None, :], vk, groups, jax.default_backend() == "cpu")
+        elif form in ("scatter", "sorted"):
+            one = (scatter_sum_kahan if form == "scatter"
+                   else dev._sorted_segment_sum)
+            sums = jnp.stack([one(jnp.where(valid, v, 0), codes, groups)
+                              for v in columns])
         else:
             sums = jnp.stack([
                 dev.segment_reduce(v, valid, codes, groups, "sum")[0]
@@ -108,6 +139,8 @@ def main(argv=None) -> int:
     ap.add_argument("--forms", default=",".join(FORMS))
     ap.add_argument("--reps", type=int, default=7)
     ap.add_argument("--seed", type=int, default=32)
+    ap.add_argument("--skew", type=float, default=0.0,
+                    help="share of the rows given to group 0")
     ap.add_argument("--out", default=None,
                     help="also append every line to this file")
     args = ap.parse_args(argv)
@@ -135,6 +168,8 @@ def main(argv=None) -> int:
         dev_valid = jnp.asarray(host_valid)
         for groups in (int(g) for g in args.groups.split(",")):
             host_codes = rng.integers(0, groups, rows, dtype=np.int32)
+            if args.skew:
+                host_codes[rng.random(rows) < args.skew] = 0
             dev_codes = jnp.asarray(host_codes)
             want_counts = np.bincount(host_codes[host_valid],
                                       minlength=groups)
@@ -144,7 +179,7 @@ def main(argv=None) -> int:
                 for c in host_cols]
             for k in columns_asked:
                 line = {"rows": rows, "groups": groups, "columns": k,
-                        "device": device}
+                        "skew": args.skew, "device": device}
                 call = (dev_codes, dev_valid, *dev_cols[:k])
                 for form in forms:
                     ms, first_s, (sums, counts) = timed_ms(
