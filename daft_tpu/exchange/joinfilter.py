@@ -26,10 +26,15 @@ Design contract:
   ``join.filter`` fault site) degrades to the unfiltered exchange — never
   a query failure.
 
-The probe has a vectorized host numpy path; when device kernels are
-enabled and the partition clears ``device_min_rows``, the Bloom gathers
-run as one jit program behind the device circuit breaker
-(``probe_bits_device``), with the host path as the breaker fallback.
+The probe has a vectorized host numpy path (min-max, then the Bloom bits
+over hashes of the keys). A partition of at least ``device_min_rows`` rows
+whose one key is an integer or a date takes the device path instead, behind
+the device circuit breaker with the host path as its fallback: one jit
+program reads the key's staged lanes (a resident key column is read from
+the partition's stage cache, neither hashed nor uploaded) against a bit
+table of the build keys addressed directly by ``key - lo``
+(``_lane_table``: exact). A build whose keys span more than
+``DIRECT_MAX_RANGE`` values keeps the host path.
 """
 
 from __future__ import annotations
@@ -53,6 +58,11 @@ BLOOM_MAX_BITS = 1 << 23
 # hash arrays (16 B/row across both seeds) and the prune win both stop
 # being worth it when the "small" side is this large
 MAX_BUILD_ROWS = 1 << 22
+
+# the device form's bit table is addressed directly by ``key - lo`` (exact:
+# only a build key hits); builds whose keys span more than this many values
+# (1 MiB of words) keep the host path
+DIRECT_MAX_RANGE = 1 << 23
 
 # second hash seed for the probe stride (any odd constant unrelated to the
 # bucket hash seed 0 works; splitmix64's increment is conventional)
@@ -132,33 +142,93 @@ def _hash_pair(cols) -> Tuple[np.ndarray, np.ndarray]:
             hash_table_columns(cols, seed=_H2_SEED))
 
 
-class RuntimeJoinFilter:
-    """A sealed, immutable Bloom + min-max filter over build-side keys."""
+def _lane_keys_dtype(dtypes) -> bool:
+    """Whether the device form applies to a filter over keys of ``dtypes``:
+    one key, an integer of at most 63 bits' range or a date (what the
+    device join stages as lanes)."""
+    from ..datatypes import TypeKind
 
-    __slots__ = ("table", "nbits", "minmax", "dtypes", "build_rows",
-                 "_device_bits")
+    if dtypes is None or len(dtypes) != 1:
+        return False
+    dt = dtypes[0]
+    return ((dt.is_integer() and dt.kind != TypeKind.UINT64)
+            or dt.kind == TypeKind.DATE)
+
+
+def _lane_table(keys: np.ndarray, wide) -> Tuple[np.ndarray, int, int]:
+    """The device form's bit table over the sorted unique build ``keys``
+    (int64, spanning at most ``DIRECT_MAX_RANGE`` values) for lanes
+    widened to ``wide`` (int32 or int64): ``(words uint32[W], lo, hi)``,
+    bit ``key - lo`` set for each key. Keys outside ``wide`` are left out
+    (no lane holds them: staging narrows losslessly). W is a power of two
+    (at least 32 words), so the program's shape follows a bucket and not
+    the data."""
+    info = np.iinfo(wide)
+    keys = keys[(keys >= info.min) & (keys <= info.max)]
+    if not len(keys):
+        return np.zeros(32, dtype=np.uint32), 1, 0
+    lo, hi = int(keys[0]), int(keys[-1])
+    bits = np.zeros(max(_next_pow2(hi - lo + 1), 1 << 10), dtype=bool)
+    bits[keys - lo] = True
+    return np.packbits(bits, bitorder="little").view("<u4"), lo, hi
+
+
+@functools.lru_cache(maxsize=1)
+def _keep_program():
+    """The device keep-mask, jitted once (jax's trace cache is keyed on the
+    function object): ``valid & lo <= v <= hi & bit(v - lo)`` over the
+    staged lanes, one gather of a table word a lane. Everything is traced,
+    so a new build of the same bucket compiles nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def keep(vals, valid, words, lo, hi):
+        v = vals.astype(lo.dtype)
+        inr = valid & (v >= lo) & (v <= hi)
+        pos = jnp.where(inr, v - lo, 0).astype(jnp.uint32)
+        word = words.at[(pos >> 5).astype(jnp.int32)].get(
+            mode="promise_in_bounds")
+        return inr & (((word >> (pos & 31)) & 1) != 0)
+
+    return keep
+
+
+class RuntimeJoinFilter:
+    """A sealed, immutable Bloom + min-max filter over build-side keys,
+    with the build's sorted unique keys where the device form applies
+    (one integer or date key spanning at most ``DIRECT_MAX_RANGE``
+    values)."""
+
+    __slots__ = ("table", "nbits", "minmax", "dtypes", "build_rows", "keys",
+                 "_lane_tables")
 
     def __init__(self, table: np.ndarray, minmax: List[Optional[Tuple[Any, Any]]],
-                 dtypes, build_rows: int):
+                 dtypes, build_rows: int, keys: Optional[np.ndarray] = None):
         self.table = table  # bool[nbits], nbits a power of two
         self.nbits = len(table)
         self.minmax = minmax  # per key column: (lo, hi) or None
         self.dtypes = dtypes
         self.build_rows = build_rows
-        self._device_bits = None  # lazily staged uint8 copy for the jit path
+        self.keys = keys  # int64 sorted unique build keys, or None
+        self._lane_tables: dict = {}  # lane dtype -> staged _lane_table
 
     # ------------------------------------------------------------- probing
-    def keep_mask(self, tbl, key_exprs, ctx=None) -> np.ndarray:
+    def keep_mask(self, tbl, key_exprs, ctx=None, cache=None) -> np.ndarray:
         """Boolean keep-mask over ``tbl``'s rows: False rows provably
         cannot match any build-side key (up to the documented NaN bypass).
-        ``ctx`` (an ExecutionContext) routes the Bloom gathers through the
-        device path when eligible."""
+        ``ctx`` (an ExecutionContext) routes the probe through the device
+        path when eligible, reading the key's lanes from ``cache`` (the
+        partition's stage cache) where it holds them."""
         import pyarrow as pa
         import pyarrow.compute as pc
 
         n = len(tbl)
         if n == 0:
             return np.zeros(0, dtype=bool)
+        dev = self._device_keep(tbl, key_exprs, ctx, cache)
+        if dev is not None:
+            return dev
         cols = _key_arrays(tbl, key_exprs, self.dtypes)
         valid = np.ones(n, dtype=bool)
         bypass = np.zeros(n, dtype=bool)
@@ -180,75 +250,74 @@ class RuntimeJoinFilter:
                     pc.less_equal(arr, pa.scalar(hi, type=arr.type)))
                 rng_ok &= np.asarray(pc.fill_null(inr, False), dtype=bool)
         h1, h2 = _hash_pair(cols)
-        hit = self._bloom_hits(h1, h2, ctx)
+        hit = self._bloom_hits(h1, h2)
         # null keys never match for the prunable join types; NaN bypasses
         return valid & (bypass | (hit & rng_ok))
 
-    def _bloom_hits(self, h1: np.ndarray, h2: np.ndarray, ctx) -> np.ndarray:
+    def _bloom_hits(self, h1: np.ndarray, h2: np.ndarray) -> np.ndarray:
         mask = np.uint64(self.nbits - 1)
-        idx = np.empty((BLOOM_PROBES, len(h1)), dtype=np.int32)
         h = h1.copy()
-        for i in range(BLOOM_PROBES):
-            idx[i] = (h & mask).astype(np.int32)
+        out = self.table[(h & mask).astype(np.int64)]
+        for _ in range(1, BLOOM_PROBES):
             h += h2
-        dev = self._bloom_hits_device(idx, ctx)
-        if dev is not None:
-            return dev
-        out = self.table[idx[0]]
-        for i in range(1, BLOOM_PROBES):
-            out &= self.table[idx[i]]
+            out &= self.table[(h & mask).astype(np.int64)]
         return out
 
-    def _bloom_hits_device(self, idx: np.ndarray, ctx) -> Optional[np.ndarray]:
-        """One jit program for the k Bloom gathers + AND reduction, behind
-        the device circuit breaker. None = take the host path (ineligible,
-        breaker open, or the attempt failed and was recorded)."""
-        if ctx is None or not getattr(ctx.cfg, "use_device_kernels", False):
+    def _device_keep(self, tbl, key_exprs, ctx, cache) -> Optional[np.ndarray]:
+        """The keep-mask from the device: the key's staged lanes against
+        the build's bit table in one program, behind the device circuit
+        breaker. None = take the host path (ineligible, breaker open, or
+        the attempt failed and was recorded). The key's lanes are read
+        from ``cache`` where it holds them (``join_filter_resident_keys``)
+        and staged without keeping otherwise: a partition gains no
+        residency from its filter. Bumps ``join_filter_device_probes`` a
+        mask."""
+        if (self.keys is None or ctx is None or not ctx.device_path_on()
+                or len(tbl) < ctx.cfg.device_min_rows):
             return None
-        if idx.shape[1] < getattr(ctx.cfg, "device_min_rows", 4096):
+        from ..datatypes import TypeKind
+        from ..expressions import required_columns
+        from ..kernels.device import fetch, staged, x64_enabled
+        from ..kernels.device_join import _stage_key
+
+        (key,) = key_exprs
+        try:
+            kdt = key._node.to_field(tbl.schema).dtype
+        except Exception:
             return None
+        if (kdt.kind == TypeKind.DATE) != (self.dtypes[0].kind == TypeKind.DATE):
+            return None
+        n = len(tbl)
+        resident = staged(cache, required_columns(key), n)
 
         def _run():
-            out = probe_bits_device(self._staged_bits(), idx)
-            return np.asarray(out, dtype=bool)
+            import jax.numpy as jnp
+
+            wide = np.int64 if x64_enabled() else np.int32
+            tab = self._lane_tables.get(wide)
+            if tab is None:
+                words, lo, hi = _lane_table(self.keys, wide)
+                tab = (jnp.asarray(words), jnp.asarray(np.array(lo, wide)),
+                       jnp.asarray(np.array(hi, wide)))
+                self._lane_tables[wide] = tab
+            got = _stage_key(tbl, key, cache if resident else None)
+            if got is None:
+                return None
+            vals, valid = got
+            lane = np.dtype(vals.dtype)
+            if (lane.itemsize > np.dtype(wide).itemsize
+                    or (lane.kind == "u"
+                        and lane.itemsize == np.dtype(wide).itemsize)):
+                return None  # the lanes do not widen losslessly
+            out = _keep_program()(vals, valid, *tab)
+            return np.asarray(fetch(out))[:n]
 
         out = ctx._device_attempt(_run)
         if out is not None:
             ctx.stats.bump("join_filter_device_probes")
+            if resident:
+                ctx.stats.bump("join_filter_resident_keys")
         return out
-
-    def _staged_bits(self) -> np.ndarray:
-        if self._device_bits is None:
-            self._device_bits = self.table.astype(np.uint8)
-        return self._device_bits
-
-
-@functools.lru_cache(maxsize=1)
-def _probe_jitted():
-    """The jitted Bloom-probe program, built once: jax's trace cache is
-    keyed on the function object, so the callable must outlive the call
-    (a per-call closure would retrace+recompile on EVERY pruned
-    partition)."""
-    import jax
-    import jax.numpy as jnp
-
-    @jax.jit
-    def _probe(bits, ix):
-        g = jnp.take(bits, ix, axis=0)  # [k, n] uint8
-        return jnp.min(g, axis=0).astype(jnp.bool_)
-
-    return _probe
-
-
-def probe_bits_device(bits_u8: np.ndarray, idx: np.ndarray):
-    """jit'd Bloom membership: gather the k probe positions per row and
-    AND-reduce — the whole probe is one device program, compiled once per
-    (bits, idx) shape via the module-lived jitted callable."""
-    import jax
-    import jax.numpy as jnp
-
-    fn = _probe_jitted()
-    return jax.device_get(fn(jnp.asarray(bits_u8), jnp.asarray(idx)))
 
 
 def prune_partition(part, jf: RuntimeJoinFilter, key_exprs, ctx):
@@ -256,7 +325,14 @@ def prune_partition(part, jf: RuntimeJoinFilter, key_exprs, ctx):
     ALWAYS returns a usable partition — the input itself on any failure
     (including the ``join.filter`` fault site). Counters:
     ``join_filter_probe_rows`` (rows inspected) and
-    ``join_filter_rows_pruned`` (rows dropped pre-exchange)."""
+    ``join_filter_rows_pruned`` (rows dropped pre-exchange); span
+    ``phase:join.filter``. A partition of one table reads a resident key
+    column from its own stage cache."""
+    with ctx.stats.profiler.span("join.filter", kind="phase"):
+        return _prune(part, jf, key_exprs, ctx)
+
+
+def _prune(part, jf: RuntimeJoinFilter, key_exprs, ctx):
     from .. import faults
     from ..micropartition import MicroPartition
     from ..series import Series
@@ -264,13 +340,14 @@ def prune_partition(part, jf: RuntimeJoinFilter, key_exprs, ctx):
     try:
         faults.check("join.filter", ctx.stats)
         tabs = part.chunk_tables()
+        cache = part.device_stage_cache() if len(tabs) == 1 else None
         kept, before, after = [], 0, 0
         for t in tabs:
             nt = len(t)
             before += nt
             if nt == 0:
                 continue
-            mask = jf.keep_mask(t, key_exprs, ctx)
+            mask = jf.keep_mask(t, key_exprs, ctx, cache)
             if mask.all():
                 kept.append(t)
                 after += nt
@@ -296,8 +373,9 @@ def prune_partition(part, jf: RuntimeJoinFilter, key_exprs, ctx):
 class JoinFilterBuilder:
     """Accumulates build-side key batches; ``seal()`` freezes the filter.
 
-    Hashes are buffered per batch (16 B/row) and the bit table is sized
-    once the true build row count is known; past MAX_BUILD_ROWS the
+    Hashes are buffered per batch (16 B/row), and for a filter the device
+    form may apply to the valid keys as int64 (8 B/row); the bit table is
+    sized once the true build row count is known; past MAX_BUILD_ROWS the
     builder abandons (returns None at seal) rather than ballooning."""
 
     def __init__(self, key_exprs, dtypes):
@@ -305,6 +383,8 @@ class JoinFilterBuilder:
         self.dtypes = list(dtypes)
         self._h1: List[np.ndarray] = []
         self._h2: List[np.ndarray] = []
+        self._keys: Optional[List[np.ndarray]] = (
+            [] if _lane_keys_dtype(self.dtypes) else None)
         self._minmax: List[Optional[Tuple[Any, Any]]] = [None] * len(dtypes)
         self._mm_dead: List[bool] = [False] * len(dtypes)
         self._rows = 0
@@ -322,11 +402,17 @@ class JoinFilterBuilder:
             self._abandoned = True
             self._h1.clear()
             self._h2.clear()
+            self._keys = None
             return
         cols = _key_arrays(tbl, self.key_exprs, self.dtypes)
         h1, h2 = _hash_pair(cols)
         self._h1.append(h1)
         self._h2.append(h2)
+        if self._keys is not None:
+            k = pc.drop_null(cols[0])
+            if pa.types.is_date32(k.type):
+                k = k.cast(pa.int32())
+            self._keys.append(np.asarray(k).astype(np.int64))
         for j, arr in enumerate(cols):
             if self._mm_dead[j] or pa.types.is_floating(arr.type):
                 # float min-max would have to reason about NaN ordering;
@@ -362,7 +448,13 @@ class JoinFilterBuilder:
                 h += h2
         minmax = [None if dead else mm
                   for mm, dead in zip(self._minmax, self._mm_dead)]
-        return RuntimeJoinFilter(table, minmax, self.dtypes, self._rows)
+        keys = None
+        if self._keys is not None:
+            keys = np.unique(np.concatenate(self._keys) if self._keys
+                             else np.zeros(0, dtype=np.int64))
+            if len(keys) and int(keys[-1]) - int(keys[0]) >= DIRECT_MAX_RANGE:
+                keys = None  # too wide a span for direct bits: host path
+        return RuntimeJoinFilter(table, minmax, self.dtypes, self._rows, keys)
 
 
 class JoinFilterSlot:

@@ -2362,7 +2362,7 @@ def stage_table_columns(table, names, bucket: int, stage_cache: Optional[dict] =
     env = {}
     dcs = {}
     for name in names:
-        ckey = (name, bucket, x64_enabled())
+        ckey = _column_key(name, bucket)
         dc = stage_cache.get(ckey) if stage_cache is not None else None
         if dc is None:
             s = table.get_column(name)
@@ -2374,6 +2374,34 @@ def stage_table_columns(table, names, bucket: int, stage_cache: Optional[dict] =
         env[name] = (dc.values, dc.valid)
         dcs[name] = dc
     return env, dcs
+
+
+def _column_key(name: str, bucket: int) -> tuple:
+    """A staged column's key in a stage cache (``stage_table_columns``)."""
+    return (name, bucket, x64_enabled())
+
+
+def staged(stage_cache: Optional[dict], names, n: int) -> bool:
+    """Whether ``stage_cache`` holds the lanes of every column in ``names``
+    over a table of ``n`` rows."""
+    if not stage_cache:
+        return False
+    b = size_bucket(n)
+    return all(_column_key(name, b) in stage_cache for name in names)
+
+
+def carry_staged(stage_cache: Optional[dict], renames: dict) -> dict:
+    """The staged column lanes of ``stage_cache``, keyed for a table over
+    the same rows in which the column ``src`` is named each of
+    ``renames[src]``. The arrays are shared, not copied."""
+    out = {}
+    for key, dc in (stage_cache or {}).items():
+        if (isinstance(dc, DeviceColumn) and isinstance(key, tuple)
+                and len(key) == 3 and key[0] in renames
+                and key == _column_key(key[0], key[1])):
+            for name in renames[key[0]]:
+                out[_column_key(name, key[1])] = dc
+    return out
 
 
 def _rewrite_between(node, schema):

@@ -136,6 +136,42 @@ def _launch_exprs(part: MicroPartition, exprs):
         part.table(), exprs, stage_cache=part.device_stage_cache())
 
 
+def _selected_column(e) -> Optional[str]:
+    """The input column a projection expression passes through unchanged
+    (a bare column, aliased or not), or None."""
+    from .expressions import Column
+
+    node = e._node
+    while isinstance(node, Alias):
+        node = node.child
+    return node.cname if isinstance(node, Column) else None
+
+
+def _passed_through_lanes(part: MicroPartition, exprs) -> dict:
+    """The staged lanes of the columns a projection passes through
+    unchanged, keyed for its output: a bare column, aliased or not, keeps
+    its rows, so its lanes over the input are its lanes over the output. A
+    consumer of the output that stages such a column (the runtime join
+    filter, a join probe) then finds a resident input's lanes in place of
+    staging them again."""
+    cache = part.device_stage_cache()
+    if not cache:
+        return {}  # (and jax stays unimported on a host-only worker)
+    from .kernels.device import carry_staged
+
+    renames: Dict[str, List[str]] = {}
+    for e in exprs:
+        src = _selected_column(e)
+        if src is not None:
+            renames.setdefault(src, []).append(e.name())
+    return carry_staged(cache, renames)
+
+
+def _with_lanes(part: MicroPartition, lanes: dict) -> MicroPartition:
+    part.device_stage_cache().update(lanes)
+    return part
+
+
 class PhysicalOp:
     """Base: children + a generator-producing execute().
 
@@ -363,18 +399,34 @@ class ProjectOp(DeviceStep, PhysicalOp):
         super().__init__([child], schema, child.num_partitions)
         self.exprs = exprs
 
+    @property
+    def has_program(self) -> bool:
+        # one that only selects and renames columns computes nothing: the
+        # host's select is zero-copy and hands the input's staged lanes on,
+        # where a program would stage, copy and fetch them back (and, over
+        # a partition larger than a morsel, stream it as morsels whose
+        # lanes die with them)
+        return any(_selected_column(e) is None for e in self.exprs)
+
     def compilable(self) -> bool:
-        return _exprs_compile(self.exprs, self.children[0].schema)
+        return self.has_program and _exprs_compile(self.exprs,
+                                                   self.children[0].schema)
 
     def launch(self, ctx, part):
-        return _launch_exprs(part, list(self.exprs))
+        # taken before the launch stages anything: only what the input
+        # held already is shared, so no staged array outlives its partition
+        kept = _passed_through_lanes(part, self.exprs)
+        resolve = _launch_exprs(part, list(self.exprs))
+        return None if resolve is None else (lambda: (resolve(), kept))
 
     def finish(self, ctx, out, part):
-        return part._wrap(out)
+        tbl, kept = out
+        return _with_lanes(part._wrap(tbl), kept)
 
     def host(self, ctx, part):
         ctx.stats.bump("host_projections")
-        return part.eval_expression_list(self.exprs)
+        return _with_lanes(part.eval_expression_list(self.exprs),
+                           _passed_through_lanes(part, self.exprs))
 
     def defer(self, part):
         exprs = list(self.exprs)

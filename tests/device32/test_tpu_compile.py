@@ -9,8 +9,8 @@ chip at all). The programs below are Q1's and Q6's shape through the
 program's own ``segment_reduce``; the compiler's own account of their
 temporaries is the guard. The join probe's search (PR 35) is held the same
 way at ``tpch1-join``'s Q5 shape, and the float sum over more than 4096
-groups (PR 39) at an eighth of ``tpch1-sql-subquery``'s Q18. No time is read
-here.
+groups at an eighth of ``tpch1-sql-subquery``'s Q18, and the runtime
+join filter's keep-mask at its Q17. No time is read here.
 """
 
 import pytest
@@ -142,3 +142,21 @@ def test_float_sum_over_many_groups_grows_with_rows_plus_groups(one_chip):
     text = compiled.as_text()
     assert f"f32[{rows // 8192},{groups}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_join_filter_keep_mask_reads_each_lane_once(one_chip):
+    import jax.numpy as jnp
+
+    from daft_tpu.exchange import joinfilter
+
+    # Q17's outer join at SF1: LINEITEM's 8M-lane bucket of l_partkey
+    # against the direct bits of ~200 part keys spanning 200k values. The
+    # host-indexed Bloom gather it replaced read 4 x 6M positions into a
+    # u8[24000000]; the keep program writes the bool mask and nothing else
+    p, words = 1 << 23, 1 << 13
+    keep = joinfilter._keep_program()
+    compiled = _compile(
+        lambda v, m, w, lo, hi: keep(v, m, w, lo[0], hi[0]), one_chip,
+        [jnp.int32, jnp.bool_, jnp.uint32, jnp.int32, jnp.int32],
+        rows=[p, p, words, 1, 1])
+    assert compiled.memory_analysis().temp_size_in_bytes < p // 4
