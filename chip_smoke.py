@@ -56,7 +56,11 @@ def layer_times(counters: dict) -> str:
     """``plan 1ms stage 12ms ... staged 3.1MB in 2 columns ... compiles 0
     (0ms) cache loads 0 dict lookups 1 packed 0 gathered agg reduce 1 dense
     0 kernel 0 sorted probe levels 56 compared 17 gathered sql plan 1.2ms 2
-    subqueries 1 scalar subquery joins 1 (1 on the device)``: the layer
+    subqueries 1 scalar subquery joins 1 (1 on the device) join filter 1
+    built 2 device 1 resident 5990000 pruned entry setup 1.1ms finish
+    0.9ms (teardown 0.0 metrics 0.7 record 0.1 history 0.0 persist 0.0)
+    convert 0.2ms dispatch lookup 0.2ms call 1.0ms gc 3 (0 gen2) 0.0ms
+    plan misses 0 (shape 0 config 0 binding 0 uncached 0)``: the layer
     counters of one query, for its printed line. A
     warm query that stages columns lost its stage cache; one that compiles
     (and for how long) met a shape the warm-up did not; one that gathers a
@@ -66,7 +70,10 @@ def layer_times(counters: dict) -> str:
     one over 4096; the levels of its join probes' searches that
     gathered are those beyond ``PROBE_COMPARE_LEVELS`` of each build; a query
     that came as SQL text says what its front end took and what its
-    subqueries became (0 throughout for one built through the API)."""
+    subqueries became (0 throughout for one built through the API); then
+    the runtime join filter, the entry layer's regions and the hooks of
+    its finish, the parts of the dispatch frames, the collections while it
+    ran, and why the plan cache missed."""
     parts = [f"{label} {counters.get(key, 0) / 1e6:.0f}ms"
              for label, key in LAYER_COUNTERS]
     parts.append(f"staged {counters.get('stage_bytes', 0) / 1e6:.1f}MB "
@@ -88,7 +95,30 @@ def layer_times(counters: dict) -> str:
                  f"{counters.get('sql_scalar_subqueries', 0)} scalar "
                  f"subquery joins {counters.get('sql_subquery_joins', 0)} "
                  f"({counters.get('sql_subquery_joins_device', 0)} "
-                 f"on the device)")
+                 f"on the device) "
+                 f"join filter {counters.get('join_filter_built', 0)} built "
+                 f"{counters.get('join_filter_device_probes', 0)} device "
+                 f"{counters.get('join_filter_resident_keys', 0)} resident "
+                 f"{counters.get('join_filter_rows_pruned', 0)} pruned")
+
+    def ms(key):
+        return f"{counters.get(key, 0) / 1e6:.1f}"
+
+    hooks = " ".join(f"{h} {ms(f'entry_finish_{h}_ns')}" for h in (
+        "teardown", "metrics", "record", "history", "persist"))
+    reasons = " ".join(
+        f"{r} {counters.get(f'plan_cache_miss_{r}', 0)}"
+        for r in ("shape", "config", "binding", "uncached"))
+    parts.append(f"entry setup {ms('entry_setup_ns')}ms "
+                 f"finish {ms('entry_finish_ns')}ms ({hooks}) "
+                 f"convert {ms('entry_convert_ns')}ms "
+                 f"dispatch lookup {ms('dispatch_lookup_ns')}ms "
+                 f"call {ms('dispatch_call_ns')}ms "
+                 f"gc {counters.get('gc_collections', 0)} "
+                 f"({counters.get('gc_collections_gen2', 0)} gen2) "
+                 f"{ms('gc_pause_ns')}ms "
+                 f"plan misses {counters.get('plan_cache_misses', 0)} "
+                 f"({reasons})")
     return " ".join(parts)
 
 

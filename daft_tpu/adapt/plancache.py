@@ -173,6 +173,14 @@ def _cfg_key(cfg) -> str:
                     for f in dataclasses.fields(cfg))
 
 
+def _sans_generation(cfg_key: str) -> list:
+    """A config key less its generation: a key ends
+    ``|v<version>|g<generation>|r<runner>`` (``_plan_query``)."""
+    parts = cfg_key.rsplit("|", 3)
+    del parts[2:3]
+    return parts
+
+
 class PlanCache:
     """Bounded, thread-safe plan/program cache (see module docstring)."""
 
@@ -202,6 +210,22 @@ class PlanCache:
                            error=repr(e))
 
     # ------------------------------------------------------------ lookup
+    def miss_reason(self, canonical_fp: str, cfg_key: str) -> Tuple[str, bool]:
+        """Why a lookup of this shape under ``cfg_key`` found no plan:
+        ``("binding", False)`` where the entry exists (a new binding:
+        literals or a source's mtime), ``("config", gen_only)`` where the
+        shape has entries under other config keys only (``gen_only``: one
+        of them differs from ``cfg_key`` in ``PLAN_CACHE.generation`` alone),
+        else ``("shape", False)``."""
+        with self._lock:
+            if (canonical_fp, cfg_key) in self._entries:
+                return "binding", False
+            others = [k for fp, k in self._entries if fp == canonical_fp]
+        if not others:
+            return "shape", False
+        mine = _sans_generation(cfg_key)
+        return "config", any(_sans_generation(k) == mine for k in others)
+
     def lookup(self, canonical_fp: str, cfg_key: str,
                binding: str) -> Optional[CompiledPlan]:
         with self._lock:
@@ -463,12 +487,14 @@ def _has_write(plan) -> bool:
 
 def plan_query(plan, cfg, stats=None, optimized: bool = False,
                runner: str = "native"):
-    """``_plan_query`` inside a ``plan`` span (what ``planning_wall_ns``
-    times), when the query's profiler is armed."""
-    from ..profile import DISARMED
+    """``_plan_query`` inside the ``plan`` frame: ``planning_wall_ns``, and
+    a ``plan`` span when the query's profiler is armed. A frame, so an
+    enclosing one (``entry.setup``) takes planning off its own time."""
+    if stats is None:
+        return _plan_query(plan, cfg, stats, optimized, runner)
+    from ..profile.timeline import DeviceFrame
 
-    prof = DISARMED if stats is None else stats.profiler
-    with prof.span("plan", kind="phase"):
+    with DeviceFrame(stats, "plan", "planning_wall_ns"):
         return _plan_query(plan, cfg, stats, optimized, runner)
 
 
@@ -480,16 +506,16 @@ def _plan_query(plan, cfg, stats, optimized: bool, runner: str):
     ``cfg`` unless a history-driven per-query hint (e.g. streaming-off)
     replaced a knob for this execution only.
 
-    Timing lands in ``stats``: ``planning_wall_ns`` covers this whole
-    call (cold planning or warm lookup+rehydrate), ``compile_wall_ns``
-    the fuse-compile share inside ``translate`` — the very costs the
-    cache removes stay measurable either way."""
-    import time as _time
-
+    Timing lands in ``stats``: ``planning_wall_ns`` (``plan_query``'s
+    frame) covers this whole call (cold planning or warm lookup+rehydrate),
+    ``compile_wall_ns`` the fuse-compile share inside ``translate`` — the
+    very costs the cache removes stay measurable either way. A miss bumps
+    ``plan_cache_misses`` and one ``plan_cache_miss_<reason>``
+    (``PlanCache.miss_reason``; ``uncached`` where the cache stood down or
+    failed)."""
     from . import fdo
     from .fingerprint import canonical_fingerprint
 
-    t0 = _time.perf_counter_ns()
     canonical = ""
     try:
         canonical = canonical_fingerprint(plan)
@@ -500,9 +526,6 @@ def _plan_query(plan, cfg, stats, optimized: bool, runner: str):
     def _finish(opt, phys, run_cfg, from_cache: bool):
         if canonical:
             phys._canonical_fp = canonical
-        if stats is not None:
-            stats.bump("planning_wall_ns",
-                       _time.perf_counter_ns() - t0)
         run_cfg = fdo.apply_query_hints(canonical, run_cfg, stats)
         return opt, phys, run_cfg
 
@@ -556,6 +579,10 @@ def _plan_query(plan, cfg, stats, optimized: bool, runner: str):
             logger.warning("plan_cache_lookup_failed", error=repr(e))
             binding = None
     if not use_cache or binding is None:
+        if use_cache and stats is not None:
+            # stood down (faults armed, an uncacheable plan) or failed
+            stats.bump_many({"plan_cache_misses": 1,
+                             "plan_cache_miss_uncached": 1})
         opt, phys, _ = _cold(record_fdo=not optimized)
         return _finish(opt, phys, cfg, from_cache=False)
 
@@ -593,10 +620,14 @@ def _plan_query(plan, cfg, stats, optimized: bool, runner: str):
             continue
         # we own the build
         try:
+            if stats is not None:
+                reason, gen_only = PLAN_CACHE.miss_reason(canonical, cfg_key)
             opt, phys, coll = _cold(record_fdo=True)
             if stats is not None:
-                stats.bump("plan_cache_misses")
-                _event(stats, "miss", fingerprint=canonical)
+                stats.bump_many({"plan_cache_misses": 1,
+                                 f"plan_cache_miss_{reason}": 1})
+                _event(stats, "miss", fingerprint=canonical, reason=reason,
+                       generation_only=gen_only)
             try:
                 cp = CompiledPlan(opt, phys,
                                   _estimate_plan_bytes(opt, phys),
@@ -615,5 +646,6 @@ def _plan_query(plan, cfg, stats, optimized: bool, runner: str):
     # fail-open tail: plan uncached (still FDO-informed)
     opt, phys, _ = _cold(record_fdo=True)
     if stats is not None:
-        stats.bump("plan_cache_misses")
+        stats.bump_many({"plan_cache_misses": 1,
+                         "plan_cache_miss_uncached": 1})
     return _finish(opt, phys, cfg, from_cache=False)

@@ -452,13 +452,37 @@ class DataFrame:
                 self._profile.to_json(profile)
             return self
         self.stats.reset_cancel()  # a cancelled DataFrame stays retryable
-        from .runners import partition_set_cache, plan_cache_key
-
         from .profile import arm_for_query
+        from .profile.timeline import DeviceFrame, end_frame, host_query
 
-        cfg = get_context().execution_config
         # armed here, where the query begins, so planning is inside a span
         want = arm_for_query(self.stats, f"q-{id(self._plan):x}", profile)
+        with host_query(self.stats):
+            # entry.setup: from here to the plan stream's first pull
+            # (execute_plan), less the planning inside it
+            DeviceFrame(self.stats, "entry.setup",
+                        "entry_setup_ns").__enter__()
+            try:
+                self._run()
+            finally:
+                end_frame(self.stats, "entry_setup_ns")
+        if want:
+            from .profile import build_profile
+
+            qp = build_profile(self.stats.profiler, self.stats)
+            self._profile = qp
+            get_context()._last_profile = qp
+            if isinstance(want, str):
+                qp.to_json(want)
+        self._plan = InMemorySource(self._result.schema, self._result.partitions)
+        return self
+
+    def _run(self) -> None:
+        """Set ``_result``: the result cache's entry for this plan, or the
+        runner's run of it."""
+        from .runners import partition_set_cache, plan_cache_key
+
+        cfg = get_context().execution_config
         cache = partition_set_cache()
         key = (plan_cache_key(self._plan)
                if cfg.enable_result_cache else None)
@@ -477,16 +501,6 @@ class DataFrame:
                 cache.put(key, self._result)
                 # the entry lives exactly as long as some DataFrame owns it
                 weakref.finalize(self, cache.release, key)
-        if want:
-            from .profile import build_profile
-
-            qp = build_profile(self.stats.profiler, self.stats)
-            self._profile = qp
-            get_context()._last_profile = qp
-            if isinstance(want, str):
-                qp.to_json(want)
-        self._plan = InMemorySource(self._result.schema, self._result.partitions)
-        return self
 
     def profile(self):
         """The QueryProfile recorded by a profiled collect(), or None."""
@@ -520,20 +534,31 @@ class DataFrame:
         self.collect()
         return self._result
 
+    def _converted(self, method: Optional[str]):
+        """The materialized result as one Table, then its ``method`` (None:
+        the Table), in the ``entry.convert`` frame (``entry_convert_ns``)."""
+        result = self._materialized()
+        from .profile.timeline import DeviceFrame, host_query
+
+        with host_query(self.stats), DeviceFrame(
+                self.stats, "entry.convert", "entry_convert_ns"):
+            table = result.to_table()
+            return table if method is None else getattr(table, method)()
+
     def to_pydict(self) -> Dict[str, list]:
-        return self._materialized().to_table().to_pydict()
+        return self._converted("to_pydict")
 
     def to_pylist(self) -> List[dict]:
-        return self._materialized().to_table().to_pylist()
+        return self._converted("to_pylist")
 
     def to_arrow(self):
-        return self._materialized().to_table().to_arrow()
+        return self._converted("to_arrow")
 
     def to_pandas(self):
-        return self._materialized().to_table().to_pandas()
+        return self._converted("to_pandas")
 
     def to_table(self):
-        return self._materialized().to_table()
+        return self._converted(None)
 
     def to_torch_map_dataset(self):
         from .integrations.torch_data import MapDataset

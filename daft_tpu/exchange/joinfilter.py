@@ -45,6 +45,7 @@ from typing import Any, List, Optional, Tuple
 import numpy as np
 
 from ..errors import DaftInternalError
+from ..profile import timeline
 
 # Bloom geometry: bits = next_pow2(rows * BITS_PER_KEY) clamped to
 # [MIN_BITS, MAX_BITS]; PROBES probes per key via Kirsch-Mitzenmacher
@@ -309,7 +310,8 @@ class RuntimeJoinFilter:
                     or (lane.kind == "u"
                         and lane.itemsize == np.dtype(wide).itemsize)):
                 return None  # the lanes do not widen losslessly
-            out = _keep_program()(vals, valid, *tab)
+            with timeline.part("dispatch.call", "dispatch_call_ns"):
+                out = _keep_program()(vals, valid, *tab)
             return np.asarray(fetch(out))[:n]
 
         out = ctx._device_attempt(_run)

@@ -23,6 +23,7 @@ from typing import Dict, Iterator, List, Optional
 from .context import ExecutionConfig
 from .micropartition import MicroPartition
 from .physical import PhysicalOp
+from .profile import timeline
 
 
 class QueryCancelledError(RuntimeError):
@@ -748,13 +749,13 @@ class ExecutionContext:
         started, whose finisher records for real)."""
         from . import faults
         from .kernels.compile_cache import configure_compile_cache
-        from .profile.timeline import DeviceFrame
 
         configure_compile_cache()
         # device_dispatch_ns: this attempt's wall less the staging (and, for
         # the whole-in-one sort and distinct attempts, the waits and copies)
         # recorded inside it
-        with DeviceFrame(self.stats, "dispatch", "device_dispatch_ns"):
+        with timeline.DeviceFrame(self.stats, "dispatch",
+                                  "device_dispatch_ns"):
             try:
                 faults.check("device.kernel", self.stats)
                 out = fn()
@@ -772,9 +773,7 @@ class ExecutionContext:
         (``device_wait_ns``, recorded by ``kernels.device.fetch``) apart
         from copying back and assembling (``gather_ns``, the rest of this
         wall). Exceptions pass through to the caller's fallback."""
-        from .profile.timeline import DeviceFrame
-
-        with DeviceFrame(self.stats, "gather", "gather_ns"):
+        with timeline.DeviceFrame(self.stats, "gather", "gather_ns"):
             return resolve()
 
     def _device_failed(self, site: str, exc: BaseException) -> None:
@@ -978,7 +977,9 @@ def _record_query(root: PhysicalOp, ctx: ExecutionContext, query_id: str,
                   outcome: str, error, rows_emitted: int) -> None:
     """Completion hook: append the QueryRecord (every outcome, including
     the error/timeout paths — this runs in execute_plan's ``finally``) and
-    hand it to the slow/failed-query auto-capture. ``enable_query_log``
+    hand it to the slow/failed-query auto-capture, fold the history and
+    persist (three parts of ``entry.finish``: ``finish.record``,
+    ``finish.history``, ``finish.persist``). ``enable_query_log``
     gates only the ring (and ``last_query_record``); the diagnostics
     capture contract — errored/deadline-killed queries always bundle when
     ``diagnostics_dir`` is set — survives a disabled log. Observability
@@ -996,16 +997,17 @@ def _record_query(root: PhysicalOp, ctx: ExecutionContext, query_id: str,
             from .obs.querylog import QUERY_LOG, build_record
 
             prof = ctx.stats.profiler
-            rec = build_record(query_id, fingerprint, plan_ops, cfg,
-                               ctx.stats, wall_ns, outcome, error=error,
-                               profiled=prof.armed,
-                               rows_emitted=rows_emitted,
-                               canonical=canonical)
-            if want_log:
-                QUERY_LOG.resize(cfg.query_log_depth)
-                QUERY_LOG.append(rec)
-                ctx.stats.last_record = rec
-            obs_capture.maybe_capture(rec, cfg, ctx.stats, prof)
+            with timeline.part("finish.record", "entry_finish_record_ns"):
+                rec = build_record(query_id, fingerprint, plan_ops, cfg,
+                                   ctx.stats, wall_ns, outcome, error=error,
+                                   profiled=prof.armed,
+                                   rows_emitted=rows_emitted,
+                                   canonical=canonical)
+                if want_log:
+                    QUERY_LOG.resize(cfg.query_log_depth)
+                    QUERY_LOG.append(rec)
+                    ctx.stats.last_record = rec
+                obs_capture.maybe_capture(rec, cfg, ctx.stats, prof)
         except Exception as e:
             from .obs.log import get_logger
 
@@ -1017,10 +1019,12 @@ def _record_query(root: PhysicalOp, ctx: ExecutionContext, query_id: str,
         try:
             from .adapt.history import HISTORY
 
-            HISTORY.fold(canonical, ctx.stats, rec if rec is not None
-                         else {"outcome": outcome,
-                               "wall_s": wall_ns / 1e9,
-                               "counters": ctx.stats.snapshot()["counters"]})
+            with timeline.part("finish.history", "entry_finish_history_ns"):
+                HISTORY.fold(canonical, ctx.stats, rec if rec is not None
+                             else {"outcome": outcome,
+                                   "wall_s": wall_ns / 1e9,
+                                   "counters":
+                                       ctx.stats.snapshot()["counters"]})
         except Exception as e:
             from .obs.log import get_logger
 
@@ -1032,7 +1036,8 @@ def _record_query(root: PhysicalOp, ctx: ExecutionContext, query_id: str,
         try:
             from . import persist
 
-            persist.maybe_save(cfg, ctx.stats)
+            with timeline.part("finish.persist", "entry_finish_persist_ns"):
+                persist.maybe_save(cfg, ctx.stats)
         except Exception as e:
             from .obs.log import get_logger
 
@@ -1147,6 +1152,8 @@ def execute_plan(root: PhysicalOp, ctx: ExecutionContext,
         progress = QueryProgress(query_id, ctx.stats, plan_ops)
         ctx.progress = progress
         register_progress(progress)
+        # the plan stream's first pull: DataFrame.collect's entry.setup ends
+        timeline.end_frame(ctx.stats, "entry_setup_ns")
         try:
             # the query id binds per PULL, never across a yield: two lazily
             # interleaved streams on one thread would otherwise cross-
@@ -1195,35 +1202,39 @@ def execute_plan(root: PhysicalOp, ctx: ExecutionContext,
             # inner finally: a teardown step raising must not leak a
             # phantom "running" query into the process registry forever.
             try:
-                with obs_log.query_context(query_id):
-                    # close the stream tree BEFORE the pool goes away: a
-                    # streaming pipeline's producers may be blocked on
-                    # their channels, and generator close is what shuts
-                    # the channels and unblocks them (GC would get there
-                    # eventually; an abandoned/erroring query must not
-                    # leave pool workers parked until then)
-                    close = getattr(it, "close", None)
-                    if close is not None:
-                        try:
-                            close()
-                        except BaseException as e:
-                            # a generator's own teardown raising must not
-                            # skip pool shutdown or the record-on-every-
-                            # completion contract (and must not mask the
-                            # query's error)
-                            obs_log.get_logger("execution").warning(
-                                "stream_close_failed", error=repr(e))
-                    # close(it) cannot reach a pipeline suspended below an
-                    # op whose raise terminated the chain above it (the
-                    # traceback keeps those frames alive — see
-                    # register_stream): shut down the stragglers directly.
-                    # Only a deliberate early stop (success/abandoned
-                    # consumer) counts short-circuits.
-                    ctx.close_streams(
-                        short_circuit=outcome in ("ok", "abandoned"))
-                    ctx.shutdown_pool()
-                    ctx.finish_query()
-                    ctx.stats.fold_op_self_host()
+                # entry.finish: this whole block, each hook a part of it
+                with obs_log.query_context(query_id), timeline.DeviceFrame(
+                        ctx.stats, "entry.finish", "entry_finish_ns"):
+                    with timeline.part("finish.teardown",
+                                       "entry_finish_teardown_ns"):
+                        # close the stream tree BEFORE the pool goes away: a
+                        # streaming pipeline's producers may be blocked on
+                        # their channels, and generator close is what shuts
+                        # the channels and unblocks them (GC would get there
+                        # eventually; an abandoned/erroring query must not
+                        # leave pool workers parked until then)
+                        close = getattr(it, "close", None)
+                        if close is not None:
+                            try:
+                                close()
+                            except BaseException as e:
+                                # a generator's own teardown raising must
+                                # not skip pool shutdown or the record-on-
+                                # every-completion contract (and must not
+                                # mask the query's error)
+                                obs_log.get_logger("execution").warning(
+                                    "stream_close_failed", error=repr(e))
+                        # close(it) cannot reach a pipeline suspended below
+                        # an op whose raise terminated the chain above it
+                        # (the traceback keeps those frames alive — see
+                        # register_stream): shut down the stragglers
+                        # directly. Only a deliberate early stop (success/
+                        # abandoned consumer) counts short-circuits.
+                        ctx.close_streams(
+                            short_circuit=outcome in ("ok", "abandoned"))
+                        ctx.shutdown_pool()
+                        ctx.finish_query()
+                        ctx.stats.fold_op_self_host()
                     prof = ctx.stats.profiler
                     prof.finish()
                     if tracing.active() and prof.armed:
@@ -1235,7 +1246,9 @@ def execute_plan(root: PhysicalOp, ctx: ExecutionContext,
                     from .profile.metrics import record_query_metrics
 
                     wall_ns = time.perf_counter_ns() - t0
-                    record_query_metrics(ctx.stats, wall_ns)
+                    with timeline.part("finish.metrics",
+                                       "entry_finish_metrics_ns"):
+                        record_query_metrics(ctx.stats, wall_ns)
                     _record_query(root, ctx, query_id, fingerprint,
                                   plan_ops, wall_ns, outcome, error,
                                   rows_out)

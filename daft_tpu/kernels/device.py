@@ -2624,8 +2624,12 @@ def _stage_and_run(table, exprs, stage_cache: Optional[dict]):
                             aux)
     if env is None:
         return None
-    run, out_dts = compile_projection(nodes, schema, tuple(sorted(needed)))
-    return run(env), out_dts, nodes, dcs, aux
+    with timeline.part("dispatch.lookup", "dispatch_lookup_ns"):
+        run, out_dts = compile_projection(nodes, schema,
+                                          tuple(sorted(needed)))
+    with timeline.part("dispatch.call", "dispatch_call_ns"):
+        outs = run(env)
+    return outs, out_dts, nodes, dcs, aux
 
 
 def eval_projection_device_async(table, exprs, stage_cache: Optional[dict] = None):

@@ -489,8 +489,9 @@ def launch_agg(child_nodes, pred_node, schema, input_names, kinds, modes,
     # columns over 64M rows: 4.6 ms at 2 and 4, 5.3 at 8, 8.6 at 16, 20.9 at
     # 32; tools/segment_sum_sweep.py), and never one (device._dense_hits)
     gb = max(2, 1 << (num_groups - 1).bit_length())
-    run = _compile_agg(child_nodes, pred_node, schema, input_names, kinds,
-                       modes, gb, use_pallas)
+    with timeline.part("dispatch.lookup", "dispatch_lookup_ns"):
+        run = _compile_agg(child_nodes, pred_node, schema, input_names,
+                           kinds, modes, gb, use_pallas)
     form = _sum_form(gb, use_pallas)
     if form == "dense" or (form in ("kernel", "sorted") and any(
             kind in ("sum", "mean") and nd.to_field(schema).dtype.is_floating()
@@ -504,7 +505,8 @@ def launch_agg(child_nodes, pred_node, schema, input_names, kinds, modes,
         n_dev = jnp.int32(n)
         if stage_cache is not None:
             stage_cache[nkey] = n_dev
-    return run(env, codes_dev, n_dev)
+    with timeline.part("dispatch.call", "dispatch_call_ns"):
+        return run(env, codes_dev, n_dev)
 
 
 def _compile_agg(child_nodes, pred_node, schema, input_names, kinds, modes, gb,

@@ -310,8 +310,10 @@ def _stage_key(table, key_expr, cache) -> Optional[Tuple]:
     env = transform_cmp_env([node], schema, table, b, cache, dcs, env, aux)
     if env is None:
         return None
-    run, _ = compile_projection([node], schema, tuple(sorted(cols)))
-    (vals, valid), = run(env)
+    with timeline.part("dispatch.lookup", "dispatch_lookup_ns"):
+        run, _ = compile_projection([node], schema, tuple(sorted(cols)))
+    with timeline.part("dispatch.call", "dispatch_call_ns"):
+        (vals, valid), = run(env)
     if not jnp.issubdtype(vals.dtype, jnp.integer):
         return None
     # a null-reviving key expression (fill_null, int transforms through the
@@ -734,7 +736,8 @@ def _probe(build_vals, build_valid, probe_vals, probe_valid):
 def _launch_probe(lv, lm, rv, rm, ln: int, rn: int, how: str):
     """Dispatch the right-build range probe now (async); return the
     resolver that makes the dup decision and finishes the probe."""
-    lo, counts, perm, dup = _probe(rv, rm, lv, lm)
+    with timeline.part("dispatch.call", "dispatch_call_ns"):
+        lo, counts, perm, dup = _probe(rv, rm, lv, lm)
 
     def resolve():
         # build=right first (probe order == host output order); ONE sort

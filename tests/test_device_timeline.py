@@ -152,9 +152,12 @@ def test_spans_are_events_of_the_xplane_nested_like_the_tree(device_cfg,
     assert ("phase:dispatch", "phase:stage") in checked
     assert ("phase:gather", "phase:device.wait") in checked
     assert ("phase:gather", "phase:join.expand") in checked
-    # planning is no operator's child: it ran before any operator opened
+    # planning is no operator's child: it ran inside collect()'s set-up,
+    # before any operator opened
     first_op = min(e[1] for e in events if e[0].startswith(PREFIX + "op:"))
-    assert plan.parent is None and plan_ev[1] + plan_ev[2] <= first_op
+    (setup,) = [s for s in spans if s.name == "entry.setup"]
+    assert plan.parent == setup.sid and setup.parent is None
+    assert plan_ev[1] + plan_ev[2] <= first_op
 
 
 def test_no_session_no_profiler_no_annotation(device_cfg, monkeypatch):
@@ -272,7 +275,12 @@ def test_planning_is_inside_a_span_under_every_arming_cause(
     assert prof.device_timeline == cause.endswith("device_trace")
     spans = prof.spans_snapshot()
     (plan,) = [s for s in spans if s.name == "plan"]
-    assert plan.kind == "phase" and plan.parent is None
+    # inside collect()'s set-up, or at the top where the serving runtime
+    # runs the plan: in no operator either way
+    setup = [s for s in spans if s.name == "entry.setup"]
+    assert len(setup) == (cause != "serving_device_trace")
+    assert plan.kind == "phase"
+    assert plan.parent == (setup[0].sid if setup else None)
     ops = [s for s in spans if s.kind == "op"]
     assert ops and plan.t0_ns + plan.dur_ns <= min(s.t0_ns for s in ops)
     # the span is what planning_wall_ns times
@@ -350,3 +358,165 @@ def test_frames_on_many_threads_lose_no_update_and_count_no_ns_twice():
     assert sum(c[k] for k in keys) <= sum(walls)
     assert outside == [None] * n_threads  # every frame was popped
     assert timeline.current_frame() is None
+
+
+# ------------------------------------------- the entry layer, dispatch parts,
+# the collector and the plan cache's misses
+
+ENTRY_NS = ("entry_setup_ns", "entry_finish_ns", "entry_convert_ns")
+FINISH_HOOKS_NS = ("entry_finish_teardown_ns", "entry_finish_metrics_ns",
+                   "entry_finish_record_ns", "entry_finish_history_ns")
+
+
+def test_one_query_counts_every_entry_region_and_both_dispatch_parts(
+        device_cfg):
+    fact, dim = _frames(n=2048, seed=13)
+    for _ in range(2):  # the second plans from the plan cache
+        q = _join_query(fact, dim)
+        t0 = time.perf_counter_ns()
+        q.collect().to_pydict()
+        wall = time.perf_counter_ns() - t0
+        c = _counters(q)
+        assert all(c[k] > 0 for k in ENTRY_NS + FINISH_HOOKS_NS), c
+        # no cache_dir: nothing to persist, and no counter for it
+        assert "entry_finish_persist_ns" not in c
+        assert sum(c[k] for k in FINISH_HOOKS_NS) <= c["entry_finish_ns"]
+        assert c["dispatch_lookup_ns"] > 0 and c["dispatch_call_ns"] > 0
+        # parts of the dispatch frames, not taken off them
+        assert (c["dispatch_lookup_ns"] + c["dispatch_call_ns"]
+                <= c["device_dispatch_ns"])
+        # the regions nest as frames: together they stay inside the wall
+        owned = sum(c[k] for k in LAYER_NS + ENTRY_NS) + c["planning_wall_ns"]
+        assert owned <= wall, (wall, c)
+        assert c["gc_collections"] >= 0 and c["gc_pause_ns"] >= 0
+
+
+def test_a_part_is_named_inside_its_frame_and_taken_off_nothing():
+    from daft_tpu.execution import RuntimeStats
+    from daft_tpu.profile import timeline
+
+    stats = RuntimeStats()
+    with timeline.DeviceFrame(stats, "dispatch", "device_dispatch_ns"):
+        with timeline.part("dispatch.call", "dispatch_call_ns"):
+            time.sleep(0.02)
+            # owned inside the part: off the part and the frame alike
+            with timeline.timed("stage", "stage_ns"):
+                time.sleep(0.01)
+            # a part inside a part records nothing of its own
+            with timeline.part("dispatch.lookup", "dispatch_lookup_ns"):
+                pass
+    c = stats.snapshot()["counters"]
+    assert c["dispatch_call_ns"] >= 20_000_000
+    assert c["stage_ns"] >= 10_000_000
+    assert c["device_dispatch_ns"] >= c["dispatch_call_ns"]
+    assert "dispatch_lookup_ns" not in c
+    # outside a frame a part is a no-op
+    with timeline.part("dispatch.call", "dispatch_call_ns"):
+        pass
+    assert stats.snapshot()["counters"]["dispatch_call_ns"] == \
+        c["dispatch_call_ns"]
+
+
+def test_a_forced_collection_lands_in_its_own_querys_counters(device_cfg):
+    import gc
+
+    from daft_tpu import DataType
+
+    frame = dt.from_pydict({"a": list(range(64))}).collect()
+
+    def collecting(x):
+        if x == 0:
+            gc.collect()  # a generation-2 collection, on the query's thread
+        return x
+
+    def q(fn):
+        return frame.select(col("a").apply(fn, return_dtype=DataType.int64()))
+
+    enabled = gc.isenabled()
+    gc.disable()  # no collection but the forced one
+    try:
+        forced = q(collecting)
+        forced.collect().to_pydict()
+        other = q(lambda x: x)
+        other.collect().to_pydict()
+    finally:
+        if enabled:
+            gc.enable()
+    c, o = _counters(forced), _counters(other)
+    assert c["gc_collections_gen2"] == 1 and c["gc_collections"] >= 1
+    assert c["gc_pause_ns"] > 0
+    assert o["gc_collections_gen2"] == 0 and o["gc_pause_ns"] == 0
+
+
+def test_a_profiled_query_records_its_collections_as_events(device_cfg):
+    import gc
+
+    from daft_tpu import DataType
+
+    frame = dt.from_pydict({"a": list(range(8))}).collect()
+    df = frame.select(col("a").apply(lambda x: gc.collect() and x,
+                                     return_dtype=DataType.int64()))
+    df.collect(profile=True)
+    evs = [e for e in df.stats.profiler.events_snapshot()
+           if e["kind"] == "gc"]
+    assert evs and all(e["attrs"]["generation"] == 2
+                       and e["attrs"]["dur_ns"] > 0 for e in evs)
+    assert sum(e["attrs"]["dur_ns"] for e in evs) == \
+        _counters(df)["gc_pause_ns"]
+
+
+def test_every_plan_cache_miss_has_its_reason(device_cfg):
+    from daft_tpu import faults
+    from daft_tpu.adapt.plancache import PLAN_CACHE
+
+    frame = dt.from_pydict({"k": [1, 2, 3] * 50, "v": list(range(150))})
+
+    def q(lit):
+        return frame.where(col("v") > lit).groupby("k").agg(
+            col("v").sum().alias("s"))
+
+    def reasons(df):
+        c = _counters(df)
+        return {k[len("plan_cache_miss_"):]: v for k, v in c.items()
+                if k.startswith("plan_cache_miss_")}, c.get(
+                    "plan_cache_misses", 0)
+
+    PLAN_CACHE.clear()
+    assert reasons(q(10).collect()) == ({"shape": 1}, 1)
+    assert reasons(q(10).collect()) == ({}, 0)  # a hit
+    assert reasons(q(20).collect()) == ({"binding": 1}, 1)
+    device_cfg.device_min_rows = 2  # another config key
+    assert reasons(q(10).collect()) == ({"config": 1}, 1)
+    with faults.inject("fuse.compile", "always"):  # the cache stands down
+        assert reasons(q(10).collect()) == ({"uncached": 1}, 1)
+
+
+def test_a_miss_says_whether_only_the_generation_changed():
+    from daft_tpu.adapt.plancache import CompiledPlan, PlanCache
+
+    cache = PlanCache()
+    key = "cfg=1|v1|g{}|rnative"
+    assert cache.miss_reason("fp", key.format(0)) == ("shape", False)
+    cache.store("fp", key.format(0), "b", CompiledPlan(None, None, 100),
+                1 << 20)
+    assert cache.miss_reason("fp", key.format(0)) == ("binding", False)
+    assert cache.miss_reason("fp", key.format(3)) == ("config", True)
+    assert cache.miss_reason("fp", "cfg=2|v1|g0|rnative") == ("config", False)
+    assert cache.miss_reason("other", key.format(0)) == ("shape", False)
+
+
+def test_the_entry_regions_and_dispatch_parts_are_events_of_the_xplane(
+        device_cfg, session):
+    fact, dim = _frames(n=1024, seed=17)
+    _join_query(fact, dim).collect()  # compile outside the session
+    start, stop = session
+    start()
+    _join_query(fact, dim).collect().to_pydict()
+    lines = stop()
+    names = {n for evs in lines.values() for n, _, _ in evs}
+    for want in ("phase:entry.setup", "phase:entry.finish",
+                 "phase:entry.convert", "phase:finish.teardown",
+                 "phase:finish.metrics", "phase:finish.record",
+                 "phase:finish.history", "phase:dispatch.lookup",
+                 "phase:dispatch.call"):
+        assert PREFIX + want in names, (want, sorted(names))
