@@ -53,7 +53,8 @@ LAYER_COUNTERS = (
 
 
 def layer_times(counters: dict) -> str:
-    """``plan 1ms stage 12ms ... staged 3.1MB in 2 columns ... compiles 0
+    """``plan 1ms stage 12ms ... staged 3.1MB in 2 columns morsels 0 views
+    1 ... compiles 0
     (0ms) cache loads 0 dict lookups 1 packed 0 gathered agg reduce 1 dense
     0 kernel 0 sorted probe levels 56 compared 17 gathered sql plan 1.2ms 2
     subqueries 1 scalar subquery joins 1 (1 on the device) join filter 1
@@ -63,7 +64,9 @@ def layer_times(counters: dict) -> str:
     plan misses 0 (shape 0 config 0 binding 0 uncached 0)``: the layer
     counters of one query, for its printed line. A
     warm query that stages columns lost its stage cache; one that compiles
-    (and for how long) met a shape the warm-up did not; one that gathers a
+    (and for how long) met a shape the warm-up did not; the device maps
+    that ran over a stage view of a partition larger than a morsel (and
+    any morsels the host path streamed); one that gathers a
     dictionary predicate met a dictionary over ``DICT_PACKED_MAX_ENTRIES``;
     one whose aggregate took the kernel grouped into a bucket over
     ``DENSE_MAX_SEGMENTS``, one whose float sums took the sorted form into
@@ -78,6 +81,8 @@ def layer_times(counters: dict) -> str:
              for label, key in LAYER_COUNTERS]
     parts.append(f"staged {counters.get('stage_bytes', 0) / 1e6:.1f}MB "
                  f"in {counters.get('stage_columns', 0)} columns "
+                 f"morsels {counters.get('stream_morsels', 0)} "
+                 f"views {counters.get('device_maps_unsplit', 0)} "
                  f"gathered {counters.get('gather_bytes', 0) / 1e6:.1f}MB "
                  f"compiles {counters.get('xla_compiles', 0)} "
                  f"({counters.get('xla_compile_ns', 0) / 1e6:.0f}ms) "

@@ -1112,6 +1112,8 @@ def execute_plan(root: PhysicalOp, ctx: ExecutionContext,
         if pipe is not None:
             return pipe
         child_streams = [build(c) for c in op.children]
+        if _unsplit(op, ctx):
+            child_streams = [_stage_views(child_streams[0], ctx)]
         if getattr(op, "batch_declared", False) and ctx.dist_backend is None:
             # dynamic-batching UDFs (physical.BatchedUdfOp): the op's own
             # execute() coalesces across partitions — thread fan-out would
@@ -1258,6 +1260,50 @@ def execute_plan(root: PhysicalOp, ctx: ExecutionContext,
                 ctx.progress = None
 
     return rooted()
+
+
+def _unsplit(op: PhysicalOp, ctx: ExecutionContext) -> bool:
+    """Does the device map ``op`` meet its in-memory source through stage
+    views (``_stage_views``)? It does where a partition of the source is
+    larger than ``morsel_size_rows``: each partition is then one launch that
+    reads the lanes the source holds and leaves it no new ones, where a
+    partition at or under a morsel keeps what its maps stage. Only row-local
+    maps (Project, Filter, FusedMap): an aggregate or a segment keeps its
+    lanes for the next query. With streaming or device residency off, or
+    partitions pinned to a mesh, a host or a worker process, every map
+    keeps the partition path."""
+    from .physical import InMemoryOp
+    from .stream.pipeline import pinned_partitions
+
+    cfg = ctx.cfg
+    src = op.children[0] if len(op.children) == 1 else None
+    if not (isinstance(src, InMemoryOp) and op.morsel_streamable
+            and cfg.streaming_execution and cfg.device_residency
+            and not pinned_partitions(ctx)):
+        return False
+    morsel = max(1, int(cfg.morsel_size_rows))
+    return (any((p.num_rows_or_none() or 0) > morsel for p in src.parts)
+            and op.device_pipelinable(ctx))
+
+
+def _stage_views(parts: Iterator[MicroPartition],
+                 ctx: ExecutionContext) -> Iterator[MicroPartition]:
+    """Each partition as a stage view (``MicroPartition.stage_view``). A
+    view's own lanes are dropped when the next view is asked for, or the
+    stream ends: by then the map has launched over it (its program holds
+    what it reads until it is done), and its output partition carries only
+    lanes the source held. So they never meet the consumer's staging."""
+    view = None
+    try:
+        for part in parts:
+            if view is not None:
+                view.drop_staged()
+            ctx.stats.bump("device_maps_unsplit")
+            view = part.stage_view()
+            yield view
+    finally:
+        if view is not None:
+            view.drop_staged()
 
 
 def _adaptive_device_map(op: PhysicalOp, child: Iterator[MicroPartition],

@@ -8,11 +8,13 @@
   in ONE `eval_expression_list` (the table-level structural memo makes the
   hash-consed shared subtrees evaluate exactly once). No intermediate
   partition is ever materialized.
-- **device path**: the WHOLE DAG — every mask and every output — goes
-  through `kernels/device.normalize_and_check` and runs as ONE jit program
-  behind the existing device breaker; the host then ANDs the mask columns
-  and compacts once. N staged dispatches and N intermediate
-  materializations become one XLA-fused kernel over the resident buffer.
+- **device path**: the WHOLE DAG — every mask and every computed output —
+  goes through `kernels/device.normalize_and_check` and runs as ONE jit
+  program behind the existing device breaker; the host then ANDs the mask
+  columns and compacts once, taking the input columns the chain passes
+  through unchanged from the input table. N staged dispatches and N
+  intermediate materializations become one XLA-fused kernel over the
+  resident buffer.
 
 The planner pass `fuse_map_chains` (called from `physical.translate` behind
 ``cfg.expr_fusion``) replaces each maximal chain with a `FusedMapOp`. Any
@@ -29,8 +31,9 @@ from typing import List, Optional, Tuple
 from .. import faults
 from ..expressions import Alias, Expression, col, required_columns
 from ..physical import (DeviceStep, PhysicalOp, _exprs_compile,
-                        _launch_exprs, summarize_exprs)
+                        _launch_exprs, _selected_column, summarize_exprs)
 from ..schema import Field, Schema
+from ..table import Table
 from .graph import (
     MASK_PREFIX,
     FusedGraph,
@@ -109,6 +112,19 @@ class FusedProgram:
                  for i, m in enumerate(graph.device_masks)]
                 + [Expression(Alias(node, name))
                    for name, node in graph.device_outputs])
+        # an output that is an input column unchanged is the host's: the
+        # map's own launch neither stages nor returns it, and holds only
+        # the lanes its masks and computed outputs read (over a partition
+        # of millions of rows the copies were most of its device memory
+        # and of its gather). A resident segment still takes every output
+        # of device_exprs as a lane.
+        self.passthrough = {}
+        for name, node in graph.device_outputs:
+            src = _selected_column(Expression(node))
+            if src in input_names:
+                self.passthrough[name] = src
+        self.launch_exprs = None if self.device_exprs is None else [
+            e for e in self.device_exprs if e.name() not in self.passthrough]
 
     # ------------------------------------------------------------- host
     def run_host(self, table):
@@ -128,18 +144,23 @@ class FusedProgram:
         return work.eval_expression_list(self.output_exprs)
 
     # ----------------------------------------------------------- device
-    def assemble_device(self, result_table):
-        """Device program result -> output table: AND the mask columns
-        (kleene, same null semantics as sequential filters) and compact the
-        output columns once."""
+    def assemble_device(self, result_table, table):
+        """The launch's result over ``table`` -> output table: each output
+        from the program, or from ``table`` where it passes an input column
+        through; then AND the mask columns (kleene, same null semantics as
+        sequential filters) and compact the output columns once."""
+        cols = [table.get_column(self.passthrough[name]).rename(name)
+                if name in self.passthrough
+                else result_table.get_column(name)
+                for name in self.out_schema.field_names()]
+        out = Table(Schema([Field(c.name, c.dtype) for c in cols]), cols)
         if not self.n_masks:
-            return result_table
+            return out
         mask_cols = result_table._columns[:self.n_masks]
         mask = mask_cols[0]
         for m in mask_cols[1:]:
             mask = mask & m
-        out_names = result_table.column_names[self.n_masks:]
-        return result_table.select_columns(out_names).filter_with_mask(mask)
+        return out.filter_with_mask(mask)
 
 
 def compile_chain(stages, input_schema: Schema,
@@ -225,11 +246,12 @@ class FusedMapOp(QueryLatches, DeviceStep, PhysicalOp):
 
     @property
     def has_program(self) -> bool:
-        return self.program.device_exprs is not None
+        # a chain that only selects and renames columns computes nothing
+        return bool(self.program.launch_exprs)
 
     def compilable(self) -> bool:
         return self.has_program and _exprs_compile(
-            self.program.device_exprs, self.children[0].schema)
+            self.program.launch_exprs, self.children[0].schema)
 
     def count(self, stats, n: int) -> None:
         # the legacy per-op class counters advance by the chain's op counts
@@ -242,12 +264,13 @@ class FusedMapOp(QueryLatches, DeviceStep, PhysicalOp):
             stats.bump("device_filters", n * g.n_filter_ops)
 
     def launch(self, ctx, part):
-        return _launch_exprs(part, self.program.device_exprs)
+        return _launch_exprs(part, self.program.launch_exprs)
 
     def finish(self, ctx, out, part):
         # the chain's host half (mask compaction): the operator's own time
         with ctx.stats.profiler.span("fuse.assemble", kind="phase"):
-            return part._wrap(self.program.assemble_device(out))
+            return part._wrap(self.program.assemble_device(out,
+                                                          part.table()))
 
     def host(self, ctx, part):
         g = self.program.graph

@@ -286,9 +286,9 @@ class DeviceSegmentOp(QueryLatches, DeviceStep, PhysicalOp):
     residency. Its DeviceStep is the resident pipeline when the partition
     is device-eligible, the retained staged ops (``map_op`` then
     ``agg_op``) otherwise — byte-identical either way. NOT
-    morsel-streamable: the aggregation is a pipeline breaker; the morsel
-    stream runs BELOW it (device-morsel mode in stream/pipeline.py) and
-    re-chunks at this op's boundary."""
+    morsel-streamable: the aggregation is a pipeline breaker, and it keeps
+    the lanes it stages in its input partition's cache for the next
+    query."""
 
     morsel_streamable = False
 

@@ -61,6 +61,20 @@ class MicroPartition:
     def device_stage_cache(self) -> Dict[Any, Any]:
         return self._device_cache
 
+    def stage_view(self) -> "MicroPartition":
+        """The same rows, with a stage cache that starts as a copy of this
+        one's: a device step over the view reads the lanes this partition
+        holds, and what it stages is the view's alone, so this partition
+        gains no residency from it."""
+        out = self._wrap(self.table())
+        out._device_cache = dict(self._device_cache)
+        return out
+
+    def drop_staged(self) -> None:
+        """Let go of every staged lane: the stage cache starts empty again
+        (a launch still reading the old one keeps it until it is done)."""
+        self._device_cache = {}
+
     # ------------------------------------------------------------- pickling
     # Partitions cross process boundaries on the dist/ worker transport.
     # Loaded partitions ship their tables; unloaded ones ship the scan task

@@ -29,7 +29,10 @@ Eligibility (the *morsel contract*): an op streams iff it declares
 DTL006 pins the pair), is row-local (UDFs decline: a batch-dependent UDF
 applied per morsel could change results), and requests no resources. The
 device-kernel path and mesh/multi-host contexts decline entirely — their
-execution units are whole resident partitions by design.
+execution units are whole resident partitions by design. A device map over
+an in-memory partition larger than a morsel is one launch over a stage view
+of the partition (``execution._unsplit``), which keeps no new lanes in the
+partition's stage cache.
 
 Error contract: a producer failure (including injected ``scan.read`` /
 ``fuse.compile``-site faults) parks on the channel and re-raises on the
@@ -49,7 +52,8 @@ from ..micropartition import MicroPartition
 from .channel import WAIT, BoundedChannel, ChannelClosed
 from .morsel import iter_morsels
 
-__all__ = ["try_stream", "extract_segment", "StreamSegment"]
+__all__ = ["try_stream", "extract_segment", "pinned_partitions",
+           "StreamSegment"]
 
 # how long the consumer sleeps on an empty channel before re-checking
 # deadline/cancellation and producer liveness (a cancelled future must
@@ -131,6 +135,17 @@ def extract_segment(op, ctx) -> Optional[StreamSegment]:
     return StreamSegment(maps, limit, source, count_source=direct)
 
 
+def pinned_partitions(ctx) -> bool:
+    """True where partitions are pinned: to devices or processes on a mesh
+    or multi-host context (morselizing would force foreign reads), to
+    worker PROCESSES under the distributed runner (map-class work ships
+    there at partition granularity; in-process morsel channels would keep
+    it on the driver)."""
+    return (getattr(ctx, "try_device_shuffle", None) is not None
+            or getattr(ctx, "scan_owner", None) is not None
+            or getattr(ctx, "dist_backend", None) is not None)
+
+
 def try_stream(op, ctx, build, trace: bool = True):
     """Return a pipelined partition stream replacing the segment rooted at
     ``op``, or None when the op/context does not stream. ``build`` is the
@@ -140,42 +155,11 @@ def try_stream(op, ctx, build, trace: bool = True):
     if not getattr(cfg, "streaming_execution", True):
         return None
     if getattr(cfg, "use_device_kernels", False):
-        # The device path wants whole resident partitions: one fused kernel
-        # over one big buffer beats many small dispatches, and morsel
-        # slices would orphan the HBM residency caches. EXCEPT in
-        # device-morsel mode (cfg.device_residency): for segment-shaped
-        # chains — every map device-pipelinable — each morsel stages to a
-        # device batch feeding its own resident program (per-morsel stage
-        # caches, same size-bucketed executables), so streaming composes
-        # with residency instead of standing it down. Mixed chains still
-        # decline: one host-only map would force every morsel through an
-        # Arrow round-trip the partition path avoids.
-        if not getattr(cfg, "device_residency", True):
-            return None
-        probe = extract_segment(op, ctx)
-        if probe is None or not probe.maps or not all(
-                m.device_pipelinable(ctx) for m in probe.maps):
-            return None
-        # only when slicing actually subdivides: a partition at or under
-        # the morsel size already IS one device batch, and the partition-
-        # granular double-buffered dispatch path pipelines it better than
-        # a one-morsel stream would
-        from ..physical import InMemoryOp as _InMem
-        msz = max(1, int(getattr(cfg, "morsel_size_rows", 128 * 1024)))
-        src = probe.source
-        if not (isinstance(src, _InMem)
-                and any((p.num_rows_or_none() or 0) > msz
-                        for p in src.parts)):
-            return None
-    if getattr(ctx, "try_device_shuffle", None) is not None \
-            or getattr(ctx, "scan_owner", None) is not None:
-        # mesh / multi-host: partitions are pinned to devices/processes;
-        # morselizing would force foreign reads
+        # the device path's unit is the whole partition: one launch over
+        # one buffer. A device map over a partition larger than a morsel
+        # runs over a stage view of it instead (execution._unsplit)
         return None
-    if getattr(ctx, "dist_backend", None) is not None:
-        # distributed runner: map-class work ships to worker PROCESSES at
-        # partition granularity through the dispatch backend — in-process
-        # morsel channels would keep that work on the driver
+    if pinned_partitions(ctx):
         return None
     seg = extract_segment(op, ctx)
     if seg is None:

@@ -687,16 +687,27 @@ QUERY_SHAPES = {
         li, left_on="o_key", right_on="l_key").select(
         col("o_key"), (col("l_price") * (1 - col("l_disc"))).alias("rev")
     ).groupby("o_key").agg(col("rev").sum().alias("rev")).sort("rev").limit(5),
+    # over a partition larger than a morsel: one launch over a stage view
+    "filter_over_a_view": lambda o, li: li.where(col("l_qty") > 10).select(
+        col("l_key"), col("l_price")),
 }
+# the shapes run with morsels smaller than LINEITEM's one partition
+OVER_A_MORSEL = {"filter_over_a_view"}
 
 
 @pytest.mark.parametrize("x64", [True, False], ids=["x64", "x32"])
 @pytest.mark.parametrize("shape", list(QUERY_SHAPES))
-def test_device_arrays_die_with_their_query(shape, x64, device_kernels_on):
+def test_device_arrays_die_with_their_query(shape, x64, device_kernels_on,
+                                            monkeypatch):
     import gc
 
     import jax
 
+    import daft_tpu
+
+    if shape in OVER_A_MORSEL:
+        monkeypatch.setattr(daft_tpu.context.get_context().execution_config,
+                            "morsel_size_rows", 1024)
     x64_was = bool(jax.config.jax_enable_x64)
     jax.config.update("jax_enable_x64", x64)
     try:
@@ -711,6 +722,8 @@ def test_device_arrays_die_with_their_query(shape, x64, device_kernels_on):
             c = df.stats.snapshot()["counters"]
             assert any(k.startswith("device_") and not k.endswith("_ns")
                        and v for k, v in c.items()), c
+            assert (c.get("device_maps_unsplit", 0) > 0) == (
+                shape in OVER_A_MORSEL), c
             del df, out
             assert len(jax.live_arrays()) == alive
         finally:

@@ -169,11 +169,11 @@ def summarise(by_query: dict, top: int = 8) -> dict:
     return out
 
 
-def drive(args) -> tuple:
-    """Set the cell up as ``chipbench/run.py`` does, trace one window."""
+def set_up(workload: str, seed: int) -> tuple:
+    """Set the cell up as ``chipbench/run.py`` does: generate, make
+    resident, warm. ``(cell, order, queries, frames, platform)``."""
     rehearse = os.environ.get("CHIPBENCH_REHEARSE") == "1"
-    _, cell, config, traffic, queries, dataset = bench.load_cell(
-        args.workload)
+    _, cell, config, traffic, queries, dataset = bench.load_cell(workload)
     if rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
@@ -190,13 +190,22 @@ def drive(args) -> tuple:
     if rehearse:
         engine["device_min_rows"] = max(8, int(4096 * min(1.0, scale)))
     dt.set_execution_config(**engine)
-    tables = dataset.generate(scale, args.seed, bench.union_columns(queries))
+    tables = dataset.generate(scale, seed, bench.union_columns(queries))
     frames = {name: dt.from_arrow(table).collect()
               for name, table in tables.items()}
     order = traffic["queries"]
     for _ in range(traffic["warmup_passes"]):
         for name in order:
             queries[name].build(frames).collect().to_pydict()
+    return cell, order, queries, frames, platform
+
+
+def drive(args) -> tuple:
+    """Set the cell up as ``chipbench/run.py`` does, trace one window."""
+    cell, order, queries, frames, platform = set_up(args.workload, args.seed)
+    import jax
+
+    import daft_tpu as dt
 
     trace_dir = os.path.join(args.trace_dir, f"{cell['name']}-{args.seed}")
     os.makedirs(trace_dir, exist_ok=True)
